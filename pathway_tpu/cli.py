@@ -26,6 +26,11 @@ def spawn(
     record: bool = False,
     record_path: str | None = None,
 ) -> int:
+    # JAX_PLATFORMS passes through untouched: one process may own the
+    # accelerator, and in a multi-process run the device classes refuse to
+    # start unless the caller pinned the run to the host on purpose
+    # (parallel/mesh.py require_single_process) — pinning it here would
+    # turn that refusal into a silent CPU run
     env_base = dict(os.environ)
     env_base["PATHWAY_THREADS"] = str(threads)
     env_base["PATHWAY_PROCESSES"] = str(processes)

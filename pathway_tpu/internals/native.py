@@ -1,15 +1,22 @@
 """Loader for the C++ host-runtime extension (``native/``).
 
-Compiles ``native/pathway_native.cpp`` with g++ on first use (cached
-under ``native/build/``) and exposes it; every caller has a Python
-fallback, and ``PATHWAY_DISABLE_NATIVE=1`` forces it.
+Compiles ``native/pathway_native.cpp`` with g++ on first use and exposes
+it; every caller has a Python fallback, and ``PATHWAY_DISABLE_NATIVE=1``
+forces it.  The build is ``-march=native``, so the cached ``.so`` under
+``native/build/`` is named by a digest of what it was built from and
+for — sources, flags, interpreter ABI, this CPU's instruction set: a
+build carried over from another machine or an older source is never
+loaded, it is rebuilt here.
 """
 
 from __future__ import annotations
 
+import glob
+import hashlib
 import importlib.util
 import logging
 import os
+import platform
 import subprocess
 import sysconfig
 import threading
@@ -23,15 +30,44 @@ _tried = False
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "pathway_native.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
+_FLAGS = [
+    "-O3", "-march=native", "-funroll-loops", "-shared", "-fPIC", "-std=c++17",
+]
+
+
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolves against: the first core's model
+    and feature flags (``/proc/cpuinfo``), else the bare architecture."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            lines = [
+                line
+                for line in f.read().split("\n\n", 1)[0].splitlines()
+                if line.startswith(("model name", "flags", "Features"))
+            ]
+    except OSError:
+        lines = []
+    return platform.machine() + "\n" + "\n".join(lines)
+
+
+def _build_key() -> str:
+    h = hashlib.sha256()
+    for path in sorted(glob.glob(os.path.join(os.path.dirname(_SRC), "*.[ch]*"))):
+        with open(path, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update((sysconfig.get_config_var("SOABI") or "").encode())
+    h.update(_cpu_identity().encode())
+    return h.hexdigest()[:16]
 
 
 def _compile() -> str | None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    so_path = os.path.join(_BUILD_DIR, "pathway_native.so")
+    so_path = os.path.join(_BUILD_DIR, f"pathway_native.{_build_key()}.so")
     # cross-PROCESS build lock + atomic rename: spawned cluster workers
     # all race through here on a cold cache; without it two g++ runs write
     # the same .so and a third process dlopens the torn file
-    lock_path = so_path + ".lock"
+    lock_path = os.path.join(_BUILD_DIR, "pathway_native.lock")
     import contextlib
 
     @contextlib.contextmanager
@@ -49,24 +85,38 @@ def _compile() -> str | None:
             yield
 
     with _build_lock():
-        if os.path.exists(so_path) and os.path.getmtime(so_path) >= os.path.getmtime(_SRC):
+        if os.path.exists(so_path):
             return so_path
         include = sysconfig.get_paths()["include"]
         tmp_path = f"{so_path}.{os.getpid()}.tmp"
-        cmd = [
-            "g++", "-O3", "-march=native", "-funroll-loops", "-shared", "-fPIC",
-            "-std=c++17", f"-I{include}", _SRC, "-o", tmp_path,
-        ]
+        cmd = ["g++", *_FLAGS, f"-I{include}", _SRC, "-o", tmp_path]
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            subprocess.run(cmd, check=True, capture_output=True, timeout=300)
             os.replace(tmp_path, so_path)
-        except Exception as e:  # noqa: BLE001
-            _logger.info("native build skipped: %r", e)
+        except FileNotFoundError:
+            _logger.info("native build skipped: no g++ on this machine")
+            return None
+        except (subprocess.SubprocessError, OSError) as e:
+            # g++ is here and the build still failed: the whole host plane
+            # is about to run on its Python fallbacks — say so
+            stderr = getattr(e, "stderr", None) or b""
+            _logger.warning(
+                "native build failed, host plane falls back to Python: %r %s",
+                e,
+                stderr.decode(errors="replace")[-2000:],
+            )
             try:
                 os.unlink(tmp_path)
             except OSError:
                 pass
             return None
+        # builds for other sources or machines are dead weight now
+        for stale in glob.glob(os.path.join(_BUILD_DIR, "pathway_native.*so")):
+            if stale != so_path:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass
         return so_path
 
 
@@ -94,7 +144,9 @@ def load() -> Any:
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
         except Exception as e:  # noqa: BLE001
-            _logger.info("native load failed: %r", e)
+            _logger.warning(
+                "native load failed, host plane falls back to Python: %r", e
+            )
             return None
         # register the value classes the VM needs for type-tagged
         # hashing (Pointer) and Json get/convert semantics.  Local

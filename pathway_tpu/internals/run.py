@@ -255,9 +255,8 @@ class _ManagedGc:
 
     CPython's automatic gen-0 collection fires every ~700 net container
     allocations; a streaming epoch allocates millions of short-lived row
-    tuples, so the collector (plus the per-collection XLA gc callback JAX
-    registers) costs ~2x wordcount throughput (measured: 183k -> 380k
-    rows/s on the 400k-line benchmark).  The reference engine has no such
+    tuples, so the collector costs ~2x wordcount throughput (measured:
+    183k -> 380k rows/s on the 400k-line benchmark).  The reference engine has no such
     pauses — Rust frees rows deterministically (src/engine/dataflow.rs) —
     so the TPU build's host runtime disables *automatic* collection for
     the duration of the run and sweeps at EPOCH BOUNDARIES instead (the
@@ -268,10 +267,11 @@ class _ManagedGc:
     the boundary the transients are already refcount-freed, so a sweep
     only walks live survivors (reducer state, buffers).  Startup objects
     (modules, the graph, jax internals — ~1M containers) are frozen out
-    of the collector entirely for the run, and JAX's per-collection gc
-    callback is detached while automatic collection is off.  Plain
-    reference-counted garbage (the vast majority of row data) is freed
-    immediately either way.  Opt out with PATHWAY_GC_INTERVAL_S=0; a
+    of the collector entirely for the run.  JAX's own gc callback stays
+    registered and runs with each sweep: it costs well under a
+    microsecond, and a server's ``pw.run`` never returns to put it back.
+    Plain reference-counted garbage (the vast majority of row data) is
+    freed immediately either way.  Opt out with PATHWAY_GC_INTERVAL_S=0; a
     user who already disabled gc keeps their setting untouched.
     """
 
@@ -290,20 +290,12 @@ class _ManagedGc:
         self._last_sweep = 0.0
         self._next_due = 0.0
         self._sweeps = 0
-        self._detached_callbacks: list[Any] = []
 
     def __enter__(self) -> "_ManagedGc":
         if self._interval <= 0 or not self._gc.isenabled():
             return self
         self._was_enabled = True
         self._gc.disable()
-        # jax registers a gc callback that runs on every collection
-        # (measured ~125ms each on this host); with automatic collection
-        # off, our explicit sweeps don't need it either
-        for cb in list(self._gc.callbacks):
-            if "jax" in (getattr(cb, "__module__", "") or ""):
-                self._gc.callbacks.remove(cb)
-                self._detached_callbacks.append(cb)
         # clean the YOUNG generations, then freeze everything into the
         # permanent generation.  A full collect here walks gen-2 — with a
         # million-row static table that is ~1s before the run even starts
@@ -348,9 +340,6 @@ class _ManagedGc:
     def __exit__(self, *exc: Any) -> None:
         if self._was_enabled:
             self._gc.unfreeze()
-            for cb in self._detached_callbacks:
-                self._gc.callbacks.append(cb)
-            self._detached_callbacks.clear()
             self._gc.enable()
 
 

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from pathway_tpu.ops.shard_map_compat import shard_map
-
 __all__ = ["ring_attention", "local_attention"]
 
 _NEG = -1e30
@@ -96,7 +94,7 @@ def ring_attention(
         mask = jnp.ones(q.shape[:2], jnp.int32)
 
     body = functools.partial(_ring_body, axis_name=axis, n_shards=n)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

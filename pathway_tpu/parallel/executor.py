@@ -27,8 +27,13 @@ from pathway_tpu.models.encoder import (
 from pathway_tpu.internals import device_counters as _devctr
 from pathway_tpu.models.tokenizer import Tokenizer, get_tokenizer
 from pathway_tpu.ops.bucketing import bucket_size
+from pathway_tpu.parallel.mesh import require_single_process
 
 __all__ = ["JittedEncoder"]
+
+#: device memory assumed where the backend reports none (the CPU): one
+#: v5e chip's 16 GB, so tests and the chip split batches alike
+_ASSUMED_DEVICE_BYTES = 16 * 2**30
 
 
 class JittedEncoder:
@@ -56,15 +61,16 @@ class JittedEncoder:
         pipeline_depth: int = 2,
         sequence_axis: str | None = None,
     ):
+        require_single_process("JittedEncoder")
         #: sequence_axis: shard the SEQUENCE dimension over this mesh
         #: axis and run ring attention inside every layer — the
         #: long-document path: max_len may exceed one device's attention
         #: memory (it must divide by the axis size).  Mutually exclusive
         #: with sharding the batch over the same axis.
-        #: chunks kept in flight before collecting a readback.  2 keeps
-        #: the historical device-memory footprint (one computing + one
-        #: draining); raise on high-RTT links to hide the round trip at
-        #: the cost of one more resident batch per extra slot.
+        #: chunks kept in flight before collecting a readback.  2 is one
+        #: computing + one draining; each extra slot overlaps more host
+        #: tokenization with device work at the cost of one more
+        #: resident batch.
         self.pipeline_depth = max(1, pipeline_depth)
         if checkpoint_dir is not None:
             # real pretrained weights: config/params/vocab all from the
@@ -165,9 +171,9 @@ class JittedEncoder:
             self._out_sharding = None
         self.params = params
         # token ids upload as int16 when the vocab permits (mask/type as
-        # uint8): 3x less host->device traffic per chunk, which is what
-        # bounds steady-state throughput on remote/tunneled backends; the
-        # cast back to int32 is fused into the compiled apply
+        # uint8): 3x fewer host->device bytes per chunk
+        # (pathway_tpu_h2d_bytes_total); the cast back to int32 is fused
+        # into the compiled apply
         self._narrow_ids = config.vocab_size < 2**15
 
         def _apply_cast(params, ids, mask, tps):
@@ -180,8 +186,45 @@ class JittedEncoder:
 
         self._apply = jax.jit(_apply_cast, out_shardings=self._out_sharding)
         self._dp = 1 if mesh is None else mesh.shape.get(data_axis, 1)
+        # activations one dispatch may hold live on a device: a quarter of
+        # its memory — params, the index slab and the pipeline's resident
+        # batches share the rest
+        device = jax.local_devices()[0] if mesh is None else mesh.devices.flat[0]
+        stats = device.memory_stats()
+        self._dispatch_bytes = (
+            (stats or {}).get("bytes_limit") or _ASSUMED_DEVICE_BYTES
+        ) // 4
 
     # ------------------------------------------------------------------
+    def _rows_per_dispatch(self, length: int) -> int:
+        """Most rows one dispatch may carry at padded length ``length``.
+
+        ``max_batch`` alone does not bound memory: attention holds
+        ``[rows, heads, length, length]`` scores, and the tokenizer pads a
+        whole batch to its longest row, so one long chunk takes a
+        1024-row batch to 512 tokens — for BGE-large a program with
+        10.8 GB of temporaries beside 1.3 GB of params on a 16.9 GB v5e
+        (XLA's memory analysis; it compiles, and leaves the index slab
+        and every other model 4.8 GB).  Rows are bounded instead by the
+        layer's live set per row: one f32 score tensor over this
+        device's heads beside eight ``[length, hidden]`` activations.
+        That model over-states what XLA assigns on a v5e by 1.25x at 512
+        tokens (20.2 MB a row measured) and 1.6x at 128 (1.9 MB), so a
+        dispatch stays under its quarter of the device.  A power of two
+        per device, so the split adds no shapes beyond the batch
+        buckets."""
+        if self.sequence_axis is not None:
+            return self.max_batch  # ring attention never holds full scores
+        cfg = self.config
+        tp = 1 if self.mesh is None else self.mesh.shape.get(self.model_axis, 1)
+        per_row = length * (
+            max(1, cfg.heads // tp) * length * 4
+            + 8 * cfg.hidden * np.dtype(cfg.dtype).itemsize
+        )
+        fit = max(1, self._dispatch_bytes // per_row)
+        rows = (1 << (fit.bit_length() - 1)) * self._dp
+        return min(self.max_batch, max(rows, max(8, self._dp)))
+
     def _pad_batch(self, ids: np.ndarray, mask: np.ndarray, tps: np.ndarray):
         """Round the batch up so it divides the data-parallel degree."""
         n = ids.shape[0]
@@ -204,10 +247,10 @@ class JittedEncoder:
         start_host_copy: bool = True,
     ):
         """Enqueue one padded chunk; returns (device_out, n_real_rows).
-        The device->host copy is started immediately (non-blocking), so on
-        remote/tunneled backends the transfer of chunk i overlaps the
-        tokenize+compute of chunk i+1.  ``start_host_copy=False`` for
-        consumers that keep the output on device (``encode_into``)."""
+        The device->host copy is started immediately (non-blocking), so
+        the readback of chunk i overlaps the tokenize+compute of chunk
+        i+1.  ``start_host_copy=False`` for consumers that keep the
+        output on device (``encode_into``)."""
         ids, mask, tps, n = self._pad_batch(ids, mask, tps)
         if self.sequence_axis is not None and ids.shape[1] < self.max_len:
             # SP shards the sequence dimension: pad to the full max_len so
@@ -226,9 +269,7 @@ class JittedEncoder:
             args = [jax.device_put(a, self._in_batch_sharding) for a in args]
         out = self._apply(self.params, *args)
         if start_host_copy:
-            copy_async = getattr(out, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
+            out.copy_to_host_async()
         return out, n
 
     def _run(self, ids: np.ndarray, mask: np.ndarray, tps: np.ndarray) -> np.ndarray:
@@ -242,9 +283,20 @@ class JittedEncoder:
         return host
 
     def _chunks(self, texts: Sequence[str], pair: Sequence[str] | None):
+        """Tokenized dispatch units ``(ids, mask, type_ids)``: up to
+        ``max_batch`` texts are tokenized together (they share one padded
+        length), then split so that no dispatch exceeds
+        :meth:`_rows_per_dispatch` at that length."""
         for i in range(0, len(texts), self.max_batch):
             sl = slice(i, i + self.max_batch)
-            yield texts[sl], None if pair is None else pair[sl]
+            ids, mask, tps = self.tokenizer.encode_batch(
+                texts[sl],
+                pair=None if pair is None else pair[sl],
+                max_len=self.max_len,
+            )
+            rows = self._rows_per_dispatch(ids.shape[1])
+            for j in range(0, ids.shape[0], rows):
+                yield ids[j : j + rows], mask[j : j + rows], tps[j : j + rows]
 
     def _run_pipelined(
         self, texts: list, pair: "list | None"
@@ -256,10 +308,7 @@ class JittedEncoder:
 
         outs: list[np.ndarray] = []
         inflight: deque = deque()
-        for chunk, pchunk in self._chunks(texts, pair):
-            ids, mask, tps = self.tokenizer.encode_batch(
-                chunk, pair=pchunk, max_len=self.max_len
-            )
+        for ids, mask, tps in self._chunks(texts, pair):
             inflight.append(self._dispatch(ids, mask, tps))
             if len(inflight) >= self.pipeline_depth:
                 out, nrows = inflight.popleft()
@@ -299,10 +348,7 @@ class JittedEncoder:
 
         inflight: deque = deque()
         pos = 0
-        for chunk, _p in self._chunks(texts, None):
-            ids, mask, tps = self.tokenizer.encode_batch(
-                chunk, max_len=self.max_len
-            )
+        for ids, mask, tps in self._chunks(texts, None):
             out, n = self._dispatch(ids, mask, tps, start_host_copy=False)
             inflight.append((out, n, keys[pos : pos + n]))
             pos += n

@@ -37,6 +37,7 @@ import numpy as np
 from pathway_tpu.internals import device_counters as _devctr
 from pathway_tpu.ops.bucketing import bucket_size, pad_rows
 from pathway_tpu.ops.topk import NEG_INF
+from pathway_tpu.parallel.mesh import require_single_process
 
 __all__ = ["IvfKnnIndex"]
 
@@ -102,6 +103,7 @@ class IvfKnnIndex:
     ):
         if metric not in ("cos", "dot"):
             raise ValueError(f"unsupported IVF metric {metric!r}")
+        require_single_process("IvfKnnIndex")
         self.dim = dim
         self.metric = metric
         self.dtype = dtype
@@ -374,10 +376,6 @@ class IvfKnnIndex:
         _devctr.record_h2d(qpad.nbytes)
         run = self._search_jit(k_eff, nprobe)
         out = run(jnp.asarray(qpad), self._centroids, self._cells, self._valid)
-        for a in out:
-            copy_async = getattr(a, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
         vals, ids = jax.device_get(out)
         _devctr.record_d2h(vals.nbytes + ids.nbytes)
         rows: list[list[tuple[Any, float]]] = []

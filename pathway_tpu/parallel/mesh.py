@@ -11,11 +11,39 @@ single-device fast path.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
-__all__ = ["make_mesh", "best_mesh", "mesh_axis_size"]
+from pathway_tpu.internals.config import pathway_config
+
+__all__ = ["make_mesh", "best_mesh", "mesh_axis_size", "require_single_process"]
+
+
+def require_single_process(component: str) -> None:
+    """Refuse to put a device component in a rank of a multi-process run.
+
+    An accelerator belongs to one process.  Under ``pathway spawn -n N``
+    every rank builds the whole graph, so every rank would construct the
+    encoder and reach for the chip: one gets it, the others fail at
+    backend start-up or come up on the CPU by JAX's own fallback and embed
+    there without a word.  Checked from the environment, before any JAX
+    call, so the refusal itself cannot take the chip.  A cluster pinned
+    to the host on purpose (``JAX_PLATFORMS=cpu``: the test suite, a
+    host-only deployment) is left alone.
+    """
+    processes = pathway_config.processes  # the topology pw.run will use
+    if processes > 1:
+        if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+            raise RuntimeError(
+                f"{component} in a run of {processes} processes: each rank "
+                "would initialise the accelerator, and a chip belongs to "
+                "one process.  Run device pipelines in one process "
+                "(`spawn -n 1`; threads scale the host plane), or set "
+                "JAX_PLATFORMS=cpu to keep a multi-process run on the host."
+            )
 
 
 def make_mesh(

@@ -31,8 +31,8 @@ from jax.sharding import PartitionSpec as P
 from pathway_tpu.internals import device_counters as _devctr
 from pathway_tpu.ops.bucketing import bucket_size, pad_rows
 from pathway_tpu.ops.distances import dot_scores, l2sq_distances, normalize
-from pathway_tpu.ops.shard_map_compat import shard_map
 from pathway_tpu.ops.topk import NEG_INF
+from pathway_tpu.parallel.mesh import require_single_process
 
 __all__ = ["ShardedKnnIndex"]
 
@@ -61,6 +61,7 @@ class ShardedKnnIndex:
     ):
         if metric not in ("cos", "dot", "l2sq"):
             raise ValueError(f"unknown metric {metric!r}")
+        require_single_process("ShardedKnnIndex")
         self.dim = dim
         self.metric = metric
         self.mesh = mesh
@@ -249,8 +250,7 @@ class ShardedKnnIndex:
         The reference's embed+index pipeline round-trips every embedding
         through host memory (python/pathway/xpacks/llm/embedders.py:
         270-327 -> index add); on a TPU the vector store lives in the
-        same HBM the encoder writes to, so the round trip is pure waste
-        — and on a tunneled link it dominates the pipeline.
+        same HBM the encoder writes to, so the round trip is pure waste.
         """
         n = len(keys) if n_valid is None else n_valid
         b = int(vectors.shape[0])
@@ -364,7 +364,7 @@ class ShardedKnnIndex:
             vals, pos = jax.lax.top_k(gs, k)
             return vals, jnp.take_along_axis(gi, pos, axis=1)
 
-        shmapped = shard_map(
+        shmapped = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), P(self.data_axis, None), P(self.data_axis)),
@@ -383,19 +383,20 @@ class ShardedKnnIndex:
         nq = queries.shape[0]
         if nq == 0 or not self._slot_of:
             return (None, nq, k, self._version)
-        k_eff = min(k, self.capacity)
+        # k is baked into the compiled program, and callers' k moves with
+        # the corpus (the segment layer over-fetches by its mask size, the
+        # adapter clamps to the live key count): bucket it like every
+        # other dynamic dimension — collect() trims each row back to k
+        k_eff = min(bucket_size(k, min_bucket=16), self.capacity)
         qb = pad_rows(queries, bucket_size(nq, min_bucket=1))
         _devctr.record_h2d(qb.nbytes)
         out = self._search_jit(k_eff)(jnp.asarray(qb), self._vectors, self._valid)
-        # start the device->host copy NOW, without blocking: on remote/
-        # tunneled backends the result transfer then overlaps later
-        # dispatches, so a serving loop with several handles in flight
-        # pays the link RTT once per pipeline fill, not once per query
-        # (measured ~6x on a stream of batch=1 queries)
+        # start the device->host copy NOW, without blocking: the result
+        # transfer then overlaps later dispatches, so a serving loop with
+        # several handles in flight waits for the device once per
+        # pipeline fill, not once per query
         for a in out:
-            copy_async = getattr(a, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
+            a.copy_to_host_async()
         self._inflight += 1
         return (out, nq, k, self._version)
 

@@ -305,10 +305,15 @@ class SegmentedIndex:
             mask.update(self._frozen_tombs)
             main = self.main
             n_main = len(main)
+            # over-fetch by the masked keys main actually holds, not by
+            # the mask's size: fresh upserts sit in the delta only, and a
+            # fetch that moves with the delta is a new compiled search
+            # program per distinct value on a device slab
+            shadowed = sum(1 for key in mask if self._has_in_main(key))
         probe = None
         main_hits: list[list[tuple[Any, float]]] | None = None
         if n_main:
-            fetch = min(k + len(mask), n_main)
+            fetch = min(k + shadowed, n_main)
             main_dispatch = getattr(main, "dispatch", None)
             if main_dispatch is not None:
                 t0_ns = _tracing.now_ns()

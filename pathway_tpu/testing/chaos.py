@@ -823,6 +823,9 @@ class ClusterDrill:
         )
 
         env = {
+            # the drill program is a host-only wordcount: its ranks must
+            # never reach for an accelerator the caller may be holding
+            "JAX_PLATFORMS": "cpu",
             "PATHWAY_CHECKPOINT_INTERVAL": str(self.checkpoint_interval_s),
             "PATHWAY_EPOCH_MAX_ROWS": str(self.epoch_max_rows),
             "PATHWAY_CLUSTER_HEARTBEAT_S": str(self.heartbeat_s),

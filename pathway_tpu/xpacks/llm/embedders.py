@@ -49,7 +49,10 @@ def _resolve_config(model: str):
 
     name = _PRESETS.get(model.lower())
     if name is None:
-        name = "MINILM_L6"
+        raise ValueError(
+            f"unknown encoder model {model!r}: not a local checkpoint "
+            f"directory and not one of the presets {sorted(_PRESETS)}"
+        )
     return getattr(enc, name)
 
 

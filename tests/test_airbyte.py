@@ -183,7 +183,7 @@ def test_airbyte_incremental_read_and_resume(fake_connector, tmp_path):
     assert saved["global"]["stream_states"][0]["stream_state"] == {"cursor": 2}
 
     # new rows arrive; a fresh pipeline resumes FROM THE SAVED STATE and
-    # extracts only the increment (the machinery VERDICT r3 asked for)
+    # extracts only the increment
     db.write_text(
         json.dumps(
             [

@@ -49,7 +49,7 @@ def test_join_left_id_duplicate_matches_raises():
         200 | x
         """
     )
-    # per-node containment (VERDICT r1): the id-collision error is routed
+    # per-node containment: the id-collision error is routed
     # to the error log and the run survives instead of aborting
     rows = _rows_of(t1.join(t2, t1.k == t2.k, id=pw.left.id).select(c=t2.b))
     assert rows == {}

@@ -653,7 +653,7 @@ def test_debug_parquet_roundtrip(tmp_path):
 
 
 def test_sql_intersect_except():
-    """INTERSECT/EXCEPT vs Table-op ground truth (VERDICT r3 item 10)."""
+    """INTERSECT/EXCEPT vs Table-op ground truth."""
     a = T(
         """
     x | y

@@ -277,7 +277,7 @@ def test_cross_encoder_scores():
 def test_encoder_long_doc_ring_attention_parity(mesh8):
     """The long-document path: TextEncoderModel with seq_mesh runs ring
     attention INSIDE every layer and must match local attention at seq
-    1024 with the same params (VERDICT r3 item 6)."""
+    1024 with the same params."""
     import dataclasses
 
     from pathway_tpu.models.encoder import TextEncoderModel

@@ -266,8 +266,7 @@ def test_adaptive_rag_answerer(tiny_embedder):
 
 def test_document_store_ingests_html_and_docx(tiny_embedder):
     """DocumentStore ingests binary .html/.docx via ParseUnstructured's
-    built-in extractors; chunks carry element-category metadata
-    (VERDICT r3 item 8)."""
+    built-in extractors; chunks carry element-category metadata."""
     from tests.test_parsers import _HTML, _minimal_docx
     from pathway_tpu.xpacks.llm.parsers import ParseUnstructured
 
