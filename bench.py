@@ -1709,7 +1709,9 @@ def bench_tracing(extra: dict) -> None:
 
     The tracing-on runs feed ``analysis/tracecrit.py`` and the
     per-stage p50/p99 attribution of the wordcount epochs and the
-    rag-serving requests lands in ``BENCH_trace.json``."""
+    rag-serving requests lands in ``BENCH_trace.json``, by
+    ``tracecrit.CATEGORIES`` (since PR 25 ``host_compute`` where older
+    artifacts say ``device``, and ``device_wait`` for the readbacks)."""
     import gc
 
     import pathway_tpu as pw

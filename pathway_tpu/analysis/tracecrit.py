@@ -14,9 +14,14 @@ per-category breakdown of a request always adds up to its end-to-end
 latency.  Categories bucket the stage names recorded across the repo:
 
 - ``queue_wait`` — admission + scheduler-lane queueing (``serve_sched``,
-  generation-queue wait)
+  generation-queue wait), the wait for the epoch cut, the hop back to
+  the REST loop
 - ``exchange``  — cluster pack/send/unpack + per-peer status waits
-- ``device``    — embed / search / generate / epoch compute
+- ``device_wait`` — the host blocked on the device and the link
+  (``encoder_readback``, ``search_readback``)
+- ``host_compute`` — embed / search / generate / epoch work as the host
+  clock sees it: tokenizing, padding, enqueueing, decoding, index
+  upkeep.  (Called ``device`` before PR 25; none of it is device time.)
 - ``merge``     — segment merge + sink/commit work
 - ``lock``      — spans explicitly named as lock waits
 - ``checkpoint``— snapshot serialization and writes
@@ -66,22 +71,31 @@ CATEGORY_OF: tuple[tuple[str, str], ...] = (
     ("pre_commit", "merge"),
     ("sink", "merge"),
     ("lock", "lock"),
-    ("serve_embed", "device"),
-    ("serve_generate", "device"),
-    ("serve_retrieve", "device"),
-    ("embed", "device"),
-    ("generate", "device"),
-    ("search", "device"),
-    ("dispatch", "device"),
-    ("collect", "device"),
-    ("epoch", "device"),
-    ("process", "device"),
-    ("ingest", "device"),
-    ("cut", "device"),
+    ("encoder_readback", "device_wait"),
+    ("search_readback", "device_wait"),
+    ("epoch_cut_wait", "queue_wait"),
+    ("rest_respond", "queue_wait"),
+    ("serve_embed", "host_compute"),
+    ("serve_generate", "host_compute"),
+    ("serve_retrieve", "host_compute"),
+    ("embed", "host_compute"),
+    ("generate", "host_compute"),
+    ("search", "host_compute"),
+    ("dispatch", "host_compute"),
+    ("collect", "host_compute"),
+    ("epoch", "host_compute"),
+    ("process", "host_compute"),
+    ("ingest", "host_compute"),
+    ("cut", "host_compute"),
+    ("rest_ingress", "host_compute"),
+    ("encoder", "host_compute"),  # encoder_tokenize, encoder_dispatch
+    ("index", "host_compute"),  # index_add, index_keyset_rebuild
+    ("slab", "host_compute"),  # slab_assign_slots, slab_scatter
+    ("connector_read", "host_compute"),
 )
 
-CATEGORIES = ("queue_wait", "exchange", "device", "merge", "lock",
-              "checkpoint", "other")
+CATEGORIES = ("queue_wait", "exchange", "device_wait", "host_compute",
+              "merge", "lock", "checkpoint", "other")
 
 
 def categorize(stage: str) -> str:

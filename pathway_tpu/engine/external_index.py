@@ -27,6 +27,7 @@ from typing import Any, Callable, Protocol, Sequence
 from pathway_tpu.engine.graph import EngineGraph, Node
 from pathway_tpu.engine.stream import Batch, Update, consolidate, per_key_changes
 from pathway_tpu.internals import api
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.keys import Pointer
 
 
@@ -120,13 +121,15 @@ class ExternalIndexNode(Node):
         changed = False
         if removals:
             try:
-                self.adapter.remove(removals)
+                with _tracing.span("index_add", {"removed": len(removals)}):
+                    self.adapter.remove(removals)
                 changed = True
             except Exception as e:  # noqa: BLE001
                 self._log_error(f"index remove failed: {e!r}")
         if additions:
             try:
-                self.adapter.add(additions)  # upsert semantics
+                with _tracing.span("index_add", {"added": len(additions)}):
+                    self.adapter.add(additions)  # upsert semantics
                 changed = True
                 if hasattr(self.adapter, "set_meta"):
                     for key, _payload in additions:

@@ -32,6 +32,7 @@ from pathway_tpu.engine.columnar import ColumnarBatch, extend_batch
 from pathway_tpu.engine.graph import EngineGraph, InputNode, Node, RunContext
 from pathway_tpu.engine.stream import TIME_STEP, Batch, Update
 from pathway_tpu.internals import api
+from pathway_tpu.internals import device_counters as _devctr
 from pathway_tpu.internals import native as _native
 from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.keys import Pointer
@@ -560,6 +561,27 @@ class Scheduler:
             serving.push_pressure("engine", level)
         except Exception:
             pass  # monitoring-path best effort; never kill the epoch loop
+
+    @staticmethod
+    def _note_epoch_cut(
+        ectx: "_tracing.TraceContext | None",
+        origin_ns: int | None,
+        cut_ns: int,
+        rows: int,
+        inject: dict[int, Batch],
+        rest_nids: set[int],
+    ) -> dict:
+        """One epoch was cut: count it, record ``epoch_cut_wait`` (first
+        row buffered -> cut, from the instants ``LatencyProbe`` is fed)
+        under the epoch's trace, and return what ``epoch_process`` says
+        of the epoch: its rows and how many of them are REST requests."""
+        _devctr.bump(epochs=1, epoch_rows=rows)
+        if origin_ns is not None:
+            _tracing.record_span(
+                "epoch_cut_wait", origin_ns, cut_ns, ctx=ectx, args={"rows": rows}
+            )
+        requests = sum(len(inject[nid]) for nid in rest_nids if nid in inject)
+        return {"rows": rows, "requests": requests}
 
     def _settle_s(self, last_epoch_s: float) -> float:
         """Adaptive micro-batch settle window (seconds): after the last
@@ -1146,6 +1168,8 @@ class Scheduler:
         primaries = [n for n in live_inputs if not getattr(n, "auxiliary", False)]
         auxiliaries = [n for n in live_inputs if getattr(n, "auxiliary", False)]
         open_subjects = {n.id for n in primaries}
+        #: inputs whose rows are REST requests (io/http names them "rest:<route>")
+        rest_nids = {n.id for n in live_inputs if n.name.startswith("rest:")}
         buffers: dict[int, list[Update]] = defaultdict(list)
         lat = self.latency
         now_ns = lat.now_ns
@@ -1311,8 +1335,11 @@ class Scheduler:
                     if _tracing.enabled()
                     else None
                 )
+                _eargs = self._note_epoch_cut(
+                    _ectx, origin_ns, cut_ns, rows_buffered, inject, rest_nids
+                )
                 with _tracing.use(_ectx), _tracing.span(
-                    "epoch_process", {"epoch": int(t)}
+                    "epoch_process", {"epoch": int(t), **_eargs}
                 ):
                     self.run_epoch(t, inject)
                 last_epoch_s = _time.monotonic() - ep0
@@ -1497,6 +1524,7 @@ class Scheduler:
         }
         my_aux = [n for n, _s in my_inputs if getattr(n, "auxiliary", False)]
         open_subjects = set(my_primaries)
+        rest_nids = {n.id for n, _s in my_inputs if n.name.startswith("rest:")}
         buffers: dict[int, list[Update]] = defaultdict(list)
         round_no = 0
         commit_requested = False
@@ -1694,8 +1722,11 @@ class Scheduler:
                 )
                 # only exchange at operators data can actually reach — the
                 # closure is identical on every worker (same gathered ids)
+                _eargs = self._note_epoch_cut(
+                    _ectx, origin_ns, cut_ns, rows_buffered, inject, rest_nids
+                )
                 with _tracing.use(_ectx), _tracing.span(
-                    "epoch_process", {"round": round_no - 1, "tid": tid}
+                    "epoch_process", {"round": round_no - 1, "tid": tid, **_eargs}
                 ):
                     self.run_epoch(
                         t, inject, ctx=ctx, cluster=cluster, tid=tid,

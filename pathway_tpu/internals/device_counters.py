@@ -1,9 +1,10 @@
-"""Live device-plane counters: jit compiles and host<->device bytes.
+"""Live device-plane counters: jit compiles, host<->device bytes, and
+what each dispatch carried.
 
 The static device analyzer (``pathway_tpu/analysis/device.py``) PREDICTS
 where recompiles and transfers happen; this module MEASURES them, the
-same estimated-vs-measured join PR 15 gave memory capacity.  Three
-counters, all monotonic:
+same estimated-vs-measured join PR 15 gave memory capacity.  All
+counters are monotonic:
 
 - ``jit_compiles`` — one per actual XLA backend compile, observed via
   ``jax.monitoring``'s ``/jax/core/compile/backend_compile_duration``
@@ -17,9 +18,19 @@ counters, all monotonic:
   transfers *we* issue, which is exactly the set the analyzer reasons
   about.
 
-Exported as ``pathway_tpu_jit_compiles_total`` /
-``pathway_tpu_h2d_bytes_total`` / ``pathway_tpu_d2h_bytes_total`` on
-``/metrics`` and joined against the static prediction on ``/status``.
+- dispatch counters, :func:`bump`-ed once per dispatch where the work
+  happens, never per row: ``encoder_*`` (``JittedEncoder._dispatch``:
+  rows and tokens as given and as padded), ``search_*``
+  (``ShardedKnnIndex.dispatch``), ``scatter_*`` (``add_batch`` /
+  ``add_batch_device``), ``epochs`` / ``epoch_rows`` (the scheduler's
+  cut) and ``rest_requests`` / ``rest_responses`` (``io/http``).
+
+:func:`snapshot` is the one door through which the benchmark reads the
+program: the counters above and the span recorder's stage totals
+(``internals/tracing.stage_totals``) as flat keys ``span_ns.<stage>`` /
+``span_count.<stage>``.  Every key is exported on ``/metrics`` as
+``pathway_tpu_<key>_total`` (the stage totals with a ``stage`` label)
+and the counters are joined against the static prediction on ``/status``.
 Importing this module never imports jax; ``install()`` is called lazily
 by the first transfer-recording caller (all of which already have jax
 loaded) and degrades to transfer-only counting when ``jax.monitoring``
@@ -31,7 +42,10 @@ from __future__ import annotations
 import threading
 from typing import Any
 
+from pathway_tpu.internals import tracing
+
 __all__ = [
+    "bump",
     "install",
     "installed",
     "record_h2d",
@@ -52,12 +66,30 @@ _counters: dict[str, int] = {
     "h2d_transfers": 0,
     "d2h_bytes": 0,
     "d2h_transfers": 0,
+    "encoder_dispatches": 0,
+    "encoder_rows": 0,
+    "encoder_rows_padded": 0,
+    "encoder_tokens": 0,
+    "encoder_tokens_padded": 0,
+    "search_dispatches": 0,
+    "search_queries": 0,
+    "search_queries_padded": 0,
+    "scatter_dispatches": 0,
+    "scatter_rows": 0,
+    "scatter_rows_padded": 0,
+    "epochs": 0,
+    "epoch_rows": 0,
+    "rest_requests": 0,
+    "rest_responses": 0,
 }
 
 
-def _bump(key: str, amount: int) -> None:
+def bump(**amounts: int) -> None:
+    """Add to several counters at once (one lock round per dispatch).
+    An unknown name is a ``KeyError``: the names above are the interface."""
     with _lock:
-        _counters[key] += amount
+        for key, amount in amounts.items():
+            _counters[key] += int(amount)
 
 
 def _on_duration(event: str, duration: float, **kw: Any) -> None:
@@ -65,7 +97,7 @@ def _on_duration(event: str, duration: float, **kw: Any) -> None:
     # jaxpr_trace / jaxpr_to_mlir events fire on cheap retraces too, so
     # only the backend event counts as "a compile happened"
     if event.endswith("backend_compile_duration"):
-        _bump("jit_compiles", 1)
+        bump(jit_compiles=1)
 
 
 def install() -> bool:
@@ -98,15 +130,13 @@ def record_h2d(nbytes: int) -> None:
     """Count one host->device upload of ``nbytes`` (call at the repo's
     ``device_put``/np->jnp coercion sites)."""
     install()
-    _bump("h2d_bytes", int(nbytes))
-    _bump("h2d_transfers", 1)
+    bump(h2d_bytes=nbytes, h2d_transfers=1)
 
 
 def record_d2h(nbytes: int) -> None:
     """Count one device->host readback of ``nbytes``."""
     install()
-    _bump("d2h_bytes", int(nbytes))
-    _bump("d2h_transfers", 1)
+    bump(d2h_bytes=nbytes, d2h_transfers=1)
 
 
 def compile_count() -> int:
@@ -117,10 +147,14 @@ def compile_count() -> int:
 
 
 def snapshot() -> dict[str, int]:
-    """Point-in-time copy of all counters (for /metrics and /status)."""
+    """Point-in-time copy of all counters and of the span recorder's
+    stage totals (for /metrics, /status and the benchmark)."""
     with _lock:
         out = dict(_counters)
     out["listener_installed"] = 1 if _installed else 0
+    for stage, (count, total_ns) in tracing.stage_totals().items():
+        out[f"span_ns.{stage}"] = total_ns
+        out[f"span_count.{stage}"] = count
     return out
 
 

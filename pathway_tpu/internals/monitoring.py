@@ -405,28 +405,24 @@ def pressure_stats(sched: Any) -> dict[str, Any]:
 
 def device_stats() -> dict[str, Any]:
     """Predicted-vs-observed device-plane join.  ``counters`` is the
-    live side (jit compiles, H2D/D2H bytes — zeros until a device module
-    runs); ``static`` is the analyzer's prediction over the device
-    source.  Keyed off ``sys.modules`` like :func:`serving_stats`: a
-    host-only process that never imported the device layer pays neither
-    a jax import nor an AST sweep on every scrape."""
+    live side (jit compiles, H2D/D2H bytes, what each dispatch carried,
+    epochs and REST requests, the span recorder's stage totals — the
+    device's zeros until a device module runs); ``static`` is the
+    analyzer's prediction over the device source.  Keyed off
+    ``sys.modules`` like :func:`serving_stats`: a host-only process that
+    never imported jax pays neither a jax import nor the AST sweep."""
     import sys
 
-    if sys.modules.get("pathway_tpu.internals.device_counters") is None:
-        return {}
-    out: dict[str, Any] = {}
-    try:
-        from pathway_tpu.internals import device_counters
+    from pathway_tpu.internals import device_counters
 
-        out["counters"] = device_counters.snapshot()
-    except Exception:
-        return {}
-    try:
-        from pathway_tpu.analysis.device import device_profile
+    out: dict[str, Any] = {"counters": device_counters.snapshot()}
+    if "jax" in sys.modules:
+        try:
+            from pathway_tpu.analysis.device import device_profile
 
-        out["static"] = device_profile()
-    except Exception:
-        pass
+            out["static"] = device_profile()
+        except Exception:
+            pass
     return out
 
 

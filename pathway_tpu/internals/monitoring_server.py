@@ -374,22 +374,21 @@ def _metrics_text(sched: Any) -> str:
     device = _device_snapshot()
     ctr = device.get("counters", {})
     if ctr:
-        lines.append("# TYPE pathway_tpu_jit_compiles_total counter")
-        lines.append(
-            f"pathway_tpu_jit_compiles_total {ctr.get('jit_compiles', 0)}"
-        )
-        lines.append("# TYPE pathway_tpu_h2d_bytes_total counter")
-        lines.append(f"pathway_tpu_h2d_bytes_total {ctr.get('h2d_bytes', 0)}")
-        lines.append("# TYPE pathway_tpu_d2h_bytes_total counter")
-        lines.append(f"pathway_tpu_d2h_bytes_total {ctr.get('d2h_bytes', 0)}")
-        lines.append("# TYPE pathway_tpu_h2d_transfers_total counter")
-        lines.append(
-            f"pathway_tpu_h2d_transfers_total {ctr.get('h2d_transfers', 0)}"
-        )
-        lines.append("# TYPE pathway_tpu_d2h_transfers_total counter")
-        lines.append(
-            f"pathway_tpu_d2h_transfers_total {ctr.get('d2h_transfers', 0)}"
-        )
+        # every key of device_counters.snapshot() but the listener flag
+        # (a 0/1 state, on /status): plain counters as
+        # pathway_tpu_<key>_total, the span recorder's stage totals
+        # (`span_ns.<stage>`, `span_count.<stage>`) under a stage label
+        last = None
+        for key in sorted(ctr):  # sorted: a name's stages follow one another
+            if key == "listener_installed":
+                continue
+            name, _, stage = key.partition(".")
+            metric = f"pathway_tpu_{name}_total"
+            if metric != last:
+                last = metric
+                lines.append(f"# TYPE {metric} counter")
+            label = f'{{stage="{stage}"}}' if stage else ""
+            lines.append(f"{metric}{label} {ctr[key]}")
         static = device.get("static", {})
         if static:
             lines.append(

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Any
 
 from pathway_tpu.engine.scheduler import Scheduler
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.parse_graph import G
 
 
@@ -331,7 +332,11 @@ class _ManagedGc:
         # gen-2 cycles (promoted survivors) cannot leak over a long
         # streaming run
         t0 = self._time.monotonic()
-        self._gc.collect(2 if self._sweeps % 8 == 0 else 1)
+        generation = 2 if self._sweeps % 8 == 0 else 1
+        # a sweep holds the GIL against every thread of the process: the
+        # span puts a stall it causes beside the requests that waited
+        with _tracing.span("gc_sweep", {"generation": generation}):
+            self._gc.collect(generation)
         self._last_sweep = self._time.monotonic()
         cost = self._last_sweep - t0
         self._next_due = self._last_sweep + max(self._interval, cost / 0.02)

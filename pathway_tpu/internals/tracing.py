@@ -16,7 +16,26 @@ Record layout (one tuple per span)::
 CLOCK_MONOTONIC is machine-wide, so spans recorded by *different
 processes on one host* share a timebase and stitch into one causal
 timeline without clock translation (the 2-proc chaos drills rely on
-this).
+this).  It is the one clock of the measured path: the native
+``monotonic_ns`` behind ``LatencyProbe.now_ns`` (``steady_clock``) and
+the benchmark harness's ``time.monotonic()`` read it too
+(``tests/test_tracing.py`` holds the first two within a millisecond).
+The harness writes a ``bench_mark`` annotation into its profile at a
+CLOCK_MONOTONIC instant it records (``benchmark/trace.py``), so a span
+lies on the profile's clock at
+``profile_ns = span_ns + (bench_mark.start_ns - mark_mono_ns)``.
+While a ``jax.profiler`` session runs, every :func:`span` block also
+enters a ``jax.profiler.TraceAnnotation`` of its stage name, so the
+stages lie in the ``.xplane.pb``'s host plane over the device lines
+with no translation (a TraceMe costs 0.4 us outside a session; jax is
+looked up in ``sys.modules``, never imported from here).
+
+**Stage totals.**  Every recorded span also adds its duration to a
+per-stage ``[count, total_ns]`` on the recording thread's ring (no lock,
+no further clock read); :func:`stage_totals` sums them over rings.  They
+never wrap with the ring, grow for the life of the process and stay
+zero under ``PATHWAY_TRACE=0``: what ``device_counters.snapshot()``
+carries to the benchmark as ``span_ns.<stage>`` / ``span_count.<stage>``.
 
 Sampling: the ring is **always on** (that is what makes it a flight
 recorder — the last ``ring_size`` spans per thread are always there for
@@ -84,6 +103,7 @@ __all__ = [
     "set_ambient",
     "set_rank",
     "span",
+    "stage_totals",
     "use",
 ]
 
@@ -147,7 +167,7 @@ _atexit_installed = False
 class _Ring:
     """One thread's span ring: preallocated slots, lock-free append."""
 
-    __slots__ = ("buf", "idx", "cap", "thread_name", "id_next")
+    __slots__ = ("buf", "idx", "cap", "thread_name", "id_next", "totals")
 
     def __init__(self, cap: int, thread_name: str, id_seed: int):
         self.cap = cap
@@ -155,6 +175,8 @@ class _Ring:
         self.idx = 0
         self.thread_name = thread_name
         self.id_next = id_seed
+        #: stage -> [count, total_ns]; written by the owning thread only
+        self.totals: dict[str, list[int]] = {}
 
     def snapshot(self) -> list[tuple]:
         """Copy the live records in append order (dump path; the copy is
@@ -348,6 +370,12 @@ def record_span(
         rec = (0, span_id, 0, stage, _rank, t0_ns, t1_ns, False, args)
     ring.buf[ring.idx % ring.cap] = rec
     ring.idx += 1
+    tot = ring.totals.get(stage)
+    if tot is None:
+        ring.totals[stage] = [1, t1_ns - t0_ns]
+    else:
+        tot[0] += 1
+        tot[1] += t1_ns - t0_ns
     return span_id
 
 
@@ -369,13 +397,37 @@ def record_spans(
     i, nid = ring.idx, ring.id_next
     trace_id, parent, sampled = ctx.trace_id, ctx.span_id, ctx.sampled
     rank = _rank
+    totals = ring.totals
     for stage, t0_ns, t1_ns, args in spans:
         nid += 1
         buf[i % cap] = (trace_id, nid, parent, stage, rank,
                         t0_ns, t1_ns, sampled, args)
         i += 1
+        tot = totals.get(stage)
+        if tot is None:
+            totals[stage] = [1, t1_ns - t0_ns]
+        else:
+            tot[0] += 1
+            tot[1] += t1_ns - t0_ns
     ring.id_next = nid
     ring.idx = i
+
+
+#: ``jax.profiler.TraceAnnotation`` once jax is loaded in this process
+_trace_annotation: Any = None
+
+
+def _find_trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` if this process has imported jax
+    (found once, then kept), else None: the recorder never imports jax."""
+    global _trace_annotation
+    if "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # jax is mid-import on another thread
+            return None
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
 
 
 class _Span:
@@ -384,7 +436,7 @@ class _Span:
     record_span reads), so entering a span allocates no extra object."""
 
     __slots__ = ("stage", "args", "parent", "t0_ns", "prev",
-                 "trace_id", "span_id", "sampled")
+                 "trace_id", "span_id", "sampled", "ann")
 
     def __init__(self, stage: str, args: dict | None, ctx: TraceContext | None):
         self.stage = stage
@@ -413,6 +465,12 @@ class _Span:
             self.span_id = ring.id_next
             self.sampled = ctx.sampled
             tls.ctx = self
+        annotation = _trace_annotation or _find_trace_annotation()
+        if annotation is not None:
+            self.ann = annotation(self.stage)
+            self.ann.__enter__()
+        else:
+            self.ann = None
         self.t0_ns = _monotonic_ns()
         return self
 
@@ -422,6 +480,8 @@ class _Span:
         if not _cfg.on or self.t0_ns == 0:
             return
         t1 = _monotonic_ns()
+        if self.ann is not None:
+            self.ann.__exit__(et, ev, tb)
         ring = tls.ring
         if ring is None:
             ring = _make_ring()
@@ -436,6 +496,12 @@ class _Span:
                    False, self.args)
         ring.buf[ring.idx % ring.cap] = rec
         ring.idx += 1
+        tot = ring.totals.get(self.stage)
+        if tot is None:
+            ring.totals[self.stage] = [1, t1 - self.t0_ns]
+        else:
+            tot[0] += 1
+            tot[1] += t1 - self.t0_ns
 
 
 def span(stage: str, args: dict | None = None,
@@ -468,6 +534,22 @@ def snapshot_records() -> list[tuple]:
     out: list[tuple] = []
     for ring in rings:
         out.extend(ring.snapshot())
+    return out
+
+
+def stage_totals() -> dict[str, tuple[int, int]]:
+    """``{stage: (count, total_ns)}`` of every span recorded since the
+    process started, summed over threads.  Monotonic (only the test-only
+    :func:`reset` zeroes it); a thread recording meanwhile is at worst
+    read one span short."""
+    with _registry_mutex:
+        rings = list(_rings)
+    out: dict[str, tuple[int, int]] = {}
+    for ring in rings:
+        # one C-level copy under the GIL: the owner may add a stage meanwhile
+        for stage, (count, total_ns) in list(ring.totals.items()):
+            c, t = out.get(stage, (0, 0))
+            out[stage] = (c + count, t + total_ns)
     return out
 
 

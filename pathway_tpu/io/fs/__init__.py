@@ -11,6 +11,7 @@ from typing import Any, Callable
 from pathway_tpu.engine.columnar import columnar_enabled as _columnar_enabled
 from pathway_tpu.internals import native as _native_mod
 from pathway_tpu.internals import schema as sch
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.keys import keys_for_values, ref_scalar
 from pathway_tpu.internals.table import Table
 from pathway_tpu.io._connector import (
@@ -445,7 +446,7 @@ class _FilesSource(RowSource):
         seqs: dict[str, int] = {}
         parsers: dict[str, Callable] = {}
         while True:
-            emitted = False
+            read_t0_ns = 0  # first read of this pass; 0: nothing read yet
             for fp in _list_files(self.path):
                 start = offsets.get(fp, 0)
                 try:
@@ -453,14 +454,19 @@ class _FilesSource(RowSource):
                 except OSError:
                     continue
                 if size > start:
+                    if not read_t0_ns:
+                        read_t0_ns = _tracing.now_ns()
                     if fp not in parsers:
                         parsers[fp] = self.parser_factory(fp)
                     offsets[fp], seqs[fp] = self._emit_file(
                         events, fp, start, seqs.get(fp, 0), parsers[fp]
                     )
-                    emitted = True
-            if emitted:
+            if read_t0_ns:
                 events.commit()
+                # one span per committed batch: read + parse + enqueue
+                _tracing.record_span(
+                    "connector_read", read_t0_ns, _tracing.now_ns()
+                )
             if self.mode == "static":
                 return
             if events.stopped:

@@ -16,9 +16,11 @@ import logging
 import threading
 from typing import Any, Callable
 
+from pathway_tpu.internals import device_counters as _devctr
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals import keys as K
 from pathway_tpu.internals import schema as sch
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.table import Table
 from pathway_tpu.io._connector import RowSource, coerce_row, fmt_value, input_table
 from pathway_tpu.io._subscribe import subscribe
@@ -87,6 +89,8 @@ class PathwayWebserver:
         app = web.Application()
 
         async def dispatch(request: "web.Request") -> "web.Response":
+            # where the request's `rest_ingress` span starts (RestServerSubject)
+            request["pathway_t0_ns"] = _tracing.now_ns()
             handler = self._routes.get((request.method, request.path))
             if handler is None:
                 return web.json_response({"error": "not found"}, status=404)
@@ -162,6 +166,10 @@ class RestServerSubject(RowSource):
         self.admission = admission
         self.tenant_field = tenant_field
         self.futures: dict[K.Pointer, asyncio.Future] = {}
+        #: per in-flight request: [when the engine resolved it (ns, 0
+        #: until then), that epoch's time]; resolve() writes them on the
+        #: engine thread, _handle reads them back on the loop
+        self._resolved: dict[K.Pointer, list] = {}
         self._seq = 0
         self._events: Any = None
         self._closed = threading.Event()
@@ -212,23 +220,47 @@ class RestServerSubject(RowSource):
             loop = asyncio.get_running_loop()
             future: asyncio.Future = loop.create_future()
             self.futures[key] = future
+            ctx = _tracing.new_trace() if _tracing.enabled() else None
+            resolved = self._resolved[key] = [0, None]
             self._events.add(key, row)
             self._events.commit()
+            _devctr.bump(rest_requests=1)
+            if ctx is not None:
+                # the request's clock starts where the webserver took it up
+                ctx.t0_ns = request["pathway_t0_ns"]
+                _tracing.record_span(
+                    "rest_ingress", ctx.t0_ns, _tracing.now_ns(), ctx=ctx
+                )
             try:
                 result = await asyncio.wait_for(future, timeout=120)
             finally:
                 self.futures.pop(key, None)
+                self._resolved.pop(key, None)
                 if self.delete_completed_queries:
                     self._events.remove(key, row)
                     self._events.commit()
+                t1_ns = _tracing.now_ns()
+                if resolved[0]:
+                    _devctr.bump(rest_responses=1)
+                    _tracing.record_span(
+                        "rest_respond", resolved[0], t1_ns, ctx=ctx,
+                        args={"epoch": resolved[1]},
+                    )
+                _tracing.finish_request(ctx, t1_ns)  # slow: tail-kept
         finally:
             if ticket is not None:
                 ticket.release()
         return result
 
-    def resolve(self, key: K.Pointer, value: Any) -> None:
+    def resolve(self, key: K.Pointer, value: Any, time: int | None = None) -> None:
+        """Hand ``value`` to the request waiting under ``key`` (engine
+        thread); ``time`` is the epoch that produced it, the causal link
+        from the request's ``rest_respond`` span to the epoch's spans."""
         future = self.futures.get(key)
         if future is not None and not future.done():
+            resolved = self._resolved.get(key)
+            if resolved is not None:
+                resolved[:] = _tracing.now_ns(), time
             loop = future.get_loop()
             loop.call_soon_threadsafe(
                 lambda: None if future.done() else future.set_result(value)
@@ -285,7 +317,7 @@ def rest_connector(
         def on_change(key: K.Pointer, row: dict, time: int, is_addition: bool) -> None:
             if not is_addition:
                 return
-            subject.resolve(key, fmt_value(row[result_col]))
+            subject.resolve(key, fmt_value(row[result_col]), time)
 
         subscribe(responses, on_change=on_change, name="rest_response")
 

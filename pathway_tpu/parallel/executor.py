@@ -25,6 +25,7 @@ from pathway_tpu.models.encoder import (
     encoder_param_specs,
 )
 from pathway_tpu.internals import device_counters as _devctr
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.models.tokenizer import Tokenizer, get_tokenizer
 from pathway_tpu.ops.bucketing import bucket_size
 from pathway_tpu.parallel.mesh import require_single_process
@@ -251,25 +252,35 @@ class JittedEncoder:
         the readback of chunk i overlaps the tokenize+compute of chunk
         i+1.  ``start_host_copy=False`` for consumers that keep the
         output on device (``encode_into``)."""
-        ids, mask, tps, n = self._pad_batch(ids, mask, tps)
-        if self.sequence_axis is not None and ids.shape[1] < self.max_len:
-            # SP shards the sequence dimension: pad to the full max_len so
-            # every device holds an equal block
-            pad = ((0, 0), (0, self.max_len - ids.shape[1]))
-            ids = np.pad(ids, pad)
-            mask = np.pad(mask, pad)
-            tps = np.pad(tps, pad)
-        if self._narrow_ids:
-            ids = ids.astype(np.int16, copy=False)
-            mask = mask.astype(np.uint8, copy=False)
-            tps = tps.astype(np.uint8, copy=False)
-        _devctr.record_h2d(ids.nbytes + mask.nbytes + tps.nbytes)
-        args = [jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(tps)]
-        if self._in_batch_sharding is not None:
-            args = [jax.device_put(a, self._in_batch_sharding) for a in args]
-        out = self._apply(self.params, *args)
-        if start_host_copy:
-            out.copy_to_host_async()
+        with _tracing.span("encoder_dispatch") as sp:
+            ids, mask, tps, n = self._pad_batch(ids, mask, tps)
+            if self.sequence_axis is not None and ids.shape[1] < self.max_len:
+                # SP shards the sequence dimension: pad to the full max_len so
+                # every device holds an equal block
+                pad = ((0, 0), (0, self.max_len - ids.shape[1]))
+                ids = np.pad(ids, pad)
+                mask = np.pad(mask, pad)
+                tps = np.pad(tps, pad)
+            rows_padded, length = ids.shape
+            sp.args = {"rows": n, "rows_padded": rows_padded, "length": length}
+            _devctr.bump(
+                encoder_dispatches=1,
+                encoder_rows=n,
+                encoder_rows_padded=rows_padded,
+                encoder_tokens=mask[:n].sum(),
+                encoder_tokens_padded=rows_padded * length,
+            )
+            if self._narrow_ids:
+                ids = ids.astype(np.int16, copy=False)
+                mask = mask.astype(np.uint8, copy=False)
+                tps = tps.astype(np.uint8, copy=False)
+            _devctr.record_h2d(ids.nbytes + mask.nbytes + tps.nbytes)
+            args = [jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(tps)]
+            if self._in_batch_sharding is not None:
+                args = [jax.device_put(a, self._in_batch_sharding) for a in args]
+            out = self._apply(self.params, *args)
+            if start_host_copy:
+                out.copy_to_host_async()
         return out, n
 
     def _run(self, ids: np.ndarray, mask: np.ndarray, tps: np.ndarray) -> np.ndarray:
@@ -278,7 +289,8 @@ class JittedEncoder:
 
     @staticmethod
     def _readback(out: Any) -> np.ndarray:
-        host = np.asarray(out)
+        with _tracing.span("encoder_readback"):
+            host = np.asarray(out)
         _devctr.record_d2h(host.nbytes)
         return host
 
@@ -289,11 +301,12 @@ class JittedEncoder:
         :meth:`_rows_per_dispatch` at that length."""
         for i in range(0, len(texts), self.max_batch):
             sl = slice(i, i + self.max_batch)
-            ids, mask, tps = self.tokenizer.encode_batch(
-                texts[sl],
-                pair=None if pair is None else pair[sl],
-                max_len=self.max_len,
-            )
+            with _tracing.span("encoder_tokenize"):
+                ids, mask, tps = self.tokenizer.encode_batch(
+                    texts[sl],
+                    pair=None if pair is None else pair[sl],
+                    max_len=self.max_len,
+                )
             rows = self._rows_per_dispatch(ids.shape[1])
             for j in range(0, ids.shape[0], rows):
                 yield ids[j : j + rows], mask[j : j + rows], tps[j : j + rows]

@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from pathway_tpu.internals import device_counters as _devctr
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.ops.bucketing import bucket_size, pad_rows
 from pathway_tpu.ops.distances import dot_scores, l2sq_distances, normalize
 from pathway_tpu.ops.topk import NEG_INF
@@ -225,18 +226,21 @@ class ShardedKnnIndex:
         if n == 0:
             return
         b = bucket_size(n)
-        slots = self._assign_slots(keys, pad_to=b)
-        if self.metric == "cos":
-            norms = np.linalg.norm(vectors, axis=1, keepdims=True)
-            np.maximum(norms, 1e-30, out=norms)
-            vectors = vectors / norms
-        vals = vectors.astype(np.dtype(self.dtype), copy=False)
-        vals = pad_rows(vals, b)
-        _devctr.record_h2d(vals.nbytes + slots.nbytes)
-        scatter = self._scatter_set if self._inflight == 0 else self._scatter_set_safe
-        self._vectors, self._valid = scatter(
-            self._vectors, self._valid, jnp.asarray(slots), jnp.asarray(vals)
-        )
+        with _tracing.span("slab_assign_slots"):
+            slots = self._assign_slots(keys, pad_to=b)
+        with _tracing.span("slab_scatter"):
+            if self.metric == "cos":
+                norms = np.linalg.norm(vectors, axis=1, keepdims=True)
+                np.maximum(norms, 1e-30, out=norms)
+                vectors = vectors / norms
+            vals = vectors.astype(np.dtype(self.dtype), copy=False)
+            vals = pad_rows(vals, b)
+            _devctr.record_h2d(vals.nbytes + slots.nbytes)
+            _devctr.bump(scatter_dispatches=1, scatter_rows=n, scatter_rows_padded=b)
+            scatter = self._scatter_set if self._inflight == 0 else self._scatter_set_safe
+            self._vectors, self._valid = scatter(
+                self._vectors, self._valid, jnp.asarray(slots), jnp.asarray(vals)
+            )
 
     def add_batch_device(
         self, keys: Sequence[Any], vectors: Any, n_valid: int | None = None
@@ -258,19 +262,22 @@ class ShardedKnnIndex:
             raise ValueError(f"vectors dim {vectors.shape[1]} != {self.dim}")
         if n > b:
             raise ValueError(f"{n} keys but only {b} vector rows")
-        slots = self._assign_slots(keys, pad_to=b)
-        scatter = (
-            self._scatter_set_device
-            if self._inflight == 0
-            else self._scatter_set_device_safe
-        )
-        self._vectors, self._valid = scatter(
-            self._vectors,
-            self._valid,
-            jnp.asarray(slots),
-            vectors,
-            self.metric == "cos",
-        )
+        with _tracing.span("slab_assign_slots"):
+            slots = self._assign_slots(keys, pad_to=b)
+        with _tracing.span("slab_scatter"):
+            _devctr.bump(scatter_dispatches=1, scatter_rows=n, scatter_rows_padded=b)
+            scatter = (
+                self._scatter_set_device
+                if self._inflight == 0
+                else self._scatter_set_device_safe
+            )
+            self._vectors, self._valid = scatter(
+                self._vectors,
+                self._valid,
+                jnp.asarray(slots),
+                vectors,
+                self.metric == "cos",
+            )
 
     def remove(self, keys: Sequence[Any]) -> None:
         slots = []
@@ -390,6 +397,9 @@ class ShardedKnnIndex:
         k_eff = min(bucket_size(k, min_bucket=16), self.capacity)
         qb = pad_rows(queries, bucket_size(nq, min_bucket=1))
         _devctr.record_h2d(qb.nbytes)
+        _devctr.bump(
+            search_dispatches=1, search_queries=nq, search_queries_padded=qb.shape[0]
+        )
         out = self._search_jit(k_eff)(jnp.asarray(qb), self._vectors, self._valid)
         # start the device->host copy NOW, without blocking: the result
         # transfer then overlaps later dispatches, so a serving loop with
@@ -426,7 +436,8 @@ class ShardedKnnIndex:
             self._quarantine.clear()
         # one host readback for both arrays (each device_get is a full
         # host<->device round trip; they dominate single-query latency)
-        vals, idx = jax.device_get(out)
+        with _tracing.span("search_readback"):
+            vals, idx = jax.device_get(out)
         _devctr.record_d2h(vals.nbytes + idx.nbytes)
         vals = vals[:nq]
         idx = idx[:nq]

@@ -221,7 +221,8 @@ class SegmentedIndex:
             ):
                 with self._main_mutex:
                     self.main.add(list(items))
-                self._keys = set(self._main_keys())
+                with _tracing.span("index_keyset_rebuild"):
+                    self._keys = set(self._main_keys())
                 return
             for key, vec in items:
                 self._tombs.discard(key)

@@ -938,7 +938,7 @@ class ClusterDrill:
 
 
 _INDEX_DRILL_PROGRAM = """
-import json, os, sys
+import json, os, sys, time
 sys.path.insert(0, {repo!r})
 import pathway_tpu as pw
 from pathway_tpu.persistence import Backend, Config, PersistenceMode
@@ -982,6 +982,23 @@ class DocSubject(pw.io.python.ConnectorSubject):
                 n += 1
                 if n % {commit_every} == 0:
                     self.commit()
+                if n == {pause_after}:
+                    self._let_a_checkpoint_hold_the_first_merge()
+
+    def _let_a_checkpoint_hold_the_first_merge(self):
+        # The kill comes at merge #2.  Recovery is only a drill of
+        # restore-then-replay if the checkpoint it restores holds merge
+        # #1: a generation killed before any such checkpoint restores the
+        # empty index and replays the whole log as one batch, which the
+        # segment layer bulk-loads with no merge at all.  So, with the
+        # delta a third full: wait for merges_total >= 1, then for longer
+        # than the checkpoint interval, so the next epoch checkpoints it.
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline:
+            if reply._node.adapter.stats().get("merges_total", 0) >= 1:
+                break
+            time.sleep(0.005)
+        time.sleep({checkpoint_pause_s})
 
 
 docs = pw.io.python.read(DocSubject(), schema=Doc)
@@ -1146,6 +1163,12 @@ class IndexDrill(ClusterDrill):
                     delta_cap=self.delta_cap,
                     k=self.k,
                     commit_every=self.epoch_max_rows,
+                    # one commit past the first merge's trigger: the delta
+                    # is then far from its cap, so merge #2 (the kill) is
+                    # two epochs away from the checkpoint that follows
+                    pause_after=(self.delta_cap // self.epoch_max_rows + 1)
+                    * self.epoch_max_rows,
+                    checkpoint_pause_s=3 * self.checkpoint_interval_s,
                 )
             )
         return prog, out, dump

@@ -100,5 +100,7 @@ def test_bench_smoke_emits_wellformed_metrics():
     assert extra["tracing_overhead_wordcount_pct"] <= 2.0
     assert extra["tracing_overhead_serving_pct"] <= 2.0
     # ...and the attribution block made it into the artifact: serving
-    # requests attribute real time to device work
-    assert extra["tracing_serving_attribution"].get("device", 0) > 0
+    # requests attribute real time to embed / search / generate work (host
+    # clock: `host_compute`; the category was called `device` before PR 25)
+    assert extra["tracing_serving_attribution"].get("host_compute", 0) > 0
+    assert "device" not in extra["tracing_serving_attribution"]

@@ -157,3 +157,133 @@ def test_metrics_expose_device_counters():
 def test_snapshot_reports_listener_state():
     snap = devctr.snapshot()
     assert snap["listener_installed"] == 1  # numeric: metrics-friendly
+
+
+# ---------------------------------------------------------------------------
+# dispatch counters (PR 25): bumped once per dispatch where the work happens;
+# the benchmark reads them through snapshot() as differences over its window
+
+
+def _moved(before: dict, after: dict) -> dict:
+    return {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+
+
+@pytest.fixture(scope="module")
+def toy_encoder():
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.models.tokenizer import HashTokenizer
+    from pathway_tpu.parallel.executor import JittedEncoder
+
+    cfg = EncoderConfig(hidden=32, layers=1, heads=2, mlp_dim=64, vocab_size=512, max_len=64)
+    return JittedEncoder(cfg, tokenizer=HashTokenizer(512), max_batch=16)
+
+
+def test_encode_moves_the_encoder_counters_by_the_reckoned_amounts(toy_encoder):
+    # words + [CLS] + [SEP] tokens a text: 4, 7, 19 -> one batch of 3 rows,
+    # padded to 8 rows (the smallest row bucket) x 32 tokens (19 -> bucket 32)
+    texts = ["a b", "a b c d e", " ".join("w%d" % i for i in range(17))]
+    before = devctr.snapshot()
+    out = toy_encoder.encode(texts)
+    moved = _moved(before, devctr.snapshot())
+    assert out.shape == (3, 32)
+    assert moved["encoder_dispatches"] == 1
+    assert moved["encoder_rows"] == 3 and moved["encoder_rows_padded"] == 8
+    assert moved["encoder_tokens"] == 4 + 7 + 19
+    assert moved["encoder_tokens_padded"] == 8 * 32
+    for stage in ("encoder_tokenize", "encoder_dispatch", "encoder_readback"):
+        assert moved[f"span_count.{stage}"] == 1 and moved[f"span_ns.{stage}"] > 0
+
+
+def test_encode_counts_one_dispatch_per_chunk_never_per_row(toy_encoder):
+    # 20 texts at max_batch 16: two tokenizer batches, 16 rows and 4 -> 8
+    texts = ["x y z"] * 20
+    before = devctr.snapshot()
+    toy_encoder.encode(texts)
+    moved = _moved(before, devctr.snapshot())
+    assert moved["encoder_dispatches"] == 2
+    assert moved["encoder_rows"] == 20 and moved["encoder_rows_padded"] == 16 + 8
+    assert moved["encoder_tokens"] == 20 * 5
+    assert moved["encoder_tokens_padded"] == (16 + 8) * 16
+
+
+@pytest.mark.parametrize("door", ["add_batch", "add_batch_device"])
+def test_slab_upsert_moves_the_scatter_counters(door):
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
+
+    idx = ShardedKnnIndex(8, capacity=256)
+    vecs = np.random.default_rng(3).standard_normal((16, 8)).astype(np.float32)
+    before = devctr.snapshot()
+    if door == "add_batch":
+        idx.add_batch([f"k{i}" for i in range(5)], vecs[:5])  # 5 rows -> bucket 8
+        padded = 8
+    else:
+        idx.add_batch_device([f"k{i}" for i in range(5)], jnp.asarray(vecs), n_valid=5)
+        padded = 16  # the device array's rows, as the encoder padded them
+    moved = _moved(before, devctr.snapshot())
+    assert len(idx) == 5
+    assert moved["scatter_dispatches"] == 1
+    assert moved["scatter_rows"] == 5 and moved["scatter_rows_padded"] == padded
+    assert moved["span_count.slab_assign_slots"] == 1 and moved["span_count.slab_scatter"] == 1
+
+
+def test_slab_search_moves_the_search_counters():
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
+
+    rng = np.random.default_rng(5)
+    idx = ShardedKnnIndex(8, capacity=256)
+    idx.add_batch([f"k{i}" for i in range(32)], rng.standard_normal((32, 8)).astype(np.float32))
+    before = devctr.snapshot()
+    rows = idx.search(rng.standard_normal((3, 8)).astype(np.float32), k=4)
+    moved = _moved(before, devctr.snapshot())
+    assert [len(r) for r in rows] == [4, 4, 4]
+    assert moved["search_dispatches"] == 1
+    assert moved["search_queries"] == 3 and moved["search_queries_padded"] == 4
+    assert moved["span_count.search_readback"] == 1
+    assert "scatter_dispatches" not in moved and "encoder_dispatches" not in moved
+
+
+def test_segment_bulk_load_times_the_keyset_rebuild_and_the_index_add():
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
+    from pathway_tpu.stdlib.indexing.segments import SegmentedIndex
+
+    rng = np.random.default_rng(9)
+    seg = SegmentedIndex(ShardedKnnIndex(8, capacity=256), delta_cap=8, auto_merge=False)
+    before = devctr.snapshot()
+    seg.add([(f"k{i}", v) for i, v in enumerate(rng.standard_normal((8, 8)).astype(np.float32))])
+    moved = _moved(before, devctr.snapshot())
+    assert len(seg) == 8 and moved["scatter_rows"] == 8
+    assert moved["span_count.index_keyset_rebuild"] == 1
+    seg.add([("one", rng.standard_normal(8).astype(np.float32))])  # the delta: no rebuild
+    assert devctr.snapshot()["span_count.index_keyset_rebuild"] - before.get("span_count.index_keyset_rebuild", 0) == 1
+    seg.close()
+
+
+def _module_name(lowered) -> str:
+    import re
+
+    return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+
+@pytest.mark.parametrize("program", ["jit__apply_cast", "jit_run", "jit__scatter_set"])
+def test_the_programs_keep_the_module_names_the_benchmark_reads(program, toy_encoder):
+    """``benchmark/metrics/*.json`` find the encoder, the slab search and the
+    bulk scatter in a device trace by these XLA module names; a rename here
+    turns four accepted per-layer metrics to null."""
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
+
+    idx = ShardedKnnIndex(8, capacity=256)
+    if program == "jit__apply_cast":
+        z = jnp.zeros((8, 16), jnp.int16)
+        lowered = toy_encoder._apply.lower(toy_encoder.params, z, z.astype(jnp.uint8), z.astype(jnp.uint8))
+    elif program == "jit_run":
+        lowered = idx._search_jit(16).lower(jnp.zeros((1, 8), jnp.float32), idx._vectors, idx._valid)
+    else:
+        lowered = ShardedKnnIndex._scatter_set.lower(
+            idx._vectors, idx._valid, jnp.zeros((8,), jnp.int32), jnp.zeros((8, 8), jnp.float32)
+        )
+    assert _module_name(lowered) == program
+
+
+def test_bump_refuses_a_counter_it_does_not_know():
+    with pytest.raises(KeyError):
+        devctr.bump(no_such_counter=1)

@@ -264,7 +264,7 @@ def test_attribute_exclusive_times_partition_the_root():
     assert sum(by.values()) == pytest.approx(info["wall_ms"])
     cats = info["by_category_ms"]
     assert cats["queue_wait"] == pytest.approx(4.0)
-    assert cats["device"] == pytest.approx(5.0)
+    assert cats["host_compute"] == pytest.approx(5.0)
 
 
 def test_critical_path_descends_into_biggest_child():
@@ -289,7 +289,7 @@ def test_report_rolls_up_quantiles_and_critical_path():
     assert rep["requests"] == 10
     assert rep["p50"]["wall_ms"] == pytest.approx(10.0)
     assert rep["p99"]["wall_ms"] == pytest.approx(10.0)
-    assert rep["mean_by_category_ms"]["device"] == pytest.approx(5.0)
+    assert rep["mean_by_category_ms"]["host_compute"] == pytest.approx(5.0)
     assert [s["stage"] for s in rep["slowest"]["critical_path"]][0] == "serve_e2e"
     assert tracecrit.report([]) == {"requests": 0}
 
@@ -308,6 +308,309 @@ def test_report_over_real_recorded_spans():
     assert rep["requests"] == 1
     p50 = rep["p50"]["by_category_ms"]
     assert p50["queue_wait"] >= 1.0
-    assert p50["device"] >= 2.0
+    assert p50["host_compute"] >= 2.0
     conn = tracecrit.connected_traces(_events())
     assert conn[ctx.trace_id] is True
+
+
+@pytest.mark.parametrize(
+    "stage,category",
+    [
+        ("epoch_process", "host_compute"),
+        ("dispatch_segments", "host_compute"),
+        ("collect_segments", "host_compute"),
+        ("encoder_tokenize", "host_compute"),
+        ("encoder_dispatch", "host_compute"),
+        ("index_add", "host_compute"),
+        ("index_keyset_rebuild", "host_compute"),
+        ("slab_assign_slots", "host_compute"),
+        ("slab_scatter", "host_compute"),
+        ("connector_read", "host_compute"),
+        ("rest_ingress", "host_compute"),
+        ("encoder_readback", "device_wait"),
+        ("search_readback", "device_wait"),
+        ("epoch_cut_wait", "queue_wait"),
+        ("rest_respond", "queue_wait"),
+    ],
+)
+def test_measured_path_stages_have_a_category(stage, category):
+    """The stages of the REST -> epoch -> encoder -> slab path (PERF.md
+    section 3): host work is ``host_compute``, only the two readbacks are
+    the host waiting for the device, and nothing is called ``device``."""
+    assert tracecrit.categorize(stage) == category
+    assert category in tracecrit.CATEGORIES and "device" not in tracecrit.CATEGORIES
+
+
+# ------------------------------------------------------------ stage totals
+
+
+def _record_by(path: str, stage: str, ctx, t0: int, dur: int) -> None:
+    if path == "record_span":
+        tracing.record_span(stage, t0, t0 + dur, ctx=ctx)
+    elif path == "record_spans":
+        tracing.record_spans(ctx, [(stage, t0, t0 + dur, None)])
+    else:
+        with tracing.use(ctx), tracing.span(stage):
+            pass
+
+
+@pytest.mark.parametrize("path", ["record_span", "record_spans", "span_cm"])
+def test_stage_totals_count_every_record_path_and_never_fall(path):
+    tracing.configure(PATHWAY_TRACE_RING="64")
+    tracing.reset()
+    ctx = tracing.new_trace()
+    seen = (0, 0)
+    for i in range(200):  # three times round the ring: totals do not wrap
+        _record_by(path, "st", ctx, 1000 * i, 7)
+        now = tracing.stage_totals()["st"]
+        assert now[0] == i + 1 and now[1] >= seen[1]
+        seen = now
+    if path != "span_cm":
+        assert seen == (200, 1400)
+    assert len([e for e in _events() if e["name"] == "st"]) == 64
+
+
+def test_stage_totals_sum_over_threads():
+    ctx = tracing.new_trace()
+
+    def work():
+        for i in range(50):
+            tracing.record_span("shared", i, i + 3, ctx=ctx)
+
+    ts = [threading.Thread(target=work) for _ in range(4)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in ts)
+    tracing.record_span("shared", 0, 5, ctx=ctx)  # and this thread's own ring
+    assert tracing.stage_totals()["shared"] == (201, 4 * 50 * 3 + 5)
+
+
+def test_stage_totals_read_zero_with_tracing_off():
+    tracing.configure(PATHWAY_TRACE="0")
+    ctx = tracing.TraceContext(1, 1)
+    for path in ("record_span", "record_spans", "span_cm"):
+        _record_by(path, "off", ctx, 0, 9)
+    assert tracing.stage_totals() == {}
+    from pathway_tpu.internals import device_counters
+
+    assert not [k for k in device_counters.snapshot() if k.startswith("span_")]
+
+
+def test_snapshot_and_metrics_carry_the_stage_totals():
+    """The one door to the benchmark (flat ``span_ns.<stage>`` keys) and
+    the same totals on /metrics under a ``stage`` label."""
+    from pathway_tpu.internals import device_counters
+    from pathway_tpu.internals.monitoring_server import _metrics_text
+
+    tracing.record_span("door", 100, 350)
+    tracing.record_span("door", 400, 450)
+    snap = device_counters.snapshot()
+    assert snap["span_ns.door"] == 300 and snap["span_count.door"] == 2
+
+    import pathway_tpu as pw
+    from pathway_tpu.engine.scheduler import Scheduler
+    from pathway_tpu.internals.parse_graph import G
+
+    pw.G.clear()
+    pw.debug.table_from_markdown("a\n1").select(b=pw.this.a)._capture_node()
+    body = _metrics_text(Scheduler(G.engine_graph, autocommit_ms=20))
+    pw.G.clear()
+    assert 'pathway_tpu_span_ns_total{stage="door"} 300' in body
+    assert 'pathway_tpu_span_count_total{stage="door"} 2' in body
+    assert body.count("# TYPE pathway_tpu_span_ns_total counter") == 1
+    for name in ("epochs", "rest_requests", "encoder_tokens_padded", "search_queries",
+                 "scatter_rows", "jit_compiles", "h2d_bytes", "d2h_bytes",
+                 "h2d_transfers", "d2h_transfers"):
+        assert f"pathway_tpu_{name}_total " in body, name
+
+
+def test_span_cm_enters_a_profiler_annotation_of_its_name(monkeypatch):
+    """While a jax.profiler session runs the stages lie in the profile's
+    host plane: every ``span`` block enters the annotation class the
+    recorder found (jax's ``TraceAnnotation``; a stand-in here)."""
+    seen = []
+
+    class _Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+
+    monkeypatch.setattr(tracing, "_trace_annotation", _Annotation)
+    with tracing.span("outer"):
+        with tracing.span("inner"):
+            pass
+    assert seen == [("enter", "outer"), ("enter", "inner"), ("exit", "inner"), ("exit", "outer")]
+    tracing.configure(PATHWAY_TRACE="0")
+    with tracing.span("off"):
+        pass
+    assert len(seen) == 4
+
+
+def test_recorder_and_counters_import_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from pathway_tpu.internals import tracing, device_counters\n"
+        "with tracing.span('s'):\n"
+        "    pass\n"
+        "device_counters.bump(epochs=1)\n"
+        "assert device_counters.snapshot()['span_count.s'] == 1\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True, text=True, timeout=120
+    )
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_jax_annotation_is_found_once_jax_is_loaded():
+    pytest.importorskip("jax")
+    import jax
+
+    tracing._trace_annotation = None
+    with tracing.span("find"):
+        pass
+    assert tracing._trace_annotation is jax.profiler.TraceAnnotation
+
+
+# ------------------------------------------------------------ the one clock
+
+
+def test_native_and_python_monotonic_clocks_are_one_clock():
+    """``LatencyProbe.now_ns`` (native ``steady_clock``) stamps the
+    scheduler's ``origin_ns`` / ``cut_ns``, which ``epoch_cut_wait`` puts
+    beside ``time.monotonic_ns`` spans: both must be CLOCK_MONOTONIC."""
+    from pathway_tpu.internals import native as native_mod
+    from pathway_tpu.internals.monitoring import LatencyProbe
+
+    native = native_mod.load()
+    if native is None or not hasattr(native, "monotonic_ns"):
+        pytest.skip("no native extension here")
+    assert LatencyProbe().now_ns is native.monotonic_ns
+    for _ in range(100):
+        a = time.monotonic_ns()
+        b = native.monotonic_ns()
+        c = tracing.now_ns()
+        assert a <= b <= c and c - a < 1_000_000
+
+
+# ----------------------------------------- the REST -> epoch -> REST path
+
+
+def _rest_roundtrip(handler_sleep_s: float = 0.0):
+    """One request through ``rest_connector`` on loopback; returns the
+    span events the recorder exports by default and all it holds."""
+    import socket
+    import urllib.request
+
+    import pathway_tpu as pw
+    from pathway_tpu.engine.scheduler import Scheduler
+    from pathway_tpu.internals import device_counters
+    from pathway_tpu.internals.parse_graph import G
+
+    pw.G.clear()
+    sock = socket.socket()
+    sock.bind(("127.0.0.1", 0))
+    port = sock.getsockname()[1]
+    sock.close()
+
+    class QuerySchema(pw.Schema):
+        query: str
+
+    def answer(q: str) -> str:
+        time.sleep(handler_sleep_s)
+        return q.upper()
+
+    queries, response_writer = pw.io.http.rest_connector(
+        host="127.0.0.1", port=port, schema=QuerySchema, delete_completed_queries=False
+    )
+    response_writer(queries.select(result=pw.apply(answer, pw.this.query)))
+    sched = Scheduler(G.engine_graph, autocommit_ms=10)
+    run_t = threading.Thread(target=sched.run, daemon=True)
+    run_t.start()
+    before = device_counters.snapshot()
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/",
+        data=json.dumps({"query": "hello"}).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    body = None
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        try:
+            with urllib.request.urlopen(req, timeout=10) as resp:
+                body = json.loads(resp.read())
+            break
+        except (ConnectionError, urllib.error.URLError):
+            time.sleep(0.2)  # server still coming up
+    after = device_counters.snapshot()
+    sched.stop()
+    run_t.join(timeout=5)
+    pw.G.clear()
+    assert body == "HELLO"
+    moved = {k: after[k] - before.get(k, 0) for k in after if after[k] != before.get(k, 0)}
+    return tracing.chrome_events(), _events(), moved
+
+
+def test_rest_request_spans_share_a_trace_with_the_epoch_between_them():
+    _default, events, moved = _rest_roundtrip()
+    (ingress,) = [e for e in events if e["name"] == "rest_ingress"]
+    (respond,) = [e for e in events if e["name"] == "rest_respond"]
+    assert ingress["args"]["trace_id"] == respond["args"]["trace_id"] != 0
+    epochs = [
+        e for e in events
+        if e["name"] == "epoch_process" and e["args"].get("epoch") == respond["args"]["epoch"]
+    ]
+    (epoch,) = epochs  # the request's rest_respond names the epoch that answered it
+    assert epoch["args"]["rows"] >= 1 and epoch["args"]["requests"] == 1
+    assert ingress["ts"] + ingress["dur"] <= epoch["ts"] + epoch["dur"]
+    assert epoch["ts"] <= respond["ts"] <= epoch["ts"] + epoch["dur"] <= respond["ts"] + respond["dur"]
+    # the cut wait lies under the epoch's trace, before its processing
+    waits = [e for e in events if e["name"] == "epoch_cut_wait"
+             and e["args"]["trace_id"] == epoch["args"]["trace_id"]]
+    assert waits and waits[0]["ts"] + waits[0]["dur"] <= epoch["ts"] + 1.0
+    assert moved["rest_requests"] == 1 and moved["rest_responses"] == 1
+    assert moved["epochs"] >= 1 and moved["epoch_rows"] >= 1
+    for stage in ("rest_ingress", "rest_respond", "epoch_cut_wait", "epoch_process"):
+        assert moved[f"span_count.{stage}"] >= 1 and moved[f"span_ns.{stage}"] > 0
+
+
+def test_rest_request_slower_than_the_tail_threshold_is_kept():
+    tracing.configure(PATHWAY_TRACE_SAMPLE="0.0", PATHWAY_TRACE_TAIL_MS="20")
+    default, events, _moved = _rest_roundtrip(handler_sleep_s=0.05)
+    (ingress,) = [e for e in events if e["name"] == "rest_ingress"]
+    kept = {e["name"] for e in default if e["args"]["trace_id"] == ingress["args"]["trace_id"]}
+    assert kept == {"rest_ingress", "rest_respond"}
+
+
+def test_fast_unsampled_rest_request_is_not_exported():
+    tracing.configure(PATHWAY_TRACE_SAMPLE="0.0", PATHWAY_TRACE_TAIL_MS="20000")
+    default, events, _moved = _rest_roundtrip()
+    (ingress,) = [e for e in events if e["name"] == "rest_ingress"]
+    assert not [e for e in default if e["args"]["trace_id"] == ingress["args"]["trace_id"]]
+
+
+def test_a_collector_sweep_is_a_span():
+    """The run loop's collector sweeps hold the GIL against every thread;
+    each is a ``gc_sweep`` span, so a stall one causes lies in the flight
+    recorder beside the requests that waited for it."""
+    from pathway_tpu.internals.run import _ManagedGc
+
+    with _ManagedGc() as mgc:
+        assert not mgc.maybe_sweep()  # not due yet
+        for n in range(1, 9):
+            mgc._next_due = 0.0
+            assert mgc.maybe_sweep()
+    sweeps = [e["args"]["generation"] for e in _events() if e["name"] == "gc_sweep"]
+    assert sweeps == [1, 1, 1, 1, 1, 1, 1, 2]  # every eighth is a full collection
+    assert tracing.stage_totals()["gc_sweep"][0] == 8
