@@ -20,7 +20,8 @@ counters are monotonic:
 
 - dispatch counters, :func:`bump`-ed once per dispatch where the work
   happens, never per row: ``encoder_*`` (``JittedEncoder._dispatch``:
-  rows and tokens as given and as padded), ``search_*``
+  ``encoder_segments`` texts in ``encoder_rows`` rows that carry one or,
+  packed, several; rows and tokens as given and as padded), ``search_*``
   (``ShardedKnnIndex.dispatch``), ``scatter_*`` (``add_batch`` /
   ``add_batch_device``), ``epochs`` / ``epoch_rows`` (the scheduler's
   cut) and ``rest_requests`` / ``rest_responses`` (``io/http``).
@@ -67,6 +68,7 @@ _counters: dict[str, int] = {
     "d2h_bytes": 0,
     "d2h_transfers": 0,
     "encoder_dispatches": 0,
+    "encoder_segments": 0,
     "encoder_rows": 0,
     "encoder_rows_padded": 0,
     "encoder_tokens": 0,

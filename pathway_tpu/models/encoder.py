@@ -6,7 +6,9 @@ through torch SentenceTransformers (MiniLM, BGE, E5 —
 (``xpacks/llm/rerankers.py:186``).  Design for the MXU:
 
 - bf16 activations / f32 params (configurable), static shapes via
-  bucketed padding (see :mod:`pathway_tpu.ops.bucketing`);
+  bucketed rows and lengths (see :mod:`pathway_tpu.ops.bucketing`); a row
+  holds one padded text, or several short ones end to end
+  (:class:`TextEncoderModel`'s ``first``), each attending within itself;
 - post-LN BERT blocks expressed as einsum-shaped flax modules so XLA
   fuses bias+gelu+residual into the matmuls;
 - tensor-parallel sharding RULES (:func:`encoder_param_specs`) mapping
@@ -23,7 +25,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from pathway_tpu.ops.pooling import cls_pool, masked_mean_pool
+from pathway_tpu.ops.pooling import (
+    cls_pool,
+    masked_mean_pool,
+    packed_cls_pool,
+    packed_mean_pool,
+)
 
 __all__ = [
     "EncoderConfig",
@@ -86,6 +93,9 @@ BGE_RERANKER_BASE = dataclasses.replace(
 
 
 class SelfAttention(nn.Module):
+    """``mask`` is a key mask [B, L], or [B, L, L]: which keys each query
+    may see (packed rows, where a token sees its own text only)."""
+
     cfg: EncoderConfig
 
     @nn.compact
@@ -112,7 +122,9 @@ class SelfAttention(nn.Module):
         else:
             scale = 1.0 / jnp.sqrt(jnp.float32(cfg.head_dim))
             logits = jnp.einsum("blhd,bmhd->bhlm", q, k).astype(jnp.float32) * scale
-            bias = jnp.where(mask.astype(bool)[:, None, None, :], 0.0, -1e30)
+            seen = mask.astype(bool)
+            seen = seen[:, None, None, :] if mask.ndim == 2 else seen[:, None]
+            bias = jnp.where(seen, 0.0, -1e30)
             probs = jax.nn.softmax(logits + bias, axis=-1).astype(cfg.dtype)
             ctx = jnp.einsum("bhlm,bmhd->blhd", probs, v)
         out = nn.DenseGeneral(
@@ -153,8 +165,15 @@ class Embeddings(nn.Module):
     cfg: EncoderConfig
 
     @nn.compact
-    def __call__(self, ids: jax.Array, type_ids: jax.Array | None) -> jax.Array:
+    def __call__(
+        self,
+        ids: jax.Array,
+        type_ids: jax.Array | None,
+        positions: jax.Array | None = None,
+    ) -> jax.Array:
         cfg = self.cfg
+        if positions is None:
+            positions = jnp.arange(ids.shape[1])[None, :]
         emb = nn.Embed(
             cfg.vocab_size, cfg.hidden, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="word",
@@ -162,7 +181,7 @@ class Embeddings(nn.Module):
         pos = nn.Embed(
             cfg.max_len, cfg.hidden, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="position",
-        )(jnp.arange(ids.shape[1])[None, :])
+        )(positions)
         emb = emb + pos
         if cfg.type_vocab:
             t = type_ids if type_ids is not None else jnp.zeros_like(ids)
@@ -176,9 +195,22 @@ class Embeddings(nn.Module):
         )(emb)
 
 
+def _packed_positions(segments: jax.Array) -> jax.Array:
+    """Token positions that restart at 0 where the segment id changes."""
+    at = jnp.arange(segments.shape[1])[None, :]
+    starts = jnp.pad(segments[:, 1:] != segments[:, :-1], ((0, 0), (1, 0)))
+    return at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
+
+
 class TextEncoderModel(nn.Module):
     """Sentence encoder: token ids -> pooled (optionally normalized)
-    embedding [B, hidden]."""
+    embedding [B, hidden].
+
+    With ``first`` [T] the rows are packed: ``mask`` then holds a segment
+    id per token (a row's texts numbered from 1, padding 0) and ``first``
+    each text's first token in the flattened [B * L]; a token attends
+    within its own segment, positions restart with every segment, and the
+    result is one embedding per text, [T, hidden], in ``first``'s order."""
 
     cfg: EncoderConfig
 
@@ -188,12 +220,25 @@ class TextEncoderModel(nn.Module):
         ids: jax.Array,
         mask: jax.Array,
         type_ids: jax.Array | None = None,
+        first: jax.Array | None = None,
     ) -> jax.Array:
         cfg = self.cfg
-        x = Embeddings(cfg, name="embeddings")(ids, type_ids)
+        if first is None:
+            x = Embeddings(cfg, name="embeddings")(ids, type_ids)
+            seen = mask
+        else:
+            x = Embeddings(cfg, name="embeddings")(
+                ids, type_ids, _packed_positions(mask)
+            )
+            seen = mask[:, :, None] == mask[:, None, :]
         for i in range(cfg.layers):
-            x = EncoderBlock(cfg, name=f"layer_{i}")(x, mask)
-        pooled = cls_pool(x) if cfg.pool == "cls" else masked_mean_pool(x, mask)
+            x = EncoderBlock(cfg, name=f"layer_{i}")(x, seen)
+        if first is None:
+            pooled = cls_pool(x) if cfg.pool == "cls" else masked_mean_pool(x, mask)
+        elif cfg.pool == "cls":
+            pooled = packed_cls_pool(x, first)
+        else:
+            pooled = packed_mean_pool(x, mask, first)
         if cfg.normalize:
             norm = jnp.sqrt(
                 jnp.sum(pooled.astype(jnp.float32) ** 2, axis=-1, keepdims=True)

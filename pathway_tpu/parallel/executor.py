@@ -6,11 +6,19 @@ whole epoch's rows are tokenized into one bucketed batch and pushed
 through a single jit-compiled flax program; with a mesh, the batch is
 data-parallel over ``"data"`` and the params tensor-parallel over
 ``"model"`` (see :func:`pathway_tpu.models.encoder_param_specs`).
+
+A tokenized batch shares one padded length, the bucket of its longest
+text.  Where most texts are much shorter than that, several are laid end
+to end in one row of the same length (:meth:`JittedEncoder._pack`) and
+the program attends within each text, so the device multiplies tokens of
+texts and not padding.  Whether a batch is packed is read from its
+lengths: exactly when that dispatches fewer padded tokens.
 """
 
 from __future__ import annotations
 
-from typing import Any, Sequence
+from collections import deque
+from typing import Any, Iterator, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +43,9 @@ __all__ = ["JittedEncoder"]
 #: device memory assumed where the backend reports none (the CPU): one
 #: v5e chip's 16 GB, so tests and the chip split batches alike
 _ASSUMED_DEVICE_BYTES = 16 * 2**30
+
+#: most texts one packed row may hold: their numbers upload as uint8
+_MAX_SEGMENTS = 255
 
 
 class JittedEncoder:
@@ -177,12 +188,17 @@ class JittedEncoder:
         # into the compiled apply
         self._narrow_ids = config.vocab_size < 2**15
 
-        def _apply_cast(params, ids, mask, tps):
+        def _apply_cast(params, ids, mask, tps, first=None):
+            # packed rows: ``mask`` numbers each row's texts and ``first``
+            # says where each text starts (both programs are this function,
+            # so both lower as ``jit__apply_cast``)
+            packed = () if first is None else (first,)
             return self.model.apply(
                 params,
                 ids.astype(jnp.int32),
                 mask.astype(jnp.int32),
                 tps.astype(jnp.int32),
+                *packed,
             )
 
         self._apply = jax.jit(_apply_cast, out_shardings=self._out_sharding)
@@ -201,9 +217,10 @@ class JittedEncoder:
         """Most rows one dispatch may carry at padded length ``length``.
 
         ``max_batch`` alone does not bound memory: attention holds
-        ``[rows, heads, length, length]`` scores, and the tokenizer pads a
-        whole batch to its longest row, so one long chunk takes a
-        1024-row batch to 512 tokens — for BGE-large a program with
+        ``[rows, heads, length, length]`` scores, and a whole batch shares
+        the length bucket of its longest text (packed or not, a row is that
+        long), so one long chunk takes a 1024-row batch to 512 tokens —
+        for BGE-large a program with
         10.8 GB of temporaries beside 1.3 GB of params on a 16.9 GB v5e
         (XLA's memory analysis; it compiles, and leaves the index slab
         and every other model 4.8 GB).  Rows are bounded instead by the
@@ -226,17 +243,30 @@ class JittedEncoder:
         rows = (1 << (fit.bit_length() - 1)) * self._dp
         return min(self.max_batch, max(rows, max(8, self._dp)))
 
+    def _row_bucket(self, n: int) -> int:
+        """Rows a dispatch of ``n`` is padded to: a power of two (8 at the
+        least) that divides the data-parallel degree."""
+        b = bucket_size(n, min_bucket=max(8, self._dp))
+        return ((b + self._dp - 1) // self._dp) * self._dp
+
+    def _padded_rows(self, n: int, per_dispatch: int) -> int:
+        """Rows that ``n`` rows cost, split ``per_dispatch`` at a time."""
+        full, rest = divmod(n, per_dispatch)
+        return full * self._row_bucket(per_dispatch) + (
+            self._row_bucket(rest) if rest else 0
+        )
+
     def _pad_batch(self, ids: np.ndarray, mask: np.ndarray, tps: np.ndarray):
         """Round the batch up so it divides the data-parallel degree."""
         n = ids.shape[0]
-        b = bucket_size(n, min_bucket=max(8, self._dp))
-        b = ((b + self._dp - 1) // self._dp) * self._dp
+        b = self._row_bucket(n)
         if b > n:
             pad = ((0, b - n), (0, 0))
             ids = np.pad(ids, pad)
             mask = np.pad(mask, pad)
             tps = np.pad(tps, pad)
         # padded rows must still be valid encoder input: one non-masked token
+        # (in packed rows, a one-token text that nothing is pooled from)
         mask[n:, 0] = 1
         return ids, mask, tps, n
 
@@ -245,15 +275,19 @@ class JittedEncoder:
         ids: np.ndarray,
         mask: np.ndarray,
         tps: np.ndarray,
+        first: np.ndarray | None = None,
         start_host_copy: bool = True,
     ):
-        """Enqueue one padded chunk; returns (device_out, n_real_rows).
+        """Enqueue one padded chunk; returns (device_out, n_real_outputs).
+        With ``first`` the rows are packed (:meth:`_pack`): ``mask`` numbers
+        each row's texts and the output has a row per text, not per row.
         The device->host copy is started immediately (non-blocking), so
         the readback of chunk i overlaps the tokenize+compute of chunk
         i+1.  ``start_host_copy=False`` for consumers that keep the
         output on device (``encode_into``)."""
         with _tracing.span("encoder_dispatch") as sp:
-            ids, mask, tps, n = self._pad_batch(ids, mask, tps)
+            tokens = np.count_nonzero(mask)
+            ids, mask, tps, rows = self._pad_batch(ids, mask, tps)
             if self.sequence_axis is not None and ids.shape[1] < self.max_len:
                 # SP shards the sequence dimension: pad to the full max_len so
                 # every device holds an equal block
@@ -262,22 +296,29 @@ class JittedEncoder:
                 mask = np.pad(mask, pad)
                 tps = np.pad(tps, pad)
             rows_padded, length = ids.shape
-            sp.args = {"rows": n, "rows_padded": rows_padded, "length": length}
+            n = rows if first is None else first.shape[0]
+            sp.args = {"texts": n, "rows": rows, "rows_padded": rows_padded, "length": length}
             _devctr.bump(
                 encoder_dispatches=1,
-                encoder_rows=n,
+                encoder_segments=n,
+                encoder_rows=rows,
                 encoder_rows_padded=rows_padded,
-                encoder_tokens=mask[:n].sum(),
+                encoder_tokens=tokens,
                 encoder_tokens_padded=rows_padded * length,
             )
             if self._narrow_ids:
                 ids = ids.astype(np.int16, copy=False)
                 mask = mask.astype(np.uint8, copy=False)
                 tps = tps.astype(np.uint8, copy=False)
-            _devctr.record_h2d(ids.nbytes + mask.nbytes + tps.nbytes)
+            h2d = ids.nbytes + mask.nbytes + tps.nbytes
             args = [jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(tps)]
             if self._in_batch_sharding is not None:
                 args = [jax.device_put(a, self._in_batch_sharding) for a in args]
+            if first is not None:
+                first = np.pad(first, (0, bucket_size(n) - n)).astype(np.int32)
+                h2d += first.nbytes
+                args.append(jax.device_put(first, self._out_sharding))
+            _devctr.record_h2d(h2d)
             out = self._apply(self.params, *args)
             if start_host_copy:
                 out.copy_to_host_async()
@@ -294,11 +335,67 @@ class JittedEncoder:
         _devctr.record_d2h(host.nbytes)
         return host
 
-    def _chunks(self, texts: Sequence[str], pair: Sequence[str] | None):
-        """Tokenized dispatch units ``(ids, mask, type_ids)``: up to
+    def _pack(
+        self, ids: np.ndarray, mask: np.ndarray, tps: np.ndarray, rows: int
+    ) -> list[tuple[tuple, np.ndarray]] | None:
+        """The packed dispatches of one tokenized batch, or None where
+        packing dispatches no fewer padded rows than the batch as it is.
+
+        First-fit over the texts sorted longest first, into rows of the
+        batch's own length: the plan's shape depends on the multiset of
+        lengths alone.  A dispatch is ``(ids, segments, type_ids, first)``,
+        at most ``rows`` rows, with the positions in the batch of the texts
+        it carries: ``segments`` numbers a row's texts from 1 (0 is
+        padding) and ``first[i]`` is the i-th of those texts' first token
+        in the flattened rows.  Masks are prefixes (every tokenizer here
+        pads on the right)."""
+        n, length = ids.shape
+        today = self._padded_rows(n, rows)
+        if today == self._row_bucket(1):
+            return None  # a question, a few texts: already the smallest dispatch
+        lens = np.maximum(mask.sum(axis=1), 1)
+        if self._padded_rows(-(-int(lens.sum()) // length), rows) >= today:
+            return None  # full rows: no packing can save a row
+        with _tracing.span("encoder_pack") as sp:
+            room = np.full(n, length)
+            held = [0] * n
+            placed = []  # (row, first token in the flattened rows, number in the row)
+            order = np.argsort(-lens, kind="stable")
+            for size in lens[order].tolist():
+                r = int((room >= size).argmax())
+                left = int(room[r])
+                held[r] += 1
+                placed.append((r, r * length + length - left, held[r]))
+                room[r] = left - size if held[r] < _MAX_SEGMENTS else 0
+            row_of, start, number = np.empty((3, n), np.int64)
+            row_of[order], start[order], number[order] = np.array(placed).T
+            n_rows = int(row_of.max()) + 1
+            sp.args = {"texts": n, "rows": n_rows, "length": length}
+            if self._padded_rows(n_rows, rows) >= today:
+                return None
+            keep = np.arange(length) < lens[:, None]
+            to = (start[:, None] + np.arange(length))[keep]
+            flat = np.zeros((3, n_rows * length), ids.dtype)
+            flat[0, to] = ids[keep]
+            flat[1, to] = np.repeat(number, lens)
+            flat[2, to] = tps[keep]
+            planes = flat.reshape(3, n_rows, length)
+            units = []
+            for d in range(0, n_rows, rows):
+                at = np.flatnonzero((row_of >= d) & (row_of < d + rows))
+                units.append(((*planes[:, d : d + rows], start[at] - d * length), at))
+        return units
+
+    def _chunks(
+        self, texts: Sequence[str], pair: Sequence[str] | None
+    ) -> Iterator[tuple[tuple, np.ndarray]]:
+        """Tokenized dispatch units: the arguments of :meth:`_dispatch`
+        and the positions in ``texts`` of the outputs it will give.  Up to
         ``max_batch`` texts are tokenized together (they share one padded
-        length), then split so that no dispatch exceeds
-        :meth:`_rows_per_dispatch` at that length."""
+        length), packed where that saves rows (:meth:`_pack`; not the
+        cross-encoder's pairs, not sequence-parallel rows), and split so
+        that no dispatch exceeds :meth:`_rows_per_dispatch` at that
+        length."""
         for i in range(0, len(texts), self.max_batch):
             sl = slice(i, i + self.max_batch)
             with _tracing.span("encoder_tokenize"):
@@ -307,29 +404,43 @@ class JittedEncoder:
                     pair=None if pair is None else pair[sl],
                     max_len=self.max_len,
                 )
+            n = ids.shape[0]
             rows = self._rows_per_dispatch(ids.shape[1])
-            for j in range(0, ids.shape[0], rows):
-                yield ids[j : j + rows], mask[j : j + rows], tps[j : j + rows]
+            units = None
+            if not self.cross and self.sequence_axis is None:
+                units = self._pack(ids, mask, tps, rows)
+            if units is None:
+                units = [
+                    ((ids[j : j + rows], mask[j : j + rows], tps[j : j + rows]),
+                     np.arange(j, min(j + rows, n)))
+                    for j in range(0, n, rows)
+                ]
+            for arrays, at in units:
+                yield arrays, i + at
 
-    def _run_pipelined(
-        self, texts: list, pair: "list | None"
-    ) -> list[np.ndarray]:
+    def _run_pipelined(self, texts: list, pair: "list | None") -> np.ndarray:
         """Tokenize/dispatch up to ``self.pipeline_depth`` chunks before
         collecting the oldest readback, so tokenize + device compute +
-        host transfer of different chunks all overlap."""
-        from collections import deque
-
-        outs: list[np.ndarray] = []
+        host transfer of different chunks all overlap.  One output per
+        text, in the order of ``texts``."""
+        ordered = None
         inflight: deque = deque()
-        for ids, mask, tps in self._chunks(texts, pair):
-            inflight.append(self._dispatch(ids, mask, tps))
+
+        def collect():
+            nonlocal ordered
+            out, n, at = inflight.popleft()
+            host = self._readback(out)[:n]
+            if ordered is None:
+                ordered = np.empty((len(texts),) + host.shape[1:], host.dtype)
+            ordered[at] = host
+
+        for arrays, at in self._chunks(texts, pair):
+            inflight.append((*self._dispatch(*arrays), at))
             if len(inflight) >= self.pipeline_depth:
-                out, nrows = inflight.popleft()
-                outs.append(self._readback(out)[:nrows])
+                collect()
         while inflight:
-            out, nrows = inflight.popleft()
-            outs.append(self._readback(out)[:nrows])
-        return outs
+            collect()
+        return ordered
 
     # ------------------------------------------------------------------
     def encode(self, texts: Sequence[str]) -> np.ndarray:
@@ -338,7 +449,7 @@ class JittedEncoder:
             raise TypeError("cross-encoder executor: use score_pairs()")
         if not texts:
             return np.zeros((0, self.config.hidden), np.float32)
-        return np.concatenate(self._run_pipelined(list(texts), None), axis=0)
+        return self._run_pipelined(list(texts), None)
 
     def encode_into(self, index: Any, keys: Sequence[Any], texts: Sequence[str]) -> int:
         """Embed ``texts`` and upsert the embeddings into ``index``
@@ -357,21 +468,17 @@ class JittedEncoder:
             raise ValueError("keys and texts must align")
         if not texts:
             return 0
-        from collections import deque
-
         inflight: deque = deque()
-        pos = 0
-        for ids, mask, tps in self._chunks(texts, None):
-            out, n = self._dispatch(ids, mask, tps, start_host_copy=False)
-            inflight.append((out, n, keys[pos : pos + n]))
-            pos += n
+        for arrays, at in self._chunks(texts, None):
+            out, n = self._dispatch(*arrays, start_host_copy=False)
+            inflight.append((out, n, [keys[a] for a in at]))
             if len(inflight) >= self.pipeline_depth:
                 out, n, kchunk = inflight.popleft()
                 index.add_batch_device(kchunk, out, n_valid=n)
         while inflight:
             out, n, kchunk = inflight.popleft()
             index.add_batch_device(kchunk, out, n_valid=n)
-        return pos
+        return len(texts)
 
     def score_pairs(self, queries: Sequence[str], docs: Sequence[str]) -> np.ndarray:
         """Cross-encoder scores for aligned (query, doc) pairs -> [n]."""
@@ -381,6 +488,4 @@ class JittedEncoder:
             raise ValueError("queries and docs must align")
         if not queries:
             return np.zeros((0,), np.float32)
-        return np.concatenate(
-            self._run_pipelined(list(queries), list(docs)), axis=0
-        )
+        return self._run_pipelined(list(queries), list(docs))

@@ -151,14 +151,21 @@ def test_rows_per_dispatch_bounds_attention_memory():
     assert enc._rows_per_dispatch(16) == enc.max_batch
 
 
-def test_long_row_splits_the_batch_and_keeps_results():
+@pytest.mark.parametrize("words", [0, 100], ids=["packs-into-one-dispatch", "packs-and-splits"])
+def test_long_row_splits_the_batch_and_keeps_results(words):
+    """One long text takes the batch to the 512 bucket, where a dispatch
+    holds 8 rows.  The shorter texts share rows: forty of up to 42 tokens
+    lie beside the long one in 3 rows, so nothing is left to split; forty
+    of 103 to 142 tokens need 11 rows, and split."""
     from pathway_tpu.parallel.executor import JittedEncoder
 
     enc = JittedEncoder(TINY, max_batch=64)
-    texts = [f"t{i} " + "w " * (i % 7) for i in range(40)] + ["long " * 400]
+    texts = [f"t{i} " + "w " * (words + i) for i in range(40)] + ["long " * 400]
     whole = enc.encode(texts)
+    np.testing.assert_allclose(whole[-1:], enc.encode(texts[-1:]), atol=1e-5)
     enc._dispatch_bytes //= 64  # force a split at the 512 bucket
     assert enc._rows_per_dispatch(512) < len(texts)
+    assert len(list(enc._chunks(texts, None))) == (2 if words else 1)
     np.testing.assert_allclose(enc.encode(texts), whole, atol=1e-5)
 
 

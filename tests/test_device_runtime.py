@@ -180,30 +180,120 @@ def toy_encoder():
 
 def test_encode_moves_the_encoder_counters_by_the_reckoned_amounts(toy_encoder):
     # words + [CLS] + [SEP] tokens a text: 4, 7, 19 -> one batch of 3 rows,
-    # padded to 8 rows (the smallest row bucket) x 32 tokens (19 -> bucket 32)
+    # padded to 8 rows (the smallest row bucket) x 32 tokens (19 -> bucket 32).
+    # Packed they would lie in one row, which is padded to the same 8 rows: no
+    # gain, so the packer is never entered and a row carries one text
     texts = ["a b", "a b c d e", " ".join("w%d" % i for i in range(17))]
     before = devctr.snapshot()
     out = toy_encoder.encode(texts)
     moved = _moved(before, devctr.snapshot())
     assert out.shape == (3, 32)
     assert moved["encoder_dispatches"] == 1
+    assert moved["encoder_segments"] == 3
     assert moved["encoder_rows"] == 3 and moved["encoder_rows_padded"] == 8
     assert moved["encoder_tokens"] == 4 + 7 + 19
     assert moved["encoder_tokens_padded"] == 8 * 32
     for stage in ("encoder_tokenize", "encoder_dispatch", "encoder_readback"):
         assert moved[f"span_count.{stage}"] == 1 and moved[f"span_ns.{stage}"] > 0
+    assert "span_count.encoder_pack" not in moved
 
 
 def test_encode_counts_one_dispatch_per_chunk_never_per_row(toy_encoder):
-    # 20 texts at max_batch 16: two tokenizer batches, 16 rows and 4 -> 8
+    # 20 texts of 5 tokens at max_batch 16: two tokenizer batches.  The 16 lie
+    # three to a 16-token row in 6 rows -> 8 where they took 16; the 4 would
+    # still cost the smallest bucket, 8, so they stay a text a row
     texts = ["x y z"] * 20
     before = devctr.snapshot()
     toy_encoder.encode(texts)
     moved = _moved(before, devctr.snapshot())
     assert moved["encoder_dispatches"] == 2
-    assert moved["encoder_rows"] == 20 and moved["encoder_rows_padded"] == 16 + 8
+    assert moved["encoder_segments"] == 20
+    assert moved["encoder_rows"] == 6 + 4 and moved["encoder_rows_padded"] == 8 + 8
     assert moved["encoder_tokens"] == 20 * 5
-    assert moved["encoder_tokens_padded"] == (16 + 8) * 16
+    assert moved["encoder_tokens_padded"] == (8 + 8) * 16
+    assert moved["span_count.encoder_pack"] == 1
+
+
+# ---------------------------------------------------------------------------
+# sequence packing (PR 26): short texts share a row where that saves rows
+
+
+def _fake_batch(lens, length):
+    """A tokenized batch of the given lengths: every token its own id."""
+    lens = np.asarray(lens)
+    mask = (np.arange(length) < lens[:, None]).astype(np.int32)
+    ids = (1 + np.arange(mask.size).reshape(mask.shape)) * mask
+    return ids.astype(np.int32), mask, (ids % 2).astype(np.int32)
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+@pytest.mark.parametrize("seed", range(5))
+def test_packer_places_every_text_once_and_whole(toy_encoder, seed, rows):
+    from pathway_tpu.models.encoder import _packed_positions
+
+    rng = np.random.default_rng(seed)
+    length = 64
+    lens = np.append(rng.integers(1, 40, size=int(rng.integers(30, 64))), length)
+    ids, mask, tps = _fake_batch(lens, length)
+    units = toy_encoder._pack(ids, mask, tps, rows)
+    placed = np.concatenate([at for _arrays, at in units])
+    assert sorted(placed) == list(range(len(lens)))  # every text, once
+    assert all(arrays[0].shape[0] <= rows for arrays, _at in units)
+    assert sum(a[0].shape[0] for a, _at in units) < len(lens)
+    for (p_ids, seg, p_tps, first), at in units:
+        assert p_ids.shape == seg.shape == p_tps.shape and p_ids.shape[1] == length
+        assert np.count_nonzero(seg) == lens[at].sum()
+        positions = np.asarray(_packed_positions(jnp.asarray(seg))).reshape(-1)
+        for t, f in zip(at, first):
+            r, o = divmod(int(f), length)
+            whole = slice(o, o + lens[t])
+            assert o + lens[t] <= length  # no text runs over its row
+            assert (p_ids[r, whole] == ids[t, : lens[t]]).all()
+            assert (p_tps[r, whole] == tps[t, : lens[t]]).all()
+            assert len(set(seg[r, whole])) == 1 and seg[r, o] > 0  # one segment, contiguous
+            assert (seg[r] == seg[r, o]).sum() == lens[t]  # and nothing else in it
+            assert (positions[f : f + lens[t]] == np.arange(lens[t])).all()
+    # the same multiset in another order: the same shapes, so the same programs
+    again = rng.permutation(len(lens))
+    other = toy_encoder._pack(ids[again], mask[again], tps[again], rows)
+    assert [[a.shape for a in arrays] for arrays, _ in other] == [[a.shape for a in arrays] for arrays, _ in units]
+
+
+@pytest.mark.parametrize(
+    "lens, length",
+    [([5], 16), ([16] * 16, 16), ([64] * 5 + [60] * 4, 64), ([3, 4, 5], 16)],
+    ids=["one-text", "full-rows", "nearly-full-rows", "under-the-smallest-bucket"],
+)
+def test_packer_leaves_alone_what_it_cannot_shrink(toy_encoder, lens, length):
+    before = devctr.snapshot()
+    assert toy_encoder._pack(*_fake_batch(lens, length), 64) is None
+    assert "span_count.encoder_pack" not in _moved(before, devctr.snapshot())
+
+
+@pytest.mark.parametrize("texts", [["a b c"], [" ".join(["w"] * 14)] * 16], ids=["one-text", "full-rows"])
+def test_unpackable_batches_take_the_program_of_a_text_a_row(toy_encoder, texts):
+    (arrays, at), = toy_encoder._chunks(texts, None)
+    assert len(arrays) == 3 and list(at) == list(range(len(texts)))  # ids, mask, type ids: no ``first``
+    assert arrays[0].shape == (len(texts), 16)
+    before = devctr.snapshot()
+    assert toy_encoder.encode(texts).shape == (len(texts), 32)
+    moved = _moved(before, devctr.snapshot())
+    assert moved["encoder_segments"] == moved["encoder_rows"] == len(texts)
+    assert "span_count.encoder_pack" not in moved
+
+
+def test_one_multiset_of_lengths_is_one_packed_program(toy_encoder):
+    rng = np.random.default_rng(11)
+    lens = np.append(rng.integers(1, 12, size=15), 30)  # 16 texts, bucket 32
+    moved = []
+    for _ in range(3):
+        texts = [" ".join(f"w{rng.integers(99)}" for _ in range(n)) for n in rng.permutation(lens)]
+        before = devctr.snapshot()
+        toy_encoder.encode(texts)
+        moved.append(_moved(before, devctr.snapshot()))
+    assert all(m["span_count.encoder_pack"] == 1 and m["encoder_rows"] < 16 for m in moved)
+    assert moved[0]["jit_compiles"] >= 1
+    assert "jit_compiles" not in moved[1] and "jit_compiles" not in moved[2]
 
 
 @pytest.mark.parametrize("door", ["add_batch", "add_batch_device"])
@@ -264,7 +354,7 @@ def _module_name(lowered) -> str:
     return re.search(r"module @(\w+)", lowered.as_text()).group(1)
 
 
-@pytest.mark.parametrize("program", ["jit__apply_cast", "jit_run", "jit__scatter_set"])
+@pytest.mark.parametrize("program", ["jit__apply_cast", "jit__apply_cast packed", "jit_run", "jit__scatter_set"])
 def test_the_programs_keep_the_module_names_the_benchmark_reads(program, toy_encoder):
     """``benchmark/metrics/*.json`` find the encoder, the slab search and the
     bulk scatter in a device trace by these XLA module names; a rename here
@@ -272,16 +362,17 @@ def test_the_programs_keep_the_module_names_the_benchmark_reads(program, toy_enc
     from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
 
     idx = ShardedKnnIndex(8, capacity=256)
-    if program == "jit__apply_cast":
+    if program.startswith("jit__apply_cast"):
         z = jnp.zeros((8, 16), jnp.int16)
-        lowered = toy_encoder._apply.lower(toy_encoder.params, z, z.astype(jnp.uint8), z.astype(jnp.uint8))
+        first = (jnp.zeros((16,), jnp.int32),) if program.endswith("packed") else ()
+        lowered = toy_encoder._apply.lower(toy_encoder.params, z, z.astype(jnp.uint8), z.astype(jnp.uint8), *first)
     elif program == "jit_run":
         lowered = idx._search_jit(16).lower(jnp.zeros((1, 8), jnp.float32), idx._vectors, idx._valid)
     else:
         lowered = ShardedKnnIndex._scatter_set.lower(
             idx._vectors, idx._valid, jnp.zeros((8,), jnp.int32), jnp.zeros((8, 8), jnp.float32)
         )
-    assert _module_name(lowered) == program
+    assert _module_name(lowered) == program.split()[0]
 
 
 def test_bump_refuses_a_counter_it_does_not_know():

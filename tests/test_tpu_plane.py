@@ -235,6 +235,48 @@ def test_jitted_encoder_batches(mesh8):
     np.testing.assert_allclose(out, out2, atol=1e-5)
 
 
+def _skewed_texts(seed=0, n=40):
+    """Lengths 3-60 in the 64-token bucket, and one text that fills it."""
+    rng = np.random.default_rng(seed)
+    words = list(rng.integers(1, 59, size=n)) + [62]  # + [CLS] and [SEP]
+    return [" ".join(f"w{rng.integers(500)}" for _ in range(k)) for k in words]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("pool", ["cls", "mean"])
+def test_packed_encode_equals_text_by_text(pool, dtype):
+    """Short texts laid end to end in one row embed as they do alone: the
+    same vectors, in the caller's order."""
+    cfg = dataclasses.replace(TINY, pool=pool, dtype=dtype, max_len=64)
+    enc = JittedEncoder(cfg, max_batch=64)
+    texts = _skewed_texts()
+    (arrays, _at), = enc._chunks(texts, None)
+    assert len(arrays) == 4 and arrays[0].shape[0] <= 32 < len(texts)  # packed: a row bucket saved
+    alone = np.concatenate([enc.encode([t]) for t in texts])
+    # bf16 keeps 8 bits: no test of this suite held it to a tolerance before
+    np.testing.assert_allclose(enc.encode(texts), alone, atol=1e-5 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("how", ["split", "data-parallel", "tensor-parallel", "encode_into"])
+def test_packed_encode_through_every_door(mesh8, how):
+    mesh = {"data-parallel": best_mesh, "tensor-parallel": lambda: best_mesh(model_parallel=2)}.get(how, lambda: None)()
+    enc = JittedEncoder(TINY, mesh=mesh, max_batch=64)
+    texts = _skewed_texts(seed=1)
+    alone = JittedEncoder(TINY, params=jax.device_get(enc.params)).encode  # a text a row
+    want = np.concatenate([alone([t]) for t in texts])
+    if how == "split":
+        enc._dispatch_bytes //= 4096  # 8 rows a dispatch at 64 tokens
+        assert len(list(enc._chunks(texts, None))) > 1
+    if how == "encode_into":
+        idx = ShardedKnnIndex(64, metric="cos", capacity=64)
+        assert enc.encode_into(idx, list(range(len(texts))), texts) == len(texts)
+        for q in (0, 17, 40):  # every key holds its own text's vector
+            (key, score), = idx.search(want[q : q + 1], 1)[0]
+            assert key == q and abs(score - 1.0) < 1e-4
+    else:
+        np.testing.assert_allclose(enc.encode(texts), want, atol=1e-5)
+
+
 def test_encode_into_device_matches_host_path(mesh8):
     """encode_into keeps embeddings on device (add_batch_device); search
     results must be identical to encode() + add_batch through the host."""

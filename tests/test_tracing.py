@@ -420,7 +420,7 @@ def test_snapshot_and_metrics_carry_the_stage_totals():
     assert 'pathway_tpu_span_ns_total{stage="door"} 300' in body
     assert 'pathway_tpu_span_count_total{stage="door"} 2' in body
     assert body.count("# TYPE pathway_tpu_span_ns_total counter") == 1
-    for name in ("epochs", "rest_requests", "encoder_tokens_padded", "search_queries",
+    for name in ("epochs", "rest_requests", "encoder_tokens_padded", "encoder_segments", "search_queries",
                  "scatter_rows", "jit_compiles", "h2d_bytes", "d2h_bytes",
                  "h2d_transfers", "d2h_transfers"):
         assert f"pathway_tpu_{name}_total " in body, name
