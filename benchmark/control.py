@@ -6,11 +6,12 @@ reference in fp8 in the program's place: the upper readings).
 
 Each seed is a whole short run of the cell through ``run.run_cell`` (its own
 weights, filler, corpus and window), so what is compared is what a run
-compares.  The control's numbers go through ``check.verdict`` against the
-workload's committed limits, as the program's do: ``control_correct`` has to
-read false on every seed, and the exit code is 1 where it does not (or where
-the program's ``correct`` is false).  Prints one JSON line per seed and a
-summary last.
+compares; the control's side is the cell's check kind's (``checks/<kind>.py``,
+``numbers`` at that precision).  Its numbers go through ``check.verdict``
+against the workload's committed limits, as the program's do:
+``control_correct`` has to read false on every seed, and the exit code is 1
+where it does not (or where the program's ``correct`` is false).  Prints one
+JSON line per seed and a summary last.
 """
 
 from __future__ import annotations
@@ -53,7 +54,7 @@ def main(argv: list[str]) -> int:
         }
         rows.append(row)
         print(json.dumps(row), flush=True)
-    names = [n for n in ("emb_gap", "score_gap", "rank_gap") if rows and n in rows[0]["program"]]
+    names = [n for n, limit in limits.items() if limit and rows and n in rows[0]["program"]]  # a limit of 0 is exact: no readings
     summary = {}
     for n in names:
         lower = [r["program"][n] for r in rows if r["program"]]

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import statistics
 
-from benchmark import work
+from benchmark import doors, work
 
 
 def read(decl: dict, r: dict) -> float | None:
@@ -23,8 +23,7 @@ def read(decl: dict, r: dict) -> float | None:
         return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
     s = r["slice"]
     if q == "step_mfu_pct":
-        model = r["config"]["model"]
-        flops = sum(work.encoder_flops(model, n) for n in s["useful_tokens"])
+        flops = doors.model_flops(r["config"], s["useful_tokens"])
         if decl.get("scan_per_request"):
             flops += s["requests"] * work.scan_flops(r["config"]["slab"])
         if flops <= 0:

@@ -2,8 +2,9 @@
 
     JAX_PLATFORMS=cpu python benchmark/rehearse_cpu.py [plain|engine_killed|overload|all]
 
-The toy cells (``benchmark/rehearsal``) go through ``run.run_cell`` exactly
-as a real cell does, past the device gate that ``run.py`` keeps for itself.
+The toy cells (``benchmark/rehearsal``: every workload file there is one) go
+through ``run.run_cell`` exactly as a real cell does, past the device gate
+that ``run.py`` keeps for itself.
 What this prints are counts and a well-formed last line, never a device
 metric.  ``engine_killed`` stops the engine mid-window and ``overload``
 offers far more than a CPU sustains; both must still end in their line.
@@ -24,23 +25,34 @@ if ROOT not in sys.path:
 REQUIRED = ("correct", "attempted", "failed", "metrics", "device")
 
 
+def _json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
 def toy_manifest() -> dict:
-    """BENCHMARK.json with its cells swapped for the toy ones of the same
-    traffic, so that the toy cells report the same metrics by the same files."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    toy = {"ingest": "toy-cls.ingest", "retrieve": "toy.retrieve"}
-    traffic_of = {w["name"]: w["traffic"] for w in manifest["workloads"]}
+    """BENCHMARK.json with its cells swapped for the toy ones: every file
+    under ``rehearsal/workloads`` is a toy cell, and stands for the cells of
+    its own traffic kind, so that it reports the same metrics by the same
+    files."""
+    manifest = _json(os.path.join(ROOT, "BENCHMARK.json"))
+    bench = os.path.join(ROOT, manifest["paths"][0])
+    toys = {
+        name[:-5]: _json(os.path.join(bench, "rehearsal", "workloads", name))
+        for name in sorted(os.listdir(os.path.join(bench, "rehearsal", "workloads")))
+        if name.endswith(".json")
+    }
+    toy_of = {wl["kind"]: cell for cell, wl in toys.items()}
+    kind_of = {w["name"]: _json(os.path.join(bench, "workloads", w["name"] + ".json"))["kind"] for w in manifest["workloads"]}
     for m in manifest["end_to_end"] + manifest["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = sorted({toy[traffic_of[w]] for w in m["workloads"]})
+            m["workloads"] = sorted({toy_of[kind_of[w]] for w in m["workloads"] if kind_of[w] in toy_of})
     manifest["data_dir"] = "benchmark/rehearsal"
     manifest["configs"] = [
-        {"name": n, "file": f"benchmark/rehearsal/configs/{n}.json"} for n in ("toy", "toy-cls")
+        {"name": n, "file": f"benchmark/rehearsal/configs/{n}.json"} for n in sorted({wl["config"] for wl in toys.values()})
     ]
     manifest["workloads"] = [
-        {"name": "toy.retrieve", "config": "toy", "traffic": "retrieve", "chips": 1},
-        {"name": "toy-cls.ingest", "config": "toy-cls", "traffic": "ingest", "chips": 1},
+        {"name": cell, "config": wl["config"], "traffic": wl["kind"], "chips": 1} for cell, wl in toys.items()
     ]
     return manifest
 
@@ -61,7 +73,11 @@ def kill_engine_after(seconds: float):
 def rehearse(cell: str, seed: int, seconds: float, trace: bool, sabotage=None, edit=None) -> dict:
     from benchmark import run
 
-    manifest = toy_manifest()
+    # a cell BENCHMARK.json names runs from its own files, as a run on the chip
+    # would; any other is a toy cell of benchmark/rehearsal
+    manifest = _json(os.path.join(ROOT, "BENCHMARK.json"))
+    if cell not in {w["name"] for w in manifest["workloads"]}:
+        manifest = toy_manifest()
     root = ROOT
     if edit is not None:  # a workload edited for this rehearsal only, in a scratch copy
         import shutil

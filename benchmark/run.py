@@ -10,15 +10,17 @@ standard output is one JSON object with ``correct``, ``attempted``,
 the output check compared, beside its limit), and the exit code is 0.  What
 went wrong is on standard error and in ``correct``.
 
-The harness holds no list of names: the cell, its configuration, its traffic
-kind and its per-layer metrics are files found by the names in
-``BENCHMARK.json`` (see ``benchmark/README.md``).
+The harness holds no list of names and no default: the cell, its
+configuration, its system kind, its model families, its traffic kind, its
+check kind and its per-layer metrics are files found by the names in
+``BENCHMARK.json`` and in the cell's own data files (``benchmark/README.md``,
+``benchmark/doors.py``).  A name with no file ends the run in its line, with
+``correct`` false and the missing file named under ``error``.
 """
 
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import os
 import shutil
@@ -31,7 +33,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.system import T0, System, log  # noqa: E402  (T0: the process's start)
+from benchmark import doors  # noqa: E402
+from benchmark.system import T0, log  # noqa: E402  (T0: the process's start)
 
 
 def load_cell(manifest: dict, root: str, workload: str) -> dict:
@@ -78,21 +81,6 @@ def memory_peak() -> int:
     return int(max(peaks))
 
 
-def ask_after_window(system, texts: list[str], k: int, timeout_s: float) -> list:
-    """The ingest cell reads its own chunks back through the user's door."""
-    from pathway_tpu.xpacks.llm.vector_store import VectorStoreClient
-
-    client = VectorStoreClient(port=system.port, timeout=timeout_s)
-    answers = []
-    for text in texts:
-        try:
-            answers.append((text, client.query(text, k)))
-        except Exception as e:  # counted as a wrong answer by the parser
-            log(f"read-back failed: {type(e).__name__}: {e}")
-            answers.append((text, None))
-    return answers
-
-
 def run_cell(manifest: dict, root: str, workload: str, seed: int, seconds: float, trace: bool, sabotage=None, control: str | None = None) -> dict:
     """Everything after the device gate.  Returns the last line as a dict;
     never raises.  Two hooks serve ``benchmark/tests`` and
@@ -113,10 +101,14 @@ def run_cell(manifest: dict, root: str, workload: str, seed: int, seconds: float
         from benchmark.peaks import peaks_for
 
         tracer = trace_mod.Tracer(os.path.join(scratch, "trace")) if trace else None
-        system = System(config, seed, scratch, chips=spec["cell"]["chips"])
+        cfg_file, wl_file = f"configs/{spec['cell']['config']}.json", f"workloads/{workload}.json"
+        system_kind = doors.find("systems", config.get("system"), f"{cfg_file} `system`")
+        traffic_kind = doors.find("traffic", wl.get("kind"), f"{wl_file} `kind`")
+        check_kind = doors.find("checks", wl.get("check", {}).get("kind"), f"{wl_file} `check.kind`")
+        system = system_kind.System(config, seed, scratch, chips=spec["cell"]["chips"])
         system.start()
-        system.fill(wl["filler_rows"], wl["warm_grid"].get("scatter_rows"))
-        traffic = importlib.import_module(f"benchmark.traffic.{wl['kind']}").Traffic(system, wl, seed, seconds, tracer)
+        system.fill(wl)
+        traffic = traffic_kind.Traffic(system, wl, seed, seconds, tracer)
         traffic.setup()
         if sabotage is not None:
             sabotage(system)
@@ -133,14 +125,7 @@ def run_cell(manifest: dict, root: str, workload: str, seed: int, seconds: float
             line["failed"] += missing
         if tracer is not None:
             tracer.wait(timeout=120)
-        grew = system.slab.capacity != config["slab"]["capacity_rows"]
-        sample = traffic.check_sample()
-        if sample.get("ask"):
-            if system.watch.fault():  # nobody answers: every read-back counts as wrong
-                sample["answers"] = [(text, None) for text in sample["ask"]]
-            else:
-                sample["answers"] = ask_after_window(system, sample["ask"], sample["k"], wl["check"]["timeout_s"])
-        stored = system.stored_vectors(sample["chunk_ids"])
+        collected = check_kind.collect(system, traffic, wl)
         line["device"]["memory_peak_bytes"] = memory_peak()
         fault = system.watch.fault()
         system.stop()
@@ -149,23 +134,9 @@ def run_cell(manifest: dict, root: str, workload: str, seed: int, seconds: float
 
         # --- the output check, against the plain reference -----------------
         t_check = time.monotonic()
-        answers, wrong = check.parse_answers(sample["answers"], sample["live_texts"], sample["k"])
-        _, wrong_all = check.parse_answers(sample["all_answers"], sample["live_texts"], sample["k"])
-        returned = {i for _q, pairs in answers for i, _s in pairs}
-        ref_ids = list(dict.fromkeys([*sample["reference_ids"], *sorted(returned)]))
-        ref = check.reference_side(
-            params, config, seed, wl["filler_rows"], sample["live_texts"], ref_ids,
-            [q for q, _p in answers], sample["k"],
-        )
-        numbers = check.compare(stored, sample["chunk_ids"], answers, ref, wrong + wrong_all + int(grew))
-        ok, compared = check.verdict(numbers, wl["limits"])
+        ok, compared = check.verdict(check_kind.numbers(collected, params, config, wl, seed), wl["limits"])
         if control is not None:
-            ctrl = check.reference_side(
-                params, config, seed, wl["filler_rows"], sample["live_texts"], ref_ids,
-                [q for q, _p in answers], sample["k"], precision=control,
-            )
-            c_stored, c_answers = check.control_side(ctrl, sample["chunk_ids"], sample["k"])
-            line["control"] = check.compare(c_stored, sample["chunk_ids"], c_answers, ref, 0)
+            line["control"] = check_kind.numbers(collected, params, config, wl, seed, precision=control)
         log(f"output check took {time.monotonic() - t_check:.1f} s")
         if fault:
             log(f"engine fault: {fault}")
@@ -183,7 +154,7 @@ def run_cell(manifest: dict, root: str, workload: str, seed: int, seconds: float
             line["device"]["busy_s"] = readings["trace"]["busy_s"]
             line["device"]["window_s"] = readings["trace"]["window_s"]
             for decl in spec["per_layer"]:
-                reader = importlib.import_module(f"benchmark.readers.{decl['reader']}")
+                reader = doors.find("readers", decl.get("reader"), f"metrics/{decl['name']}.json `reader`")
                 value = reader.read(decl, readings)
                 if value is not None:
                     line["metrics"][decl["name"]] = {"value": float(value), "unit": decl["unit"]}
@@ -192,6 +163,7 @@ def run_cell(manifest: dict, root: str, workload: str, seed: int, seconds: float
     except BaseException as e:  # the run still ends in its line
         traceback.print_exc(file=sys.stderr)
         log(f"the run broke: {type(e).__name__}: {e}")
+        line["error"] = f"{type(e).__name__}: {e}"
         line["correct"] = False
         line["failed"] = max(line["failed"], line["attempted"], 1)
         line["attempted"] = max(line["attempted"], line["failed"])

@@ -20,7 +20,6 @@ set-up, so these spreads are a floor under those of separate runs.
 
 from __future__ import annotations
 
-import importlib
 import json
 import os
 import shutil
@@ -36,8 +35,7 @@ if ROOT not in sys.path:
 
 
 def main(argv: list[str]) -> int:
-    from benchmark import run
-    from benchmark.system import System
+    from benchmark import doors, run
     from benchmark.traffic.retrieve_open import peak_in_flight
 
     workload, seconds = argv[0], float(argv[1])
@@ -54,14 +52,15 @@ def main(argv: list[str]) -> int:
     wl = dict(spec["workload"], max_in_flight=most)
     os.makedirs(spec["scratch_parent"], exist_ok=True)
     scratch = tempfile.mkdtemp(prefix="sweep-", dir=spec["scratch_parent"])
-    system = System(spec["config"], 7, scratch, chips=spec["cell"]["chips"])
+    system_kind = doors.find("systems", spec["config"].get("system"), f"configs/{spec['cell']['config']}.json `system`")
+    system = system_kind.System(spec["config"], 7, scratch, chips=spec["cell"]["chips"])
     try:
         system.start()
-        system.fill(wl["filler_rows"], wl["warm_grid"].get("scatter_rows"))
+        system.fill(wl)
         grid = wl["warm_grid"]
         powers = [1 << i for i in range(most.bit_length())]
         wl["warm_grid"] = dict(grid, encoder_rows=[p for p in powers if p >= 8], search_rows=powers)
-        traffic = importlib.import_module(f"benchmark.traffic.{wl['kind']}").Traffic(system, wl, 7, seconds, None)
+        traffic = doors.find("traffic", wl.get("kind"), f"workloads/{workload}.json `kind`").Traffic(system, wl, 7, seconds, None)
         traffic.setup()
 
         def probe(rate: float, n: int) -> dict:

@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+from benchmark import doors, run
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 
@@ -26,7 +28,13 @@ def test_every_name_has_its_file_and_every_file_agrees(manifest):
     configs = {c["name"]: c for c in manifest["configs"]}
     for c in manifest["configs"]:
         with open(os.path.join(ROOT, c["file"])) as f:
-            assert json.load(f)["name"] == c["name"]
+            config = json.load(f)
+        assert config["name"] == c["name"]
+        assert os.path.exists(os.path.join(bench, "systems", config["system"] + ".py"))
+        groups = doors.model_groups(config)
+        assert groups, "a configuration has at least one model group that names its family"
+        for group in groups.values():
+            assert os.path.exists(os.path.join(bench, "families", group["family"] + ".py"))
         assert all(NAME.match(k) for k in c["reduced"])
     for w in manifest["workloads"]:
         assert NAME.match(w["name"]) and len(w["why"]) <= 200 and w["chips"] in (1, 4)
@@ -34,6 +42,7 @@ def test_every_name_has_its_file_and_every_file_agrees(manifest):
             wl = json.load(f)
         assert wl["config"] == w["config"] and w["config"] in configs
         assert os.path.exists(os.path.join(bench, "traffic", wl["kind"] + ".py"))
+        assert os.path.exists(os.path.join(bench, "checks", wl["check"]["kind"] + ".py"))
     e2e = {m["name"] for m in manifest["end_to_end"]}
     assert "setup_s" in e2e
     for m in manifest["per_layer"]:
@@ -45,6 +54,34 @@ def test_every_name_has_its_file_and_every_file_agrees(manifest):
         assert os.path.exists(os.path.join(bench, "readers", decl["reader"] + ".py"))
     declared = {os.path.basename(p)[:-5] for p in glob.glob(os.path.join(bench, "metrics", "*.json"))}
     assert declared == {m["name"] for m in manifest["per_layer"]}
+
+
+def test_a_metric_with_a_list_is_those_cells_and_one_with_no_list_is_every_cells(manifest):
+    """An end-to-end metric that lists its cells is theirs alone and its list is
+    never empty (the driver refuses a metric that no cell reports, so a new one
+    comes with its first cell); ``setup_s`` has no list and is every cell's."""
+    declared = {m["name"]: m for m in manifest["end_to_end"]}
+    cells = {w["name"] for w in manifest["workloads"]}
+    assert "workloads" not in declared["setup_s"]
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        assert "workloads" not in m or (m["workloads"] and set(m["workloads"]) <= cells), m["name"]
+    for w in manifest["workloads"]:
+        names = {m["name"] for m in run.load_cell(manifest, ROOT, w["name"])["end_to_end"]}
+        assert names == {n for n, m in declared.items() if w["name"] in m.get("workloads", cells)}
+        assert "setup_s" in names and len(names) >= 2
+    # a later `benchmark` PR declares one with its first cell: that cell reports it and no other does
+    first, *others = sorted(cells)
+    later = dict(manifest, end_to_end=[*manifest["end_to_end"], dict(declared["retrieve_p50_ms"], name="answer_p50_ms", workloads=[first])])
+    assert "answer_p50_ms" in {m["name"] for m in run.load_cell(later, ROOT, first)["end_to_end"]}
+    for other in others:
+        assert "answer_p50_ms" not in {m["name"] for m in run.load_cell(later, ROOT, other)["end_to_end"]}
+
+
+def test_a_name_with_no_file_is_an_error_that_names_the_file():
+    with pytest.raises(doors.MissingKind, match=r"benchmark/families/no_such_family\.py does not exist"):
+        doors.family({"family": "no_such_family"})
+    with pytest.raises(doors.MissingKind, match="names nothing"):
+        doors.find("systems", None, "configs/c.json `system`")
 
 
 def test_a_full_check_fits_its_budget_with_24_cells(manifest):

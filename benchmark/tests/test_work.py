@@ -1,6 +1,9 @@
 """The work functions against numbers worked by hand."""
 
-from benchmark import work
+import pytest
+
+from benchmark import doors, work
+from benchmark.families import bert_encoder
 from benchmark.peaks import peaks_for
 
 BGE = {"hidden_size": 1024, "intermediate_size": 4096, "num_hidden_layers": 24}
@@ -8,8 +11,22 @@ BGE = {"hidden_size": 1024, "intermediate_size": 4096, "num_hidden_layers": 24}
 
 def test_encoder_flops_of_one_padded_bge_row():
     # per layer 512 * (8*1024^2 + 4*1024*4096) + 4*512^2*1024 = 12.88e9 + 1.07e9
-    assert work.encoder_flops(BGE, 512) == 24 * (512 * (8 * 1024**2 + 4 * 1024 * 4096) + 4 * 512**2 * 1024)
-    assert 330e9 < work.encoder_flops(BGE, 512) < 340e9
+    assert bert_encoder.row_flops(BGE, 512) == 24 * (512 * (8 * 1024**2 + 4 * 1024 * 4096) + 4 * 512**2 * 1024)
+    assert 330e9 < bert_encoder.row_flops(BGE, 512) < 340e9
+    assert bert_encoder.flops(BGE, [512, 512, 16]) == 2 * bert_encoder.row_flops(BGE, 512) + bert_encoder.row_flops(BGE, 16)
+
+
+def test_a_slices_model_work_is_summed_over_the_groups_it_went_through():
+    group = dict(BGE, family="bert_encoder")
+    one = {"name": "c", "model": group, "slab": {"dim": 4}}
+    assert doors.model_flops(one, [512, 16]) == bert_encoder.flops(BGE, [512, 16])
+    assert doors.model_flops(one, {"model": [512]}) == bert_encoder.row_flops(BGE, 512)
+    two = dict(one, generator=dict(group, num_hidden_layers=2))
+    want = bert_encoder.row_flops(BGE, 512) + bert_encoder.row_flops(two["generator"], 16)
+    assert doors.model_flops(two, {"model": [512], "generator": [16]}) == want
+    assert doors.model_flops(two, {"generator": [16]}) == bert_encoder.row_flops(two["generator"], 16)
+    with pytest.raises(doors.MissingKind):  # two groups, and the slice does not say which
+        doors.model_flops(two, [512])
 
 
 def test_scan_work_of_the_minilm_slab():
@@ -22,7 +39,5 @@ def test_scan_work_of_the_minilm_slab():
 
 
 def test_an_unknown_chip_is_an_error():
-    import pytest
-
     with pytest.raises(KeyError):
         peaks_for("TPU v9")
