@@ -24,7 +24,14 @@ counters are monotonic:
   packed, several; rows and tokens as given and as padded), ``search_*``
   (``ShardedKnnIndex.dispatch``), ``scatter_*`` (``add_batch`` /
   ``add_batch_device``), ``epochs`` / ``epoch_rows`` (the scheduler's
-  cut) and ``rest_requests`` / ``rest_responses`` (``io/http``).
+  cut), ``rest_requests`` / ``rest_responses`` (``io/http``) and, once a
+  generated answer (``JittedDecoder.generate``): ``gen_*`` (the prompt's
+  tokens as given and as padded to chunk buckets, its prefill dispatches,
+  the tokens chosen and the decode steps), ``moe_rows_here`` /
+  ``moe_rows_routed`` (token-expert pairs the experts held here computed /
+  pairs the router chose anywhere) and ``dsa_keys_selected`` /
+  ``dsa_keys_scored`` (keys the queries attended to / keys their indexer
+  scored), the last four counted on the device by the programs themselves.
 
 :func:`snapshot` is the one door through which the benchmark reads the
 program: the counters above and the span recorder's stage totals
@@ -83,6 +90,16 @@ _counters: dict[str, int] = {
     "epoch_rows": 0,
     "rest_requests": 0,
     "rest_responses": 0,
+    "gen_requests": 0,
+    "gen_prompt_tokens": 0,
+    "gen_prompt_tokens_padded": 0,
+    "gen_prefill_dispatches": 0,
+    "gen_new_tokens": 0,
+    "gen_decode_steps": 0,
+    "moe_rows_here": 0,
+    "moe_rows_routed": 0,
+    "dsa_keys_selected": 0,
+    "dsa_keys_scored": 0,
 }
 
 

@@ -8,6 +8,7 @@ flax modules, jit-compiled in bf16, batched per epoch, and shardable
 ``jax.sharding.Mesh``.
 """
 
+from pathway_tpu.models.decoder import DEEPSEEK_V32_EXP, DecoderConfig
 from pathway_tpu.models.encoder import (
     BGE_BASE,
     BGE_LARGE,
@@ -25,6 +26,8 @@ from pathway_tpu.models.vision import SIGLIP_BASE, DualEncoderModel, VisionConfi
 
 __all__ = [
     "EncoderConfig",
+    "DecoderConfig",
+    "DEEPSEEK_V32_EXP",
     "TextEncoderModel",
     "CrossEncoderModel",
     "VisionConfig",

@@ -1,0 +1,413 @@
+"""A causal decoder with a latent (MLA) cache, lightning-indexer sparse
+attention and a share of routed experts -- the generation stage.
+
+The architecture is DeepSeek-V3.2-Exp's (``config.json`` keys keep their
+published names in :class:`DecoderConfig`); three more keys say which share
+of a layer this chip holds: ``experts_held`` / ``expert_offset`` (the router
+keeps its published width and its experts per token, the chip computes its
+own experts' part of the result and leaves out what the absent ones would
+add) and ``vocab_held`` (embedding, head, logits and the greedy choice are
+over that slice).
+
+Two programs over one pre-sized cache, both pure functions of ``(params,
+..., cache)`` that return the cache they were given, updated in place when
+the caller donates it (:class:`pathway_tpu.parallel.JittedDecoder` does):
+
+- :func:`prefill` -- a bucket of prompt tokens of one sequence, written into
+  its slot from ``start`` on.  Keys and values are expanded from the latent
+  rows per head (the compute-bound form), block of keys by block with a
+  running softmax, over as many blocks as the chunk's last token can see:
+  on a TPU in one fused kernel (``ops/selected_attention.py``), elsewhere as
+  the same loop written in ``jax.numpy``.
+- :func:`decode_step` -- one new token for each of a few sequences, in the
+  absorbed form: 128 query heads against one 576-wide latent row a token.
+
+Per layer the cache holds two kinds of state side by side: the latent row
+``[cKV; k_rope]`` (``kv_lora_rank + qk_rope_head_dim`` values a token) and
+the indexer's key (``index_head_dim`` values a token).  A query attends to
+the ``index_topk`` keys its indexer scores highest among those before it;
+the selection is a mask at the exact k-th largest score (a bisection over
+the scores' bit patterns, no sort), so prefill and decode share one rule.
+
+Weights and caches are ``config.dtype`` (bfloat16); products accumulate in
+float32; the residual stream, norms, the router, the indexer's scores and
+the softmax are float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+__all__ = ["DecoderConfig", "DEEPSEEK_V32_EXP", "init_cache", "prefill", "decode_step", "STATS"]
+
+#: what both programs count, in the order of the vector they return
+STATS = ("moe_rows_here", "moe_rows_routed", "dsa_keys_selected", "dsa_keys_scored")
+
+_NEG = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    hidden_size: int = 7168
+    num_hidden_layers: int = 61
+    first_k_dense_replace: int = 3
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 256  # the router's width
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    rope_theta: float = 10000.0
+    rope_scaling: tuple = (
+        ("beta_fast", 32), ("beta_slow", 1), ("factor", 40), ("mscale", 1),
+        ("mscale_all_dim", 1), ("original_max_position_embeddings", 4096), ("type", "yarn"),
+    )
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 129280  # published; ``vocab_held`` rows of it live here
+    # --- this chip's share of a layer
+    experts_held: int = 256
+    expert_offset: int = 0
+    vocab_held: int = 129280
+    dtype: Any = jnp.bfloat16
+    # --- blocking (no width): keys a block of the prefill's attention loop,
+    # token-expert pairs a block of the expert loop
+    key_block: int = 512
+    expert_block: int = 128
+
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        rs = dict(self.rope_scaling)
+        m = 0.1 * rs["mscale_all_dim"] * math.log(rs["factor"]) + 1.0
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
+
+    def inv_freq(self) -> np.ndarray:
+        """YaRN: the published frequencies where a rotation completes often
+        within the original context, the interpolated ones where it does not,
+        a linear ramp between."""
+        rs, dim = dict(self.rope_scaling), self.qk_rope_head_dim
+        freq = self.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+        def dim_of(rotations: float) -> float:
+            return dim * math.log(rs["original_max_position_embeddings"] / (rotations * 2 * math.pi)) / (2 * math.log(self.rope_theta))
+
+        low = max(math.floor(dim_of(rs["beta_fast"])), 0)
+        high = min(math.ceil(dim_of(rs["beta_slow"])), dim - 1)
+        interpolated = np.clip((np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0)
+        return freq * (1.0 - interpolated) + freq / rs["factor"] * interpolated
+
+
+#: the published configuration, uncut
+DEEPSEEK_V32_EXP = DecoderConfig()
+
+
+def init_cache(config: DecoderConfig, slots: int, positions: int) -> dict:
+    """The two kinds of state of every layer, for ``slots`` sequences of up
+    to ``positions`` tokens, zeroed."""
+    shape = (config.num_hidden_layers, slots, positions)
+    return {
+        "latent": jnp.zeros((*shape, config.latent_width), config.dtype),
+        "index_k": jnp.zeros((*shape, config.index_head_dim), config.dtype),
+    }
+
+
+# ------------------------------------------------------------------ pieces
+def _mm(spec: str, a, b, out=None):
+    """A product of ``config.dtype`` inputs accumulated in float32."""
+    y = jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+    return y if out is None else y.astype(out)
+
+
+def _rms(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * scale
+
+
+def _rotate(x, pos, inv_freq):
+    """Rope on the last axis of ``x`` [T, ..., dim], neighbouring pairs."""
+    ang = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    ang = ang.reshape((ang.shape[0],) + (1,) * (x.ndim - 2) + (ang.shape[1],))
+    x = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    re, im = x[..., 0], x[..., 1]
+    out = jnp.stack([re * jnp.cos(ang) - im * jnp.sin(ang), re * jnp.sin(ang) + im * jnp.cos(ang)], axis=-1)
+    return out.reshape(*out.shape[:-2], -1)
+
+
+def _swiglu(x, p, dt):
+    hidden = jax.nn.silu(_mm("tc,cf->tf", x, p["gate"])) * _mm("tc,cf->tf", x, p["up"])
+    return _mm("tf,fc->tc", hidden.astype(dt), p["down"])
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+
+
+def _select(scores, visible, k: int):
+    """For each row the keys it attends to: the ``k`` largest ``scores``
+    among the ``visible``, all of them where fewer are.  The threshold is
+    the exact k-th largest, found bit by bit from the top: 32 counts."""
+    bits = jnp.where(visible, _ordered_bits(scores), jnp.uint32(0))
+
+    def narrow(i, found):
+        trial = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(bits >= trial[:, None], axis=-1) >= k
+        return jnp.where(enough, trial, found)
+
+    kth = jax.lax.fori_loop(0, 32, narrow, jnp.zeros(bits.shape[:1], jnp.uint32))
+    return visible & (bits >= kth[:, None])
+
+
+def _attention_inputs(h, lp, pos, cfg: DecoderConfig):
+    """What both forms of attention share: the queries, the indexer's
+    queries and weights, and the two rows each token adds to the cache."""
+    dt, eps = cfg.dtype, cfg.rms_norm_eps
+    T = h.shape[0]
+    inv_freq = jnp.asarray(cfg.inv_freq(), jnp.float32)
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    x = _rms(h, lp["attn_norm"], eps).astype(dt)
+    cq = _rms(_mm("tc,cr->tr", x, lp["q_a"]), lp["q_norm"], eps).astype(dt)
+    q = _mm("tr,rd->td", cq, lp["q_b"]).reshape(T, cfg.num_attention_heads, nope + rope)
+    q_nope, q_rope = q[..., :nope].astype(dt), _rotate(q[..., nope:], pos, inv_freq).astype(dt)
+    kva = _mm("tc,cr->tr", x, lp["kv_a"])
+    latent = jnp.concatenate(
+        [_rms(kva[:, : cfg.kv_lora_rank], lp["kv_norm"], eps), _rotate(kva[:, cfg.kv_lora_rank :], pos, inv_freq)], axis=-1
+    ).astype(dt)
+    qi = _mm("tr,rd->td", cq, lp["idx_q"]).reshape(T, cfg.index_n_heads, cfg.index_head_dim)
+    qi = jnp.concatenate([_rotate(qi[..., :rope], pos, inv_freq), qi[..., rope:]], axis=-1).astype(dt)
+    ki = _mm("tc,cd->td", x, lp["idx_k"])
+    mean = jnp.mean(ki, axis=-1, keepdims=True)
+    ki = (ki - mean) * jax.lax.rsqrt(jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True) + 1e-6)
+    ki = ki * lp["idx_k_norm"]["scale"] + lp["idx_k_norm"]["bias"]
+    ki = jnp.concatenate([_rotate(ki[:, :rope], pos, inv_freq), ki[:, rope:]], axis=-1).astype(dt)
+    wi = _mm("tc,cj->tj", x, lp["idx_w"]) * (cfg.index_n_heads**-0.5 * cfg.index_head_dim**-0.5)
+    return q_nope, q_rope, latent, qi, ki, wi
+
+
+def _route(x, lp, cfg: DecoderConfig):
+    """Each token's chosen experts (published numbers) and their gates."""
+    s = jax.nn.sigmoid(jnp.einsum("tc,ce->te", x.astype(jnp.float32), lp["router"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
+    biased = s + lp["router_bias"]
+    T, E = s.shape
+    per = E // cfg.n_group
+    grouped = biased.reshape(T, cfg.n_group, per)
+    group_rank = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    best = jax.lax.top_k(group_rank, cfg.topk_group)[1]
+    keep = jnp.any(best[:, :, None] == jnp.arange(cfg.n_group)[None, None, :], axis=1)
+    eligible = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(T, E)
+    chosen = jax.lax.top_k(eligible, cfg.num_experts_per_tok)[1]
+    weight = jnp.take_along_axis(s, chosen, axis=1)
+    return chosen, weight / jnp.sum(weight, axis=1, keepdims=True) * cfg.routed_scaling_factor
+
+
+def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig):
+    """The part of the routed result that the experts held here give:
+    ``sum over chosen experts e held here of gates_e SwiGLU_e(x)``.
+
+    A grouped product over uneven groups with nothing dropped: the
+    token-expert pairs that fall to this chip are ordered by expert, each
+    expert's run is cut into blocks of ``expert_block`` pairs (the last one
+    part empty), and a loop over exactly the blocks in use multiplies each by
+    its expert's three matrices.  Work follows the pairs that came, not the
+    worst case.  Returns the result [T, hidden] float32 and the pairs
+    computed."""
+    T, K = chosen.shape
+    E, B, dt = cfg.experts_held, cfg.expert_block, cfg.dtype
+    local = chosen - cfg.expert_offset
+    here = (local >= 0) & (local < E) & live[:, None]
+    expert_of = jnp.where(here, local, E).reshape(-1)  # E: not ours
+    order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
+    count = jnp.sum(expert_of[:, None] == jnp.arange(E)[None, :], axis=0).astype(jnp.int32)
+    first_pair = jnp.cumsum(count) - count
+    blocks = (count + B - 1) // B
+    last_block = jnp.cumsum(blocks)
+    flat_gates = gates.reshape(-1)
+
+    def one_block(b, out):
+        e = jnp.sum(b >= last_block).astype(jnp.int32)  # the expert whose run holds block b
+        within = (b - (last_block[e] - blocks[e])) * B + jnp.arange(B, dtype=jnp.int32)
+        valid = within < count[e]
+        pair = order[jnp.clip(first_pair[e] + within, 0, T * K - 1)]
+        token = pair // K
+        p = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(w, e, keepdims=False), experts)
+        y = _swiglu(x[token], p, dt) * jnp.where(valid, flat_gates[pair], 0.0)[:, None]
+        return out.at[token].add(y)
+
+    out = jax.lax.fori_loop(0, last_block[-1], one_block, jnp.zeros((T, x.shape[1]), jnp.float32))
+    return out, jnp.sum(count)
+
+
+def _mlp(h, lp, live, cfg: DecoderConfig):
+    """The layer's feed-forward half; returns what it adds and the
+    token-expert pairs (computed here, chosen anywhere)."""
+    x = _rms(h, lp["mlp_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+    if "mlp" in lp:
+        return _swiglu(x, lp["mlp"], cfg.dtype), jnp.int32(0), jnp.int32(0)
+    chosen, gates = _route(x, lp, cfg)
+    routed, rows_here = _experts_here(x, chosen, gates, live, lp["experts"], cfg)
+    rows_routed = jnp.sum(live).astype(jnp.int32) * cfg.num_experts_per_tok
+    return _swiglu(x, lp["shared"], cfg.dtype) + routed, rows_here, rows_routed
+
+
+def _logits(h, params, cfg: DecoderConfig):
+    x = _rms(h, params["final_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+    return _mm("tc,cv->tv", x, params["head"])
+
+
+def _rows_of(cache, layer: int, slot):
+    """One sequence's rows of one layer, [positions, width], sliced out of
+    the whole cache (never a layer's worth of slots)."""
+    _, _, positions, width = cache.shape
+    return jax.lax.dynamic_slice(cache, (layer, slot, 0, 0), (1, 1, positions, width))[0, 0]
+
+
+# ----------------------------------------------------------------- prefill
+def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg: DecoderConfig):
+    """Attention of a chunk's queries over their sequence's cached rows, in
+    the expanded form.  ``n_blocks`` key blocks are visited: those the
+    chunk's last token can see."""
+    C, KB, dt = q_nope.shape[0], cfg.key_block, cfg.dtype
+    H, nope, vd, rank = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    L = latent_rows.shape[0]
+
+    def score_block(b, scores):
+        keys = jax.lax.dynamic_slice_in_dim(index_rows, b * KB, KB)
+        per_head = jax.nn.relu(_mm("tjd,sd->tjs", qi, keys))
+        return jax.lax.dynamic_update_slice_in_dim(scores, jnp.sum(per_head * wi[:, :, None], axis=1), b * KB, axis=1)
+
+    scores = jax.lax.fori_loop(0, n_blocks, score_block, jnp.full((C, L), _NEG, jnp.float32))
+    visible = jnp.arange(L)[None, :] <= pos[:, None]
+    selected = _select(scores, visible, cfg.index_topk)
+    if jax.default_backend() == "tpu":
+        # the fused kernel (ops/selected_attention.py): keys and values expanded block by block into buffers per
+        # head, then scores, softmax and weighted sum with nothing of a score tile leaving the chip
+        from pathway_tpu.ops.selected_attention import selected_attention  # Pallas: a second to import, so only where it runs
+
+        kv_b = lp["kv_b"].reshape(rank, H, nope + vd)
+
+        def expand_block(b, buffers):
+            rows = jax.lax.dynamic_slice_in_dim(latent_rows, b * KB, KB)[:, :rank]
+            parts = (_mm("sr,rhd->hsd", rows, kv_b[..., :nope], dt), _mm("sr,rhd->hsd", rows, kv_b[..., nope:], dt))
+            return tuple(jax.lax.dynamic_update_slice_in_dim(buf, part, b * KB, axis=1) for buf, part in zip(buffers, parts))
+
+        k_nope, v = jax.lax.fori_loop(0, n_blocks, expand_block, (jnp.zeros((H, L, nope), dt), jnp.zeros((H, L, vd), dt)))
+        scaled = lambda q: (q.astype(jnp.float32) * cfg.softmax_scale).astype(dt).transpose(1, 0, 2)
+        out = selected_attention(scaled(q_nope), scaled(q_rope), k_nope, latent_rows[:, rank:], v, selected, n_blocks, block_k=KB)
+        return _mm("td,dc->tc", out.transpose(1, 0, 2).reshape(C, H * vd), lp["o"]), selected, visible
+
+    def attend_block(b, carry):
+        top, mass, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(latent_rows, b * KB, KB)
+        kv = _mm("sr,rd->sd", rows[:, :rank], lp["kv_b"], dt).reshape(KB, H, nope + vd)
+        s = (_mm("thd,shd->hts", q_nope, kv[..., :nope]) + _mm("thd,sd->hts", q_rope, rows[:, rank:])) * cfg.softmax_scale
+        sel = jax.lax.dynamic_slice_in_dim(selected, b * KB, KB, axis=1)[None]
+        new_top = jnp.maximum(top, jnp.max(jnp.where(sel, s, _NEG), axis=-1))
+        p = jnp.where(sel, jnp.exp(s - new_top[..., None]), 0.0)
+        shrink = jnp.exp(top - new_top)
+        acc = acc * shrink[..., None] + _mm("hts,shd->htd", p.astype(dt), kv[..., nope:])
+        return new_top, mass * shrink + jnp.sum(p, axis=-1), acc
+
+    start = (jnp.full((H, C), _NEG, jnp.float32), jnp.zeros((H, C), jnp.float32), jnp.zeros((H, C, vd), jnp.float32))
+    _, mass, acc = jax.lax.fori_loop(0, n_blocks, attend_block, start)
+    out = (acc / mass[..., None]).astype(dt).transpose(1, 0, 2).reshape(C, H * vd)
+    return _mm("td,dc->tc", out, lp["o"]), selected, visible
+
+
+def prefill(params, ids, cache, slot, start, length, *, config: DecoderConfig):
+    """One bucket of a prompt: ``ids`` [C] (``length`` of them real, the rest
+    padding) are the tokens ``start .. start + C`` of the sequence in
+    ``slot``.  Returns float32 logits over the held vocabulary at the last
+    real token, the cache with the chunk's rows written, and the counts of
+    :data:`STATS`.  ``start + C`` may not pass the cache's positions."""
+    cfg = config
+    C = ids.shape[0]
+    pos = start + jnp.arange(C, dtype=jnp.int32)
+    live = jnp.arange(C) < length
+    n_blocks = (start + C + cfg.key_block - 1) // cfg.key_block
+    h = params["embed"][ids].astype(jnp.float32)
+    stats = jnp.zeros((len(STATS),), jnp.int32)
+    latent_all, index_all = cache["latent"], cache["index_k"]
+    for li, lp in enumerate(params["layers"]):
+        q_nope, q_rope, latent, qi, ki, wi = _attention_inputs(h, lp, pos, cfg)
+        latent_all = jax.lax.dynamic_update_slice(latent_all, latent[None, None], (li, slot, start, 0))
+        index_all = jax.lax.dynamic_update_slice(index_all, ki[None, None], (li, slot, start, 0))
+        latent_rows, index_rows = _rows_of(latent_all, li, slot), _rows_of(index_all, li, slot)
+        attended, selected, visible = _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg)
+        h = h + attended
+        added, rows_here, rows_routed = _mlp(h, lp, live, cfg)
+        h = h + added
+        stats = stats + jnp.stack([
+            rows_here, rows_routed,
+            jnp.sum(selected & live[:, None]).astype(jnp.int32), jnp.sum(visible & live[:, None]).astype(jnp.int32),
+        ])
+    last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1)
+    return _logits(last, params, cfg)[0], {"latent": latent_all, "index_k": index_all}, stats
+
+
+# ------------------------------------------------------------------ decode
+def _decode_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, lp, cfg: DecoderConfig):
+    """One query against its sequence's cached rows, in the absorbed form:
+    the query is carried into the latent space (``q_nope W_kvb^K``), scores
+    and the weighted sum are taken over the latent rows themselves, and
+    the result is carried out again (``W_kvb^V``)."""
+    dt, rank, nope = cfg.dtype, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    H, L = cfg.num_attention_heads, latent_rows.shape[0]
+    kv_b = lp["kv_b"].reshape(rank, H, nope + cfg.v_head_dim)
+    index = jnp.sum(jax.nn.relu(_mm("jd,sd->js", qi, index_rows)) * wi[:, None], axis=0)
+    visible = (jnp.arange(L) <= pos)[None, :]
+    selected = _select(index[None, :], visible, cfg.index_topk)
+    q_latent = _mm("hd,rhd->hr", q_nope, kv_b[..., :nope], dt)
+    s = (_mm("hr,sr->hs", q_latent, latent_rows[:, :rank]) + _mm("hd,sd->hs", q_rope, latent_rows[:, rank:])) * cfg.softmax_scale
+    p = jax.nn.softmax(jnp.where(selected, s, _NEG), axis=-1)
+    mixed = _mm("hs,sr->hr", p.astype(dt), latent_rows[:, :rank], dt)
+    out = _mm("hr,rhd->hd", mixed, kv_b[..., nope:], dt).reshape(-1)
+    return out, jnp.sum(selected).astype(jnp.int32), jnp.sum(visible).astype(jnp.int32)
+
+
+def decode_step(params, ids, cache, slots, lengths, *, config: DecoderConfig):
+    """One new token for each of ``ids`` [B]: sequence ``slots[b]`` holds
+    ``lengths[b]`` tokens and ``ids[b]`` becomes its next.  Returns float32
+    logits [B, vocab_held], the cache with one more row a sequence, and the
+    counts of :data:`STATS`."""
+    cfg = config
+    B = ids.shape[0]
+    h = params["embed"][ids].astype(jnp.float32)
+    live = jnp.ones((B,), bool)
+    stats = jnp.zeros((len(STATS),), jnp.int32)
+    latent_all, index_all = cache["latent"], cache["index_k"]
+    for li, lp in enumerate(params["layers"]):
+        q_nope, q_rope, latent, qi, ki, wi = _attention_inputs(h, lp, lengths, cfg)
+        outs = []
+        for b in range(B):  # a row written and a sequence's rows read, each in place: no copy of a cache
+            latent_all = jax.lax.dynamic_update_slice(latent_all, latent[b][None, None, None], (li, slots[b], lengths[b], 0))
+            index_all = jax.lax.dynamic_update_slice(index_all, ki[b][None, None, None], (li, slots[b], lengths[b], 0))
+            outs.append(_decode_attention(
+                q_nope[b], q_rope[b], qi[b], wi[b], _rows_of(latent_all, li, slots[b]), _rows_of(index_all, li, slots[b]), lengths[b], lp, cfg
+            ))
+        out, selected, visible = (jnp.stack(parts) for parts in zip(*outs))
+        h = h + _mm("td,dc->tc", out, lp["o"])
+        added, rows_here, rows_routed = _mlp(h, lp, live, cfg)
+        h = h + added
+        stats = stats + jnp.stack([rows_here, rows_routed, jnp.sum(selected), jnp.sum(visible)])
+    return _logits(h, params, cfg), {"latent": latent_all, "index_k": index_all}, stats

@@ -64,6 +64,10 @@ class HashTokenizer(Tokenizer):
     def count_tokens(self, text: str) -> int:
         return len(_WORD_RE.findall(text.lower()))
 
+    def word_ids(self, text: str) -> list[int]:
+        """One id a word and nothing else: what a decoder's prompt is."""
+        return self._tokens(text)
+
     def encode_batch(
         self,
         texts: Sequence[str],

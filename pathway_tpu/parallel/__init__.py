@@ -31,6 +31,7 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 
 from pathway_tpu.parallel.mesh import best_mesh, make_mesh, mesh_axis_size
 from pathway_tpu.parallel.executor import JittedEncoder
+from pathway_tpu.parallel.generation import JittedDecoder
 from pathway_tpu.parallel.ivf_knn import IvfKnnIndex
 from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
 
@@ -39,6 +40,7 @@ __all__ = [
     "best_mesh",
     "mesh_axis_size",
     "JittedEncoder",
+    "JittedDecoder",
     "IvfKnnIndex",
     "ShardedKnnIndex",
 ]

@@ -1,0 +1,175 @@
+"""The generation stage's executor: per-request state on the device and
+the programs that fill and read it.
+
+:class:`JittedDecoder` stands beside :class:`JittedEncoder` and keeps the
+same discipline.  State is pre-sized and never grows: for every layer two
+caches side by side, the latent rows and the indexer's keys
+(:func:`pathway_tpu.models.decoder.init_cache`), ``slots`` sequences of
+``positions`` tokens, updated in place through donation as the index slab
+is.  Shapes come from a small fixed set: a prompt is cut into chunks of the
+``chunk_buckets`` (:meth:`plan`: the cheapest cover), each one execution of
+the prefill program of its bucket, and every new token is one execution of the one decode
+program.  :meth:`warm` runs all of them once, so that a serving window
+compiles nothing.
+
+:meth:`generate` is the loop that yields tokens: the prompt's chunks are
+enqueued back to back, then the decode steps, each taking the token the
+step before it chose on the device, so the host runs ahead of the chip and
+blocks twice a request, outside the lock that orders the enqueues: for the
+prompt (span ``generate_prefill``) and for the answer (``generate_decode``).
+Requests generate one after another on the device (the engine gives this
+stage one request an epoch); the slots are taken in turn.
+
+The two programs lower as ``jit__prefill_chunk`` and ``jit__decode_token``:
+the benchmark finds their device time by these names and a tier-1 test
+holds them.
+"""
+
+from __future__ import annotations
+
+import threading
+from typing import Any, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from pathway_tpu.internals import device_counters as _devctr
+from pathway_tpu.internals import tracing as _tracing
+from pathway_tpu.models import decoder as _decoder
+from pathway_tpu.parallel.mesh import require_single_process
+
+__all__ = ["JittedDecoder"]
+
+#: what one more prefill dispatch costs beside its tokens, in tokens: every
+#: weight is read again and the sequence's keys and values are expanded again
+#: (on a v5e at the published widths 25 ms, where 512 tokens of a chunk cost 33)
+_DISPATCH_TOKENS = 512
+
+
+class JittedDecoder:
+    """Holds the decoder's params, its caches and its compiled programs.
+
+    ``generate(prompt_ids, max_new_tokens)`` -> the ids chosen (greedy, over
+    the held slice of the vocabulary) and the float32 logits each was
+    chosen from."""
+
+    def __init__(
+        self,
+        config: _decoder.DecoderConfig,
+        *,
+        params: Any,
+        slots: int = 8,
+        positions: int = 8704,
+        chunk_buckets: Sequence[int] = (512, 2048, 2560),
+    ):
+        require_single_process("JittedDecoder")
+        buckets = tuple(sorted(chunk_buckets))
+        unit = buckets[0]
+        if positions % unit or unit % config.key_block or any(b % unit for b in buckets):
+            raise ValueError(
+                f"chunk buckets {buckets} must be multiples of the smallest, which must divide "
+                f"positions {positions} and be a multiple of the key block {config.key_block}"
+            )
+        self.config = config
+        self.params = params
+        self.slots = slots
+        self.positions = positions
+        self.chunk_buckets = buckets
+        self.cache = _decoder.init_cache(config, slots, positions)
+        self._lock = threading.Lock()  # the caches are one donated state
+        self._turn = 0
+
+        def _prefill_chunk(params, ids, cache, slot, start, length):
+            logits, cache, stats = _decoder.prefill(params, ids, cache, slot, start, length, config=config)
+            return jnp.argmax(logits).astype(jnp.int32)[None], logits, cache, stats
+
+        def _decode_token(params, token, cache, slot, length):
+            logits, cache, stats = _decoder.decode_step(params, token, cache, slot, length, config=config)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits[0], cache, stats
+
+        self._prefill = jax.jit(_prefill_chunk, donate_argnums=(2,))
+        self._decode = jax.jit(_decode_token, donate_argnums=(2,))
+
+    # ------------------------------------------------------------------
+    def plan(self, tokens: int) -> list[tuple[int, int, int]]:
+        """A prompt of ``tokens`` tokens as ``(start, real tokens, bucket)``
+        chunks: the cover by buckets that costs least, where a dispatch costs
+        its bucket's tokens and :data:`_DISPATCH_TOKENS` more (only the last
+        chunk may hold padding, and none may pass the cache's end)."""
+        unit = self.chunk_buckets[0]
+        need, room = -(-tokens // unit), self.positions // unit
+        sizes = [b // unit for b in self.chunk_buckets]
+        best: list = [(0, ())]  # best[n]: (cost, buckets) for the prompt's last n units
+        for n in range(1, need + 1):
+            best.append(min(
+                (_DISPATCH_TOKENS // unit + b + best[max(n - b, 0)][0], (-b, *best[max(n - b, 0)][1]))
+                for b in sizes if need - n + b <= room
+            ))
+        chunks, start = [], 0
+        for b in best[need][1]:
+            chunks.append((start, min(tokens - start, -b * unit), -b * unit))
+            start += -b * unit
+        return chunks
+
+    def generate(self, prompt_ids: Sequence[int], max_new_tokens: int) -> dict:
+        """Prefill ``prompt_ids`` and choose ``max_new_tokens`` tokens, each
+        the largest logit over the held vocabulary.  Returns ``{"ids": int32
+        [n], "logits": float32 [n, vocab_held]}``: row i is what ids[i] was
+        chosen from."""
+        prompt = np.asarray(prompt_ids, np.int32)
+        if not 0 < prompt.size <= self.positions - max_new_tokens or max_new_tokens < 1:
+            raise ValueError(
+                f"a prompt of {prompt.size} tokens and {max_new_tokens} new ones do not fit "
+                f"the cache's {self.positions} positions"
+            )
+        chunks = self.plan(prompt.size)
+        steps = max_new_tokens - 1
+        with _tracing.span("generate_prefill") as sp:
+            sp.args = {"tokens": int(prompt.size), "chunks": len(chunks)}
+            # the lock covers the enqueues alone: the caches are one donated state, and the device runs programs
+            # in the order they were enqueued, so whoever enqueues next finds the caches as this request leaves them
+            with self._lock:
+                slot = self._turn % self.slots
+                self._turn += 1
+                slot_arr = np.asarray([slot], np.int32)
+                stats = []
+                for start, real, bucket in chunks:
+                    ids = np.zeros(bucket, np.int32)
+                    ids[:real] = prompt[start : start + real]
+                    _devctr.record_h2d(ids.nbytes)
+                    token, logits, self.cache, st = self._prefill(
+                        self.params, ids, self.cache, np.int32(slot), np.int32(start), np.int32(real)
+                    )
+                    stats.append(st)
+                rows, tokens = [logits], [token]
+                for i in range(steps):
+                    token, logits, self.cache, st = self._decode(
+                        self.params, token, self.cache, slot_arr, np.asarray([prompt.size + i], np.int32)
+                    )
+                    rows.append(logits)
+                    tokens.append(token)
+                    stats.append(st)
+            rows[0].block_until_ready()
+        with _tracing.span("generate_decode") as sp:
+            sp.args = {"steps": steps}
+            rows, tokens, stats = jax.device_get((rows, tokens, stats))
+        logits = np.stack(rows)
+        _devctr.record_d2h(logits.nbytes)
+        counted = np.sum(np.stack(stats).astype(np.int64), axis=0)
+        _devctr.bump(
+            gen_requests=1,
+            gen_prompt_tokens=prompt.size,
+            gen_prompt_tokens_padded=sum(bucket for _s, _r, bucket in chunks),
+            gen_prefill_dispatches=len(chunks),
+            gen_new_tokens=max_new_tokens,
+            gen_decode_steps=steps,
+            **dict(zip(_decoder.STATS, (int(c) for c in counted))),
+        )
+        return {"ids": np.concatenate(tokens).astype(np.int32), "logits": logits}
+
+    def warm(self, max_new_tokens: int = 2) -> None:
+        """Run every program once: a prompt of each chunk bucket's size (one
+        chunk of that bucket), each followed by a decode step."""
+        for bucket in self.chunk_buckets:
+            self.generate(np.ones(min(bucket, self.positions - max_new_tokens), np.int32), max_new_tokens)

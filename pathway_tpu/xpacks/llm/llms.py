@@ -6,10 +6,16 @@
 (OpenAI/LiteLLM/Cohere, reference ``:84/:313/:544``) are gated on their
 client packages; :class:`HFPipelineChat` (``:441``) on a locally cached
 model.  ``prompt_chat_single_qa`` matches the reference helper.
+
+:class:`TPUDecoderChat` is the chat that stays on the chip: a causal
+decoder (``models/decoder.py``) behind :class:`JittedDecoder`, so that
+``BaseRAGQuestionAnswerer(llm=TPUDecoderChat(...))`` answers without the
+request leaving the device that retrieved for it.
 """
 
 from __future__ import annotations
 
+import collections
 from typing import Any
 
 from pathway_tpu.internals import udfs
@@ -21,6 +27,7 @@ __all__ = [
     "LiteLLMChat",
     "HFPipelineChat",
     "CohereChat",
+    "TPUDecoderChat",
     "prompt_chat_single_qa",
 ]
 
@@ -141,3 +148,70 @@ class HFPipelineChat(BaseChat):
         out = self.pipeline(prompt, **{**self.call_kwargs, **kwargs})
         text = out[0]["generated_text"]
         return text[len(prompt) :] if text.startswith(prompt) else text
+
+
+_DECODER_PRESETS = {"deepseek-ai/deepseek-v3.2-exp": "DEEPSEEK_V32_EXP", "deepseek-v3.2-exp": "DEEPSEEK_V32_EXP"}
+
+#: generations :meth:`TPUDecoderChat.recent_generations` keeps
+_KEPT_GENERATIONS = 64
+
+
+class TPUDecoderChat(BaseChat):
+    """A causal decoder on the TPU; one greedy generation a call.
+
+    ``model`` names an architecture preset; ``config`` (a ``DecoderConfig``)
+    takes its place for a share of a layer or a small size.  ``params`` is
+    the decoder's parameter tree, handed in as ``TPUEncoderEmbedder`` takes
+    one: no checkpoint of this family can be read here yet.  The prompt is
+    the messages' contents, one token a word (the hashing tokenizer over the
+    held slice of the vocabulary); with no vocabulary to print from, the
+    answer is rendered from the ids chosen: ``t<id>`` a token.
+    """
+
+    def __init__(
+        self,
+        model: str = "deepseek-ai/DeepSeek-V3.2-Exp",
+        *,
+        params: Any = None,
+        config: Any = None,
+        max_new_tokens: int = 32,
+        slots: int = 8,
+        positions: int = 8704,
+        chunk_buckets: tuple = (512, 2048, 2560),
+        **kwargs: Any,
+    ):
+        super().__init__(model=model, **kwargs)
+        from pathway_tpu.models import decoder
+        from pathway_tpu.models.tokenizer import HashTokenizer
+        from pathway_tpu.parallel import JittedDecoder
+
+        if config is None:
+            preset = _DECODER_PRESETS.get(model.lower())
+            if preset is None:
+                raise ValueError(f"unknown decoder model {model!r}: not one of the presets {sorted(_DECODER_PRESETS)}")
+            config = getattr(decoder, preset)
+        if params is None:
+            raise ValueError("TPUDecoderChat needs params=: the decoder's parameter tree (models/decoder.py names its leaves)")
+        self.max_new_tokens = max_new_tokens
+        self.tokenizer = HashTokenizer(config.vocab_held)
+        self.decoder = JittedDecoder(config, params=params, slots=slots, positions=positions, chunk_buckets=chunk_buckets)
+        self._recent: collections.deque = collections.deque(maxlen=_KEPT_GENERATIONS)
+
+    def __wrapped__(self, messages: list[dict] | str, **kwargs: Any) -> str:
+        from pathway_tpu.internals import tracing
+
+        if isinstance(messages, str):
+            messages = prompt_chat_single_qa(messages)
+        with tracing.span("generate_tokenize"):
+            prompt_ids = self.tokenizer.word_ids("\n".join(str(m.get("content", "")) for m in messages))
+        out = self.decoder.generate(prompt_ids, self.max_new_tokens)
+        with tracing.span("generate_detokenize"):
+            text = " ".join(f"t{i}" for i in out["ids"])
+        self._recent.append({"prompt_ids": prompt_ids, "ids": out["ids"], "logits": out["logits"], "text": text})
+        return text
+
+    def recent_generations(self) -> list[dict]:
+        """The last 64 generations, oldest first: the prompt's ids, the ids
+        chosen, the float32 logits over the held vocabulary each was chosen
+        from (what a ``logprobs`` caller is given) and the text returned."""
+        return list(self._recent)

@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import pathway_tpu as pw
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.internals.table import Table
 from pathway_tpu.xpacks.llm import prompts
 from pathway_tpu.xpacks.llm.document_store import DocumentStore
@@ -135,7 +136,8 @@ class BaseRAGQuestionAnswerer(SummaryQuestionAnswerer):
 
         def answer(prompt: str, docs: list, return_context: Any) -> dict:
             docs = list(docs or ())
-            text = template(prompt, docs)
+            with _tracing.span("answer_prompt"):
+                text = template(prompt, docs)
             response = _call_llm(self.llm, prompt_chat_single_qa(text))
             out: dict = {"response": response}
             if return_context:
