@@ -21,12 +21,19 @@ import os
 from typing import Any, Callable, Iterable, Sequence
 
 from pathway_tpu.internals import api
+from pathway_tpu.internals import device_counters, tracing
 from pathway_tpu.internals import keys as K
 from pathway_tpu.internals import native as _native
 from pathway_tpu.internals.keys import Pointer
 from pathway_tpu.engine import cluster as cl
 from pathway_tpu.engine.reducers import ReducerImpl
-from pathway_tpu.engine.stream import Batch, Update, consolidate, per_key_changes
+from pathway_tpu.engine.stream import (
+    Batch,
+    Update,
+    consolidate,
+    per_key_changes,
+    same_row,
+)
 
 
 class ErrorEntry(str):
@@ -957,6 +964,21 @@ class GroupByNode(Node):
                 dirty[gh] = g
         if frame_dirty:
             dirty.update(frame_dirty)
+        if not dirty:
+            return []
+        # A dirty group's change is its last emitted row against the row
+        # just extracted: nothing where consolidate would cancel the pair,
+        # else one retraction and one insertion.  A row that carries its
+        # group's values can only equal a row of its own group (groups are
+        # told apart by the same lookup that consolidate keys rows by), so
+        # each pair is decided alone: by same_row where the two rows tell,
+        # by consolidate of that pair where they do not.  Without the group
+        # values two groups may emit equal rows under one key (a caller's
+        # output_key_fn, or NaN group values, which the default key hashes
+        # alike) and only consolidate over the whole list cancels those.
+        t0_ns = tracing.now_ns()
+        pairwise = self.include_group_values
+        consolidated = 0
         out = []
         for gh, g in dirty.items():
             # output key is a pure function of the group values — hash it
@@ -964,9 +986,8 @@ class GroupByNode(Node):
             okey = g.get("okey")
             if okey is None:
                 okey = g["okey"] = self.output_key_fn(g["gvals"])
-            if g["last_out"] is not None:
-                out.append(Update(okey, g["last_out"], -1))
-                g["last_out"] = None
+            last = g["last_out"]
+            row = None
             if g["count"] > 0:
                 errs = g.get("errs") or {}
                 reduced = tuple(
@@ -975,12 +996,44 @@ class GroupByNode(Node):
                         zip(self.reducer_args, g["accs"])
                     )
                 )
-                row = (tuple(g["gvals"]) + reduced) if self.include_group_values else reduced
-                out.append(Update(okey, row, 1))
-                g["last_out"] = row
+                row = reduced
+                if self.include_group_values:
+                    row = tuple(g["gvals"]) + reduced
             elif g["count"] == 0:
                 del st["groups"][gh]
-        return consolidate(out)
+            g["last_out"] = row
+            if last is None or row is None:
+                if row is not None:
+                    out.append(Update(okey, row, 1))
+                elif last is not None:
+                    out.append(Update(okey, last, -1))
+                continue
+            same = same_row(last, row) if pairwise else None
+            if same:
+                continue
+            pair = [Update(okey, last, -1), Update(okey, row, 1)]
+            if same is None:
+                consolidated += 1
+                if pairwise:
+                    pair = consolidate(pair)
+            out += pair
+        if not pairwise:
+            out = consolidate(out)
+        tracing.record_span(
+            "groupby_emit",
+            t0_ns,
+            tracing.now_ns(),
+            args={
+                "node": f"{self.name}#{self.id}",
+                "groups": len(dirty),
+                "consolidated": consolidated,
+            },
+        )
+        device_counters.bump(
+            groupby_groups_emitted=len(dirty),
+            groupby_groups_consolidated=consolidated,
+        )
+        return out
 
 
 class DeduplicateNode(Node):

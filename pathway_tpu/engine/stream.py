@@ -53,6 +53,60 @@ def hashable_row(values: tuple) -> tuple:
     return tuple(hashable(v) for v in values)
 
 
+def _same_cell(a: Any, b: Any) -> bool | None:
+    """:func:`same_row` for one pair of cells of rows that hold an
+    unhashable cell somewhere, by the kinds :func:`hashable` tells apart."""
+    if a is b:
+        return True
+    if isinstance(a, np.ndarray) or isinstance(b, np.ndarray):
+        return None
+    for kind in (dict, list, tuple):
+        if isinstance(a, kind):
+            if not isinstance(b, kind):
+                return None
+            if kind is dict:
+                # a dict's tagged form is its JSON text: {"a": 1} is not
+                # {"a": 1.0}, and {1: "x"} is {"1": "x"}
+                return hashable(a) == hashable(b)
+            if len(a) != len(b):
+                return False
+            for x, y in zip(a, b):
+                if x is not y:
+                    same = _same_cell(x, y)
+                    if not same:
+                        return same
+            return True
+        if isinstance(b, kind):
+            return None
+    # the tagged form leaves both cells as they are, and the dict lookup
+    # wants equal hashes before it asks ``==`` (pw.Json hashes its text)
+    return hash(a) == hash(b) and bool(a == b)
+
+
+def same_row(old: tuple, new: tuple) -> bool | None:
+    """Whether :func:`consolidate` would cancel ``Update(k, old, -1)``
+    against ``Update(k, new, 1)``, decided from the two rows alone: True,
+    False, or None where only ``consolidate`` can say (an ndarray cell, a
+    container beside a scalar, a cell that will not hash or compare).
+
+    ``consolidate`` keys a row by itself where it hashes and by
+    :func:`hashable_row` where it does not, and a dict lookup calls two keys
+    equal when their hashes are and they are the same object or ``==``.  So
+    cells that are one object are equal, a sequence is unequal to one of
+    another length, and neither needs the walk over every cell of both rows
+    that a tagged form costs: a ``reducers.tuple`` of a table's dicts that
+    gained a row is told from its predecessor at its length."""
+    if old is new:
+        return True
+    try:
+        try:
+            return hash(old) == hash(new) and bool(old == new)
+        except TypeError:
+            return _same_cell(old, new)
+    except Exception:  # noqa: BLE001 - consolidate meets it too and reports it
+        return None
+
+
 def _py_consolidate(batch: Iterable[Update]) -> Batch:
     acc: dict[tuple, list] = {}
     for u in batch:

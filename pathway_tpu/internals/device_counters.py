@@ -31,7 +31,11 @@ counters are monotonic:
   ``moe_rows_routed`` (token-expert pairs the experts held here computed /
   pairs the router chose anywhere) and ``dsa_keys_selected`` /
   ``dsa_keys_scored`` (keys the queries attended to / keys their indexer
-  scored), the last four counted on the device by the programs themselves.
+  scored), the last four counted on the device by the programs themselves;
+  and once a ``GroupByNode.process`` call that had dirty groups:
+  ``groupby_groups_emitted`` (groups whose change it emitted) and
+  ``groupby_groups_consolidated`` (those of them whose two rows could not
+  tell it whether they differ, so ``consolidate`` hashed the pair).
 
 :func:`snapshot` is the one door through which the benchmark reads the
 program: the counters above and the span recorder's stage totals
@@ -100,6 +104,8 @@ _counters: dict[str, int] = {
     "moe_rows_routed": 0,
     "dsa_keys_selected": 0,
     "dsa_keys_scored": 0,
+    "groupby_groups_emitted": 0,
+    "groupby_groups_consolidated": 0,
 }
 
 
