@@ -1,7 +1,6 @@
 """Streaming wordcount over a jsonlines directory.
 
-The canonical demo graph (bench.py's wordcount, as a standalone
-program).  Lintable without running: ``python -m pathway_tpu.cli lint
+The canonical demo graph, as a standalone program.  Lintable without running: ``python -m pathway_tpu.cli lint
 examples/wordcount.py``.  The analyzer's accepted warnings for it live
 in ``scripts/lint_baseline.json``: a file source feeding a groupby is a
 full exchange (PW-X002) and unwindowed state (PW-S001) — both are the

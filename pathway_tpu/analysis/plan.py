@@ -47,8 +47,8 @@ class ExecutionPlan:
     """The optimizer's output: rewritten-graph summary + step log.
 
     ``counters()`` (rewrite count per pass) feeds ``/status`` →
-    ``plan``, the ``pathway_tpu_plan_rewrites`` gauge on ``/metrics``,
-    and the bench artifact.  ``format()`` is the golden-tested text.
+    ``plan`` and the ``pathway_tpu_plan_rewrites`` gauge on ``/metrics``.
+    ``format()`` is the golden-tested text.
     """
 
     def __init__(self, level: int):

@@ -31,8 +31,7 @@ latency.  Categories bucket the stage names recorded across the repo:
 chain: walking from the root, at each level pick the child contributing
 the most wall time, yielding the "admission → scheduler → dispatch →
 collect" style path reports quote.  :func:`report` rolls per-trace
-breakdowns into p50/p99 attribution; ``bench.py`` embeds its output in
-``BENCH_trace.json``.
+breakdowns into p50/p99 attribution.
 """
 
 from __future__ import annotations

@@ -27,8 +27,8 @@ from pathway_tpu.internals import native as _native
 
 def columnar_enabled() -> bool:
     """Global gate: ``PATHWAY_DISABLE_COLUMNAR=1`` forces every operator
-    onto the row path (the bench harness uses it for the columnar-vs-row
-    smoke gate; also the escape hatch if a frame kernel misbehaves)."""
+    onto the row path (the tests' row-path reference; also the escape
+    hatch if a frame kernel misbehaves)."""
     return os.environ.get("PATHWAY_DISABLE_COLUMNAR", "") != "1" and (
         _native.load() is not None
     )

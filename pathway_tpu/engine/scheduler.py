@@ -1447,7 +1447,7 @@ class Scheduler:
             for c in ctxs[1:]:
                 ctxs[0].error_log.extend(c.error_log)
         # exchange-overhead probe: pack/send/unpack/wait totals for this
-        # process's collectives, surfaced through monitoring and bench
+        # process's collectives, surfaced through monitoring and ctx.stats
         ctxs[0].stats["exchange"] = cluster.exchange_stats()
         return ctxs[0]
 

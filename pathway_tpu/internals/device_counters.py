@@ -9,8 +9,8 @@ counters are monotonic:
 - ``jit_compiles`` — one per actual XLA backend compile, observed via
   ``jax.monitoring``'s ``/jax/core/compile/backend_compile_duration``
   event (cache hits emit nothing, so a warmed, shape-stable serving loop
-  holds this flat — the zero-recompile steady-state invariant the bench
-  ``--smoke`` gate enforces).
+  holds this flat — the zero-recompile steady-state invariant
+  ``tests/test_device_runtime.py`` holds).
 - ``h2d_bytes`` / ``d2h_bytes`` — recorded at the repo's own transfer
   call sites (``parallel/sharded_knn.py`` dispatch/collect,
   ``parallel/executor.py`` chunk uploads/readbacks, ``parallel/
@@ -166,7 +166,7 @@ def record_d2h(nbytes: int) -> None:
 
 def compile_count() -> int:
     """Current jit-compile total (installs the listener on first use so
-    bench warmup loops can bracket themselves)."""
+    warm-up loops can bracket themselves)."""
     install()
     return _counters["jit_compiles"]
 

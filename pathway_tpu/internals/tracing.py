@@ -50,9 +50,9 @@ a post-mortem dump), so head sampling governs *export*, not recording:
   exports even when head sampling skipped it.  Slow requests are the
   ones worth attributing; the knob guarantees they survive sampling.
 
-Other knobs: ``PATHWAY_TRACE=0`` disables recording entirely (the
-bench overhead gate A/Bs this), ``PATHWAY_TRACE_RING`` sizes the
-per-thread ring (default 4096 spans), and ``PATHWAY_TRACE_DIR`` names
+Other knobs: ``PATHWAY_TRACE=0`` disables recording entirely,
+``PATHWAY_TRACE_RING`` sizes the per-thread ring (default 4096
+spans), and ``PATHWAY_TRACE_DIR`` names
 the flight-recorder spool: when set, :func:`flush` writes
 ``trace-r{rank}-*.json`` Chrome-trace files there (and an atexit hook
 flushes on clean process exit).  Dump triggers wired elsewhere:
@@ -252,7 +252,7 @@ class TraceContext:
 
 def configure(**env: Any) -> None:
     """Apply env-style knobs programmatically and reload the config
-    (tests and bench use this instead of mutating os.environ ad hoc)."""
+    (tests use this instead of mutating os.environ ad hoc)."""
     for key, value in env.items():
         if value is None:
             os.environ.pop(key, None)

@@ -5,7 +5,7 @@ so queueing delay is *measured* instead of hidden), one pacing thread
 per tenant, exponential inter-arrivals from a per-tenant
 ``numpy.random.default_rng(seed + index)`` — bit-identical schedules
 run-to-run.  Mixed read/write: each arrival is a query or (with
-``write_fraction``) an upsert into the live index, so the bench load is
+``write_fraction``) an upsert into the live index, so the offered load is
 the paper's concurrent query+churn regime, not a read-only cache test.
 
 Reports per tenant and per SLO class: offered vs achieved qps, shed

@@ -48,8 +48,8 @@ misdiagnose):
 - :meth:`chaos.slow_peer` — every outbound transmission from one rank is
   slowed (seeded jitter): a degraded-but-alive peer that drags epochs
   without ever missing a liveness deadline.
-Overload primitives (drive ``tests/test_overload.py`` and
-``bench.py bench_overload`` — sustained pressure rather than failure):
+Overload primitives (drive ``tests/test_overload.py`` — sustained
+pressure rather than failure):
 
 - :meth:`chaos.firehose_source` — a seedable synthetic source pushing
   rows at a target rate (or flat-out); when the ingest credit buffer

@@ -4,9 +4,8 @@ run; a dead peer must be *detected* within the liveness timeout instead
 of hanging a ``recv`` forever; and link teardown must complete in
 bounded time even with peers mid-conversation.
 
-The drills go through ``testing.chaos.ClusterDrill`` — the same harness
-``bench.py`` uses for the committed recovery numbers — so the test and
-the benchmark can never drift apart on what "recovered" means.
+The drills go through ``testing.chaos.ClusterDrill``, the one place that
+says what "recovered" means.
 """
 
 from __future__ import annotations

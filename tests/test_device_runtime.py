@@ -72,8 +72,12 @@ def test_warmed_ivf_serving_loop_records_zero_compiles():
         idx.train()
 
     sizes = list(range(1, 10))
+    base = devctr.compile_count()
     for nq in sizes:  # warmup: compiles land here, bounded by buckets
         idx.search(rng.standard_normal((nq, dim)).astype(np.float32), k=3)
+    # a program a bucket, not a program a size (the unbucketed control is
+    # test_shape_unstable_jit_records_a_compile_per_shape)
+    assert devctr.compile_count() - base < len(sizes)
 
     base = devctr.compile_count()
     for nq in sizes:

@@ -11,7 +11,10 @@ from __future__ import annotations
 
 import doctest
 import importlib
+import os
 import pkgutil
+import re
+import subprocess
 
 import pytest
 
@@ -80,3 +83,42 @@ def test_docstring_example(dt_case):
     )
     result = runner.run(dt_case)
     assert result.failed == 0, f"{dt_case.name}: {result.failed} failed"
+
+
+# ---------------------------------------------------------------------------
+# the README names files that exist
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _repo_files() -> list[str]:
+    """The tracked files; where the checkout is no git repository, the
+    files on disk outside dot-directories and build output."""
+    try:
+        out = subprocess.run(
+            ["git", "-C", REPO, "ls-files"], capture_output=True, text=True, timeout=30
+        )
+        if out.returncode == 0 and out.stdout.strip():
+            return out.stdout.split("\n")
+    except (OSError, subprocess.TimeoutExpired):
+        pass
+    files = []
+    for root, dirs, names in os.walk(REPO):
+        dirs[:] = [d for d in dirs if not d.startswith(".") and d not in ("__pycache__", "build", "chiprun_out", "chip_work")]
+        files += [os.path.relpath(os.path.join(root, n), REPO) for n in names]
+    return files
+
+
+def test_readme_names_only_files_that_exist():
+    """Every file name the README puts in backticks is, at a ``/``
+    boundary, the tail of a file's path in the repo: a document that
+    cites a deleted artifact or a renamed module fails here, not in a
+    reader's hands."""
+    with open(os.path.join(REPO, "README.md")) as f:
+        named = set(re.findall(r"`([^`\s]+\.(?:py|md|json|jsonl|sh|cpp))`", f.read()))
+    assert len(named) >= 40, sorted(named)  # the pattern still finds them
+    files = _repo_files()
+    missing = sorted(
+        n for n in named if not any(("/" + f).endswith("/" + n) for f in files)
+    )
+    assert not missing, missing

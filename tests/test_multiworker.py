@@ -450,23 +450,20 @@ _STATS_PROGRAM = textwrap.dedent(
 )
 
 
-def test_two_process_exchange_stats(tmp_path):
-    """The pipelined transport reports its overhead probe: framed
-    transmissions flowed, the status consensus rode them every round, and
-    allgather stayed a run-boundary constant."""
-    words = ["apple", "pear", "apple", "plum", "apple", "pear"] * 20
+def _run_stats_cluster(tmp_path, words, *, threads, **env_extra) -> tuple[list, dict]:
+    """Two processes over TCP on ``_STATS_PROGRAM``; each process's
+    exchange stats and the final counts."""
     input_file = tmp_path / "w.jsonl"
     input_file.write_text("\n".join(json.dumps({"word": w}) for w in words))
     output_file = tmp_path / "out.jsonl"
-
     prog = tmp_path / "prog.py"
     prog.write_text(
         _STATS_PROGRAM.format(
             repo=REPO, input=str(input_file), output=str(output_file)
         )
     )
-    env = dict(os.environ)
-    env["PATHWAY_THREADS"] = "2"
+    env = dict(os.environ, **env_extra)
+    env["PATHWAY_THREADS"] = str(threads)
     env["PATHWAY_PROCESSES"] = "2"
     env["PATHWAY_FIRST_PORT"] = str(next_port(3))
     procs = []
@@ -489,7 +486,16 @@ def test_two_process_exchange_stats(tmp_path):
             l for l in out.decode().splitlines() if l.startswith("EXCHANGE_STATS=")
         )
         all_stats.append(json.loads(line[len("EXCHANGE_STATS="):]))
-    assert _final_counts(output_file) == {"apple": 60, "pear": 40, "plum": 20}
+    return all_stats, _final_counts(output_file)
+
+
+def test_two_process_exchange_stats(tmp_path):
+    """The pipelined transport reports its overhead probe: framed
+    transmissions flowed, the status consensus rode them every round, and
+    allgather stayed a run-boundary constant."""
+    words = ["apple", "pear", "apple", "plum", "apple", "pear"] * 20
+    all_stats, counts = _run_stats_cluster(tmp_path, words, threads=2)
+    assert counts == {"apple": 60, "pear": 40, "plum": 20}
 
     for stats in all_stats:
         # data moved over the framed transport and was accounted for
@@ -504,3 +510,42 @@ def test_two_process_exchange_stats(tmp_path):
         for key in ("pack_ms", "send_ms", "unpack_ms", "recv_wait_ms",
                     "status_wait_ms"):
             assert stats[key] >= 0.0
+
+
+@pytest.fixture(scope="module")
+def wire_runs(tmp_path_factory):
+    """The same two-process wordcount over the columnar wire (``_K_FRAME``,
+    the default) and over the row wire (``PATHWAY_DISABLE_COLUMNAR=1``,
+    ``_K_UPDATES``): the exchange stats summed over both processes."""
+    from pathway_tpu.internals import native
+
+    if native.load() is None:
+        pytest.skip("native extension unavailable (no g++?): no frames to ship")
+    words = [f"a-rather-long-word-{i % 40:02d}" for i in range(20_000)]
+    expected = {f"a-rather-long-word-{i:02d}": 500 for i in range(40)}
+    runs = {}
+    for wire, env in (("frame", {}), ("row", {"PATHWAY_DISABLE_COLUMNAR": "1"})):
+        all_stats, counts = _run_stats_cluster(
+            tmp_path_factory.mktemp(wire), words, threads=1, **env
+        )
+        assert counts == expected, wire
+        runs[wire] = {
+            key: sum(stats[key] for stats in all_stats)
+            for key in ("strpool_hits", "strpool_misses", "bytes_sent", "transmissions")
+        }
+    return runs
+
+
+@pytest.mark.parametrize("wire", ["frame", "row"])
+def test_columnar_wire_engages_and_ships_fewer_bytes(wire_runs, wire):
+    """``_K_FRAME`` really carries a cluster's updates (a silent fall-back
+    to ``_K_UPDATES`` would pass every equivalence test): its string pool
+    sees traffic and the same updates cost fewer bytes than on the row
+    wire, where the pool sees none.  Counts from the exchange stats."""
+    pool = wire_runs[wire]["strpool_hits"] + wire_runs[wire]["strpool_misses"]
+    assert wire_runs[wire]["transmissions"] > 0
+    if wire == "frame":
+        assert pool > 0, wire_runs
+        assert wire_runs["frame"]["bytes_sent"] < wire_runs["row"]["bytes_sent"], wire_runs
+    else:
+        assert pool == 0, wire_runs

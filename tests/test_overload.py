@@ -19,10 +19,12 @@ import time
 import pytest
 
 import pathway_tpu as pw
+from pathway_tpu.analysis import tracecrit
 from pathway_tpu.engine.scheduler import (
     IngestCredit,
     IngestOverflow,
 )
+from pathway_tpu.internals import native, tracing
 from pathway_tpu.testing.chaos import chaos
 
 # ---------------------------------------------------------------------------
@@ -310,15 +312,21 @@ def test_exchange_credit_throttles_slow_but_alive_peer(monkeypatch):
     being isolated; consuming drains the window and the producer
     finishes."""
     monkeypatch.setenv("PATHWAY_EXCHANGE_CREDIT_BYTES", "8192")
+    # loaded before the link threads race for it: a thread that asks
+    # while another is mid-load is told there is no native module
+    # (ROADMAP, Design: `internals/native.py` `load`)
+    native.load()
     links0, links1 = _link_pair(_next_port(2))
     n_frames = 6
     try:
         sent = []
+        t_mark = time.monotonic_ns()
 
         def producer() -> None:
-            for i in range(n_frames):
-                links0.send_updates_async(1, ("s", i), _boxes(60))
-                sent.append(i)
+            with tracing.use(tracing.new_trace(sampled=True)):
+                for i in range(n_frames):
+                    links0.send_updates_async(1, ("s", i), _boxes(60))
+                    sent.append(i)
 
         t = threading.Thread(target=producer, daemon=True)
         t.start()
@@ -348,6 +356,15 @@ def test_exchange_credit_throttles_slow_but_alive_peer(monkeypatch):
         assert links0.pressure_level() >= 0.0
         with links0.stats_lock:
             assert links0.stats["credit_stall_ms"] > 0
+        # the stall is on the producer's trace, where the attribution
+        # files it under the exchange
+        waits = [
+            e
+            for e in tracing.chrome_events(since_ns=t_mark, all_spans=True)
+            if e["name"] == "credit_wait"
+        ]
+        assert waits and all(e["args"]["dst"] == 1 for e in waits)
+        assert tracecrit.categorize("credit_wait") == "exchange"
     finally:
         links0.close()
         links1.close()

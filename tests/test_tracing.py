@@ -313,6 +313,37 @@ def test_report_over_real_recorded_spans():
     assert conn[ctx.trace_id] is True
 
 
+def test_served_requests_leave_connected_traces_of_host_stages():
+    """What a request through the serving co-scheduler records: its
+    stages under one trace with ``serve_e2e`` at the root, which the
+    attribution files under ``host_compute`` (embed, search, generate, on
+    the host's clock) and ``queue_wait``; no category is called
+    ``device``."""
+    from pathway_tpu.serving import HashingEmbedder, StageCoScheduler
+    from pathway_tpu.stdlib.indexing.hnsw import HnswIndex
+    from pathway_tpu.stdlib.indexing.segments import SegmentedIndex
+
+    emb = HashingEmbedder(dim=32)
+    seg = SegmentedIndex(HnswIndex(32, metric="cos"), delta_cap=64, auto_merge=False)
+    seg.add([(f"doc{i}", emb(f"slab bucket probe lane {i}")) for i in range(12)])
+    co = StageCoScheduler(embedder=emb, index=seg, k=4, lookahead=True)
+    try:
+        for i in range(3):
+            co.submit(f"bucket probe {i}").result(timeout=10)
+    finally:
+        co.close()
+        seg.close()
+    events = _events()
+    stages = {e["name"] for e in events}
+    assert {"serve_embed", "generate", "serve_e2e"} <= stages, stages
+    rep = tracecrit.report(events)
+    assert rep["requests"] == 3
+    assert all(tracecrit.connected_traces(events).values())
+    assert rep["slowest"]["critical_path"][0]["stage"] == "serve_e2e"
+    categories = set(rep["mean_by_category_ms"])
+    assert "host_compute" in categories and "device" not in categories
+
+
 @pytest.mark.parametrize(
     "stage,category",
     [
