@@ -209,7 +209,23 @@ class SegmentedIndex:
 
         A batch at least ``delta_cap`` large with nothing buffered is a
         bulk load and goes straight into the sealed main segment — the
-        initial corpus shouldn't crawl through the delta."""
+        initial corpus shouldn't crawl through the delta.
+
+        A bulk load costs the batch, not the corpus: with nothing in the
+        delta, no tombstone and no merge in flight the membership set is
+        exactly main's keys, so it takes the batch's keys and no more.
+        It resynchronises — one ``set()`` over every key of main (80 ms
+        beside a million keys), under the ``index_keyset_rebuild`` span —
+        only when the two sizes say they are out of step: main was loaded
+        directly (``main.add_batch`` behind the segment layer), or its
+        ``len()`` does not count what its ``keys`` lists.
+
+        Equal sizes are taken as in step.  So main must not be changed
+        behind the segment layer except by loads that change its length:
+        a direct remove-and-add that keeps ``len(main)``, or a direct load
+        that brings it up to a stale ``len(_keys)``, would go unseen until
+        a later bulk load finds the sizes apart or ``load_state_dict``
+        rebuilds the set."""
         if not items:
             return
         with self._lock:
@@ -219,10 +235,14 @@ class SegmentedIndex:
                 and not self._tombs
                 and not self._merging
             ):
+                in_step = len(self._keys) == len(self.main)
                 with self._main_mutex:
                     self.main.add(list(items))
-                with _tracing.span("index_keyset_rebuild"):
-                    self._keys = set(self._main_keys())
+                if in_step:
+                    self._keys.update(key for key, _ in items)
+                if not in_step or len(self._keys) != len(self.main):
+                    with _tracing.span("index_keyset_rebuild"):
+                        self._keys = set(self._main_keys())
                 return
             for key, vec in items:
                 self._tombs.discard(key)

@@ -15,9 +15,11 @@ from __future__ import annotations
 
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pathway_tpu.internals import tracing
 from pathway_tpu.parallel import IvfKnnIndex, ShardedKnnIndex
 from pathway_tpu.stdlib.indexing.hnsw import HnswIndex
 from pathway_tpu.stdlib.indexing.segments import SegmentedIndex
@@ -158,6 +160,167 @@ def test_segmented_bulk_load_goes_straight_to_main():
     assert len(seg.main) == 32
     assert not seg._delta, "bulk load must not crawl through the delta"
     assert len(seg) == 32
+
+
+# ---------------------------------------------------------------------------
+# the membership set is kept by the batch (ISSUE 33): a bulk load adds its
+# own keys and walks main's only when the two sizes are out of step
+
+
+def _rebuilds():
+    return tracing.stage_totals().get("index_keyset_rebuild", (0, 0))[0]
+
+
+def _assert_membership(seg, expect):
+    """Every public reading of the membership against ``expect`` (what the
+    calls so far should have left) and against a recount from scratch."""
+    recount = (set(seg._main_keys()) | set(seg._delta) | set(seg._frozen)) - seg._tombs - seg._frozen_tombs
+    assert recount == expect
+    assert set(seg.keys()) == expect
+    assert len(seg) == len(seg.keys()) == seg.stats()["size"] == len(expect)
+    assert all(key in seg for key in expect) and "absent" not in seg
+
+
+def _items(rng, keys):
+    return list(zip(keys, _unit(rng, len(keys))))
+
+
+def _load_directly(kind, main, keys, vecs):
+    """Rows put into ``main`` behind the segment layer, as the benchmark's
+    ``System.fill`` loads its filler (``add_batch_device`` on the slab)."""
+    if kind == "sharded":
+        main.add_batch_device(keys, jnp.asarray(vecs))
+    elif kind == "ivf":
+        main.add_batch(keys, vecs)
+    else:
+        main.add(list(zip(keys, vecs)))
+
+
+@pytest.mark.parametrize("kind", ["hnsw", "sharded", "ivf"])
+def test_segmented_membership_after_public_calls_matches_a_recount(kind):
+    """bulk load, bulk load, delta add, remove, merge, bulk load: after each
+    call the membership reads what a recount gives, and no bulk load onto an
+    index in step walks main's keys."""
+    rng = np.random.default_rng(33)
+    seg = SegmentedIndex(_factory(kind), delta_cap=16, auto_merge=False)
+    try:
+        before = _rebuilds()
+        expect = {f"a{i}" for i in range(16)}
+        seg.add(_items(rng, sorted(expect)))
+        _assert_membership(seg, expect)
+        if kind == "ivf":
+            # untrained it buffers rows and its len() counts a key buffered
+            # twice twice: that main is the miscounting case further down
+            seg.main.train()
+        more = [f"b{i}" for i in range(24)]
+        seg.add(_items(rng, more))
+        expect |= set(more)
+        _assert_membership(seg, expect)
+        assert not seg._delta and len(seg.main) == 40
+        seg.add(_items(rng, ["d0", "d1", "a3"]))  # under delta_cap: the delta; a3 shadows main's
+        expect |= {"d0", "d1"}
+        _assert_membership(seg, expect)
+        seg.remove(["a0", "d1", "b7", "absent"])  # main, delta-only, main, nowhere
+        expect -= {"a0", "d1", "b7"}
+        _assert_membership(seg, expect)
+        seg.add(_items(rng, [f"c{i}" for i in range(16)]))  # bulk-sized, but tombstones wait: the delta
+        expect |= {f"c{i}" for i in range(16)}
+        _assert_membership(seg, expect)
+        assert len(seg._delta) == 18
+        seg.merge()
+        _assert_membership(seg, expect)
+        assert not seg._delta and not seg._tombs and len(seg.main) == len(expect)
+        last = [f"e{i}" for i in range(20)] + ["a3", "c2"]  # new keys and two upserts of main's
+        seg.add(_items(rng, last))
+        expect |= set(last)
+        _assert_membership(seg, expect)
+        assert not seg._delta and len(seg.main) == len(expect)
+        assert _rebuilds() == before, "an index in step was resynchronised"
+    finally:
+        seg.close()
+
+
+@pytest.mark.parametrize("kind", ["hnsw", "sharded", "ivf"])
+def test_segmented_direct_load_then_bulk_resynchronises_once(kind, monkeypatch):
+    """The harness's sequence: N rows into ``main`` behind the segment
+    layer, then two bulk loads.  The first finds the sizes out of step and
+    walks main's keys once; the second, and a bulk load onto any index in
+    step, never reads ``main.keys``."""
+    rng = np.random.default_rng(34)
+    main = _factory(kind)
+    seg = SegmentedIndex(main, delta_cap=16, auto_merge=False)
+    walks = []
+    keys_attr = type(main).__dict__["keys"]
+    if isinstance(keys_attr, property):  # the slab's
+        spy = property(lambda self: walks.append(1) or keys_attr.fget(self))
+    else:
+        spy = lambda self: walks.append(1) or keys_attr(self)  # noqa: E731
+    monkeypatch.setattr(type(main), "keys", spy)
+    try:
+        direct = [-(i + 1) for i in range(48)]
+        _load_directly(kind, main, direct, _unit(rng, 48))
+        assert len(seg) == 0 and len(main) == 48, "nothing told the segment layer yet"
+        before = _rebuilds()
+        first = [-(i + 1) for i in range(48, 64)]
+        seg.add(_items(rng, first))
+        assert (len(walks), _rebuilds() - before) == (1, 1)
+        second = [-(i + 1) for i in range(64, 96)]
+        seg.add(_items(rng, second))
+        seg.add(_items(rng, [f"live{i}" for i in range(16)]))
+        assert (len(walks), _rebuilds() - before) == (1, 1), "a bulk load onto an index in step walked main's keys"
+        monkeypatch.undo()
+        _assert_membership(seg, set(direct + first + second) | {f"live{i}" for i in range(16)})
+        assert seg.stats()["main_size"] == 112 and not seg._delta
+    finally:
+        seg.close()
+
+
+@pytest.mark.parametrize("kind", ["hnsw", "sharded", "ivf"])
+def test_segmented_bulk_upserts_and_duplicates_keep_the_length_right(kind):
+    """A bulk load whose keys main already holds, and one that names a key
+    twice, add no member twice."""
+    rng = np.random.default_rng(35)
+    seg = SegmentedIndex(_factory(kind), delta_cap=16, auto_merge=False)
+    try:
+        held = [f"k{i}" for i in range(20)]
+        seg.add(_items(rng, held))
+        seg.add(_items(rng, held[:16]))  # every key an upsert
+        _assert_membership(seg, set(held))
+        seg.add(_items(rng, [f"n{i}" for i in range(15)] + ["n0", "k1"]))  # n0 twice, k1 again
+        _assert_membership(seg, set(held) | {f"n{i}" for i in range(15)})
+        (hit,) = seg.search(_unit(rng, 1), 40)
+        assert len({key for key, _ in hit}) == len(hit) == 35, "a key surfaced twice"
+        _assert_membership(seg, set(held) | {f"n{i}" for i in range(15)})
+    finally:
+        seg.close()
+
+
+@pytest.mark.parametrize("main", ["len_counts_one_more", "ivf_untrained"])
+def test_segmented_main_that_miscounts_falls_to_the_rebuild(main):
+    """A ``main`` whose ``len()`` does not count what its ``keys`` lists
+    cannot be shown in step, so its bulk loads take the safe side: an IVF
+    index before training buffers rows and counts a key buffered twice
+    twice."""
+
+    class Miscounting(HnswIndex):
+        def __len__(self):
+            return super().__len__() + 1
+
+    rng = np.random.default_rng(36)
+    seg = SegmentedIndex(
+        Miscounting(D, metric="cos") if main == "len_counts_one_more" else _factory("ivf"),
+        delta_cap=16,
+        auto_merge=False,
+    )
+    try:
+        before = _rebuilds()
+        seg.add(_items(rng, [f"a{i}" for i in range(16)]))
+        seg.add(_items(rng, [f"b{i}" for i in range(15)] + ["a0"]))  # a0 again
+        seg.add(_items(rng, [f"c{i}" for i in range(16)]))
+        assert _rebuilds() - before == (3 if main == "len_counts_one_more" else 2)
+        assert set(seg.keys()) == set(seg._main_keys()) and len(seg) == 47 and "b9" in seg and "a0" in seg
+    finally:
+        seg.close()
 
 
 def test_segmented_auto_merge_triggers():
