@@ -104,6 +104,12 @@ _counters: dict[str, int] = {
     "moe_rows_routed": 0,
     "dsa_keys_selected": 0,
     "dsa_keys_scored": 0,
+    "xdec_tokens_run": 0,
+    "xdec_tokens_seen": 0,
+    "swa_keys_in_window": 0,
+    "swa_keys_multiplied": 0,
+    "ssm_tokens_scanned": 0,
+    "ssm_tokens_padded": 0,
     "groupby_groups_emitted": 0,
     "groupby_groups_consolidated": 0,
 }

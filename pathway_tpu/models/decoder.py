@@ -44,10 +44,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["DecoderConfig", "DEEPSEEK_V32_EXP", "init_cache", "prefill", "decode_step", "STATS"]
+__all__ = ["DecoderConfig", "DEEPSEEK_V32_EXP", "init_cache", "prefill", "decode_step", "STATS", "DISPATCH_TOKENS"]
 
 #: what both programs count, in the order of the vector they return
 STATS = ("moe_rows_here", "moe_rows_routed", "dsa_keys_selected", "dsa_keys_scored")
+
+#: what one more prefill dispatch costs beside its tokens, in tokens: every
+#: weight is read again and the sequence's keys and values are expanded again
+#: (on a v5e at the published widths 25 ms, where 512 tokens of a chunk cost 33)
+DISPATCH_TOKENS = 512
 
 _NEG = -1e30
 
@@ -334,12 +339,14 @@ def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_b
     return _mm("td,dc->tc", out, lp["o"]), selected, visible
 
 
-def prefill(params, ids, cache, slot, start, length, *, config: DecoderConfig):
+def prefill(params, ids, cache, slot, start, length, last=True, *, config: DecoderConfig):
     """One bucket of a prompt: ``ids`` [C] (``length`` of them real, the rest
     padding) are the tokens ``start .. start + C`` of the sequence in
     ``slot``.  Returns float32 logits over the held vocabulary at the last
     real token, the cache with the chunk's rows written, and the counts of
-    :data:`STATS`.  ``start + C`` may not pass the cache's positions."""
+    :data:`STATS`.  ``start + C`` may not pass the cache's positions.
+    ``last`` (whether the prompt ends in this chunk) is the executor's to
+    say and changes nothing here: every layer runs for every token."""
     cfg = config
     C = ids.shape[0]
     pos = start + jnp.arange(C, dtype=jnp.int32)

@@ -2,9 +2,14 @@
 the programs that fill and read it.
 
 :class:`JittedDecoder` stands beside :class:`JittedEncoder` and keeps the
-same discipline.  State is pre-sized and never grows: for every layer two
-caches side by side, the latent rows and the indexer's keys
-(:func:`pathway_tpu.models.decoder.init_cache`), ``slots`` sequences of
+same discipline.  What a request's state is, and what the two programs do
+with it, is the architecture's: the module that defines the configuration's
+class (:mod:`pathway_tpu.models.decoder`: for every layer latent rows and
+indexer keys by position; :mod:`pathway_tpu.models.hybrid_decoder`:
+recurrent states, rings, one layer's keys and values, each of its own shape
+and lifetime) gives ``init_cache``, ``prefill``, ``decode_step``, the
+``STATS`` both count and ``DISPATCH_TOKENS``.  The executor owns the rest:
+the state is pre-sized and never grows, ``slots`` sequences of
 ``positions`` tokens, updated in place through donation as the index slab
 is.  Shapes come from a small fixed set: a prompt is cut into chunks of the
 ``chunk_buckets`` (:meth:`plan`: the cheapest cover), each one execution of
@@ -27,6 +32,7 @@ holds them.
 
 from __future__ import annotations
 
+import sys
 import threading
 from typing import Any, Sequence
 
@@ -36,15 +42,9 @@ import numpy as np
 
 from pathway_tpu.internals import device_counters as _devctr
 from pathway_tpu.internals import tracing as _tracing
-from pathway_tpu.models import decoder as _decoder
 from pathway_tpu.parallel.mesh import require_single_process
 
 __all__ = ["JittedDecoder"]
-
-#: what one more prefill dispatch costs beside its tokens, in tokens: every
-#: weight is read again and the sequence's keys and values are expanded again
-#: (on a v5e at the published widths 25 ms, where 512 tokens of a chunk cost 33)
-_DISPATCH_TOKENS = 512
 
 
 class JittedDecoder:
@@ -56,7 +56,7 @@ class JittedDecoder:
 
     def __init__(
         self,
-        config: _decoder.DecoderConfig,
+        config: Any,
         *,
         params: Any,
         slots: int = 8,
@@ -72,20 +72,21 @@ class JittedDecoder:
                 f"positions {positions} and be a multiple of the key block {config.key_block}"
             )
         self.config = config
+        self.architecture = arch = sys.modules[type(config).__module__]  # the module whose configuration this is
         self.params = params
         self.slots = slots
         self.positions = positions
         self.chunk_buckets = buckets
-        self.cache = _decoder.init_cache(config, slots, positions)
+        self.cache = arch.init_cache(config, slots, positions)
         self._lock = threading.Lock()  # the caches are one donated state
         self._turn = 0
 
-        def _prefill_chunk(params, ids, cache, slot, start, length):
-            logits, cache, stats = _decoder.prefill(params, ids, cache, slot, start, length, config=config)
+        def _prefill_chunk(params, ids, cache, slot, start, length, last):
+            logits, cache, stats = arch.prefill(params, ids, cache, slot, start, length, last, config=config)
             return jnp.argmax(logits).astype(jnp.int32)[None], logits, cache, stats
 
         def _decode_token(params, token, cache, slot, length):
-            logits, cache, stats = _decoder.decode_step(params, token, cache, slot, length, config=config)
+            logits, cache, stats = arch.decode_step(params, token, cache, slot, length, config=config)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits[0], cache, stats
 
         self._prefill = jax.jit(_prefill_chunk, donate_argnums=(2,))
@@ -95,15 +96,17 @@ class JittedDecoder:
     def plan(self, tokens: int) -> list[tuple[int, int, int]]:
         """A prompt of ``tokens`` tokens as ``(start, real tokens, bucket)``
         chunks: the cover by buckets that costs least, where a dispatch costs
-        its bucket's tokens and :data:`_DISPATCH_TOKENS` more (only the last
-        chunk may hold padding, and none may pass the cache's end)."""
+        its bucket's tokens and the architecture's ``DISPATCH_TOKENS`` more
+        (only the last chunk may hold padding, and none may pass the cache's
+        end)."""
         unit = self.chunk_buckets[0]
+        dispatch = self.architecture.DISPATCH_TOKENS / unit
         need, room = -(-tokens // unit), self.positions // unit
         sizes = [b // unit for b in self.chunk_buckets]
         best: list = [(0, ())]  # best[n]: (cost, buckets) for the prompt's last n units
         for n in range(1, need + 1):
             best.append(min(
-                (_DISPATCH_TOKENS // unit + b + best[max(n - b, 0)][0], (-b, *best[max(n - b, 0)][1]))
+                (dispatch + b + best[max(n - b, 0)][0], (-b, *best[max(n - b, 0)][1]))
                 for b in sizes if need - n + b <= room
             ))
         chunks, start = [], 0
@@ -139,7 +142,7 @@ class JittedDecoder:
                     ids[:real] = prompt[start : start + real]
                     _devctr.record_h2d(ids.nbytes)
                     token, logits, self.cache, st = self._prefill(
-                        self.params, ids, self.cache, np.int32(slot), np.int32(start), np.int32(real)
+                        self.params, ids, self.cache, np.int32(slot), np.int32(start), np.int32(real), np.bool_(start + real == prompt.size)
                     )
                     stats.append(st)
                 rows, tokens = [logits], [token]
@@ -164,7 +167,7 @@ class JittedDecoder:
             gen_prefill_dispatches=len(chunks),
             gen_new_tokens=max_new_tokens,
             gen_decode_steps=steps,
-            **dict(zip(_decoder.STATS, (int(c) for c in counted))),
+            **dict(zip(self.architecture.STATS, (int(c) for c in counted))),
         )
         return {"ids": np.concatenate(tokens).astype(np.int32), "logits": logits}
 

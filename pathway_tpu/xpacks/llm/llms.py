@@ -28,6 +28,7 @@ __all__ = [
     "HFPipelineChat",
     "CohereChat",
     "TPUDecoderChat",
+    "decoder_preset",
     "prompt_chat_single_qa",
 ]
 
@@ -150,7 +151,26 @@ class HFPipelineChat(BaseChat):
         return text[len(prompt) :] if text.startswith(prompt) else text
 
 
-_DECODER_PRESETS = {"deepseek-ai/deepseek-v3.2-exp": "DEEPSEEK_V32_EXP", "deepseek-v3.2-exp": "DEEPSEEK_V32_EXP"}
+#: preset name (lower case) -> the architecture's module under ``pathway_tpu.models`` and its configuration there
+_DECODER_PRESETS = {
+    "deepseek-ai/deepseek-v3.2-exp": ("decoder", "DEEPSEEK_V32_EXP"),
+    "deepseek-v3.2-exp": ("decoder", "DEEPSEEK_V32_EXP"),
+    "microsoft/phi-4-mini-flash-reasoning": ("hybrid_decoder", "PHI4_MINI_FLASH"),
+    "phi-4-mini-flash-reasoning": ("hybrid_decoder", "PHI4_MINI_FLASH"),
+}
+
+
+def decoder_preset(model: str) -> Any:
+    """The published configuration a decoder preset's name stands for (a
+    frozen dataclass of its architecture's module; ``dataclasses.replace``
+    gives a share of it or a small size)."""
+    import importlib
+
+    preset = _DECODER_PRESETS.get(model.lower())
+    if preset is None:
+        raise ValueError(f"unknown decoder model {model!r}: not one of the presets {sorted(_DECODER_PRESETS)}")
+    return getattr(importlib.import_module(f"pathway_tpu.models.{preset[0]}"), preset[1])
+
 
 #: generations :meth:`TPUDecoderChat.recent_generations` keeps
 _KEPT_GENERATIONS = 64
@@ -159,8 +179,9 @@ _KEPT_GENERATIONS = 64
 class TPUDecoderChat(BaseChat):
     """A causal decoder on the TPU; one greedy generation a call.
 
-    ``model`` names an architecture preset; ``config`` (a ``DecoderConfig``)
-    takes its place for a share of a layer or a small size.  ``params`` is
+    ``model`` names an architecture preset (:func:`decoder_preset`);
+    ``config`` (that architecture's configuration class) takes its place for
+    a share of a layer or a small size.  ``params`` is
     the decoder's parameter tree, handed in as ``TPUEncoderEmbedder`` takes
     one: no checkpoint of this family can be read here yet.  The prompt is
     the messages' contents, one token a word (the hashing tokenizer over the
@@ -181,17 +202,13 @@ class TPUDecoderChat(BaseChat):
         **kwargs: Any,
     ):
         super().__init__(model=model, **kwargs)
-        from pathway_tpu.models import decoder
         from pathway_tpu.models.tokenizer import HashTokenizer
         from pathway_tpu.parallel import JittedDecoder
 
         if config is None:
-            preset = _DECODER_PRESETS.get(model.lower())
-            if preset is None:
-                raise ValueError(f"unknown decoder model {model!r}: not one of the presets {sorted(_DECODER_PRESETS)}")
-            config = getattr(decoder, preset)
+            config = decoder_preset(model)
         if params is None:
-            raise ValueError("TPUDecoderChat needs params=: the decoder's parameter tree (models/decoder.py names its leaves)")
+            raise ValueError("TPUDecoderChat needs params=: the decoder's parameter tree (the architecture's module names its leaves)")
         self.max_new_tokens = max_new_tokens
         self.tokenizer = HashTokenizer(config.vocab_held)
         self.decoder = JittedDecoder(config, params=params, slots=slots, positions=positions, chunk_buckets=chunk_buckets)

@@ -2,7 +2,10 @@
 (``models/decoder.py``), its executor (``parallel/generation.py``) and
 ``TPUDecoderChat``, each held against the plain reference of the benchmark's
 ``deepseek_v32`` family (float32 ``jax.numpy``, no cache, no chunks, no
-absorbed form), on seeded weights."""
+absorbed form), on seeded weights.  The executor, the chat and the answer
+route are one code for both architectures and run here over both (``served``):
+the second, ``models/hybrid_decoder.py``, against the ``phi4flash`` family's
+reference (its own tests are ``test_hybrid_decoder.py``'s)."""
 
 from __future__ import annotations
 
@@ -20,9 +23,11 @@ import pytest
 
 import pathway_tpu as pw
 from benchmark.families import deepseek_v32 as family
+from benchmark.families import phi4flash as hybrid_family
 from pathway_tpu.internals import device_counters as devctr
 from pathway_tpu.models import MINILM_L6, decoder
 from pathway_tpu.parallel import JittedDecoder
+from tests import hybrid_toy
 from tests.utils import T
 
 ROPE_SCALING = {"beta_fast": 32, "beta_slow": 1, "factor": 4, "mscale": 1, "mscale_all_dim": 1, "original_max_position_embeddings": 16, "type": "yarn"}
@@ -208,9 +213,40 @@ def test_bfloat16_stays_near_the_reference(model):
 
 
 # ------------------------------------------------------------ the executor
+@pytest.fixture(scope="module", params=["deepseek_v32", "phi4flash"])
+def served(request, model):
+    """Each architecture the executor serves, at its toy size: the
+    configuration, seeded float32 parameters, the family whose reference they
+    are held against, and what a generation of ``prompt`` tokens in chunks of
+    ``padded`` and ``steps`` decode steps makes the architecture's own
+    counters read."""
+    if request.param == "deepseek_v32":
+        def counted(prompt, padded, steps):
+            tokens = prompt + steps
+            return {"moe_rows_routed": 2 * 4 * tokens, "moe_rows_here": 2 * 4 * tokens, "dsa_keys_scored": 3 * sum(range(1, tokens + 1))}
+
+        return {"cfg": model["cfg"], "params": model["params"], "family": family, "group": GROUP, "counted": counted, "silent": "xdec_tokens_seen"}
+
+    def counted(prompt, padded, steps):
+        tokens = prompt + steps
+        return {
+            "xdec_tokens_run": 1 + steps, "xdec_tokens_seen": tokens, "ssm_tokens_scanned": 3 * tokens, "ssm_tokens_padded": 3 * (padded + steps),
+            "swa_keys_in_window": 2 * sum(min(t + 1, 8) for t in range(tokens)), "swa_keys_multiplied": 2 * (padded * 16 + steps * 8),
+        }
+
+    return {
+        "cfg": hybrid_toy.config_of(hybrid_toy.GROUP), "params": hybrid_toy.float32_params(hybrid_toy.GROUP), "family": hybrid_family,
+        "group": hybrid_toy.GROUP, "counted": counted, "silent": "dsa_keys_scored",
+    }
+
+
 @pytest.fixture(scope="module")
-def executor(model):
-    return JittedDecoder(model["cfg"], params=model["params"], slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
+def executor(served):
+    return JittedDecoder(served["cfg"], params=served["params"], slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
+
+
+def _reference(served, ids, positions):
+    return served["family"].reference_logits(served["params"], served["group"], [ids], [positions], q_block=16)[0]
 
 
 def test_the_plan_cuts_a_prompt_into_buckets(executor):
@@ -225,25 +261,38 @@ def test_the_plan_cuts_a_prompt_into_buckets(executor):
         JittedDecoder(executor.config, params=executor.params, positions=POSITIONS, chunk_buckets=(12, 16))
 
 
-def test_generate_is_greedy_over_the_references_logits_and_moves_the_counters(model, executor):
+def test_what_a_dispatch_costs_is_the_architectures(executor):
+    """The seam: the executor takes ``init_cache``, ``prefill``, ``decode_step``,
+    ``STATS`` and ``DISPATCH_TOKENS`` from the module of the configuration's
+    class; a dearer dispatch makes the plan prefer fewer, larger chunks."""
+    arch = executor.architecture
+    assert arch.__name__ == type(executor.config).__module__ and set(executor.cache) == set(arch.init_cache(executor.config, 1, 8))
+    wide = JittedDecoder(executor.config, params=executor.params, slots=1, positions=4096, chunk_buckets=(512, 2048, 2560))
+    dear = [(0, 1100, 2048)]  # a dispatch at a chunk of 512's price: one chunk of 2,048, 948 of them padding
+    cheap = [(0, 512, 512), (512, 512, 512), (1024, 76, 512)]
+    assert wide.plan(1100) == (dear if arch.DISPATCH_TOKENS >= 512 else cheap)
+    assert wide.plan(2561) == [(0, 2560, 2560), (2560, 1, 512)]
+
+
+def test_generate_is_greedy_over_the_references_logits_and_moves_the_counters(model, served, executor):
     prompt = model["ids"][:21]
     before = devctr.snapshot()
     out = executor.generate(prompt, 6)
     moved = {k: v - before.get(k, 0) for k, v in devctr.snapshot().items()}
-    assert out["ids"].shape == (6,) and out["logits"].shape == (6, GROUP["vocab_size"])
+    assert out["ids"].shape == (6,) and out["logits"].shape == (6, served["group"]["vocab_size"])
     assert np.array_equal(out["ids"], out["logits"].argmax(axis=1))
     whole = np.concatenate([prompt, out["ids"]])
-    ref = family.reference_logits(model["params"], GROUP, [whole], [list(range(20, 26))], q_block=16)[0]
-    assert np.abs(out["logits"] - ref).max() < 2e-5
-    # what a request of 21 tokens and 6 new ones implies
+    assert np.abs(out["logits"] - _reference(served, whole, list(range(20, 26)))).max() < 2e-5
+    # what a request of 21 tokens (a chunk of 16 and one of 8) and 6 new ones implies
     want = {
         "gen_requests": 1, "gen_prompt_tokens": 21, "gen_prompt_tokens_padded": 24, "gen_prefill_dispatches": 2,
-        "gen_new_tokens": 6, "gen_decode_steps": 5, "moe_rows_routed": 2 * 4 * (21 + 5), "moe_rows_here": 2 * 4 * (21 + 5),
-        "dsa_keys_scored": 3 * sum(range(1, 27)), "span_count.generate_prefill": 1, "span_count.generate_decode": 1,
+        "gen_new_tokens": 6, "gen_decode_steps": 5, "span_count.generate_prefill": 1, "span_count.generate_decode": 1,
+        **served["counted"](21, 24, 5), served["silent"]: 0,  # the other architecture's counters stay where they were
     }
     assert {k: moved[k] for k in want} == want
-    exact = 3 * sum(min(t, 8) for t in range(1, 27))
-    assert exact <= moved["dsa_keys_selected"] <= exact + 12  # ties with the k-th score are selected with it
+    if served["family"] is family:
+        exact = 3 * sum(min(t, 8) for t in range(1, 27))
+        assert exact <= moved["dsa_keys_selected"] <= exact + 12  # ties with the k-th score are selected with it
     again = executor.generate(prompt, 6)  # the next slot, and then the first again
     third = executor.generate(prompt, 6)
     assert np.array_equal(again["logits"], out["logits"]) and np.array_equal(third["logits"], out["logits"])
@@ -269,15 +318,15 @@ def test_the_generation_programs_keep_the_module_names_the_benchmark_reads(progr
     """``benchmark/metrics/*.json`` find the prefill and the decode program
     in a device trace by these XLA module names."""
     one = np.zeros(1, np.int32)
-    if program == "jit__prefill_chunk":
-        lowered = executor._prefill.lower(executor.params, np.zeros(8, np.int32), executor.cache, np.int32(0), np.int32(0), np.int32(8))
+    if program == "jit__prefill_chunk":  # whatever a prompt runs, its last chunk's further layers included, lowers under this name
+        lowered = executor._prefill.lower(executor.params, np.zeros(8, np.int32), executor.cache, np.int32(0), np.int32(0), np.int32(8), np.bool_(True))
     else:
         lowered = executor._decode.lower(executor.params, one, executor.cache, one, one)
     assert re.search(r"module @(\w+)", lowered.as_text()).group(1) == program
 
 
-def test_warm_runs_every_program_and_a_generation_then_compiles_nothing(model):
-    fresh = JittedDecoder(model["cfg"], params=model["params"], slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
+def test_warm_runs_every_program_and_a_generation_then_compiles_nothing(model, served):
+    fresh = JittedDecoder(served["cfg"], params=served["params"], slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
     fresh.warm()
     before = devctr.compile_count()
     fresh.generate(model["ids"][:29], 5)
@@ -285,9 +334,11 @@ def test_warm_runs_every_program_and_a_generation_then_compiles_nothing(model):
 
 
 # ----------------------------------------------------------------- the chat
-def test_the_chat_tokenizes_generates_and_keeps_what_it_produced(model):
-    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat
+def test_the_chat_tokenizes_generates_and_keeps_what_it_produced(served):
+    from pathway_tpu.xpacks.llm.llms import TPUDecoderChat, decoder_preset
 
+    model, GROUP = served, served["group"]
+    assert type(decoder_preset({family: "deepseek-ai/DeepSeek-V3.2-Exp", hybrid_family: "microsoft/Phi-4-mini-flash-reasoning"}[served["family"]])) is type(served["cfg"])
     chat = TPUDecoderChat(config=model["cfg"], params=model["params"], max_new_tokens=4, slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
     before = devctr.snapshot()
     text = chat.__wrapped__([{"role": "user", "content": "What colour are bananas, then?"}])
@@ -303,9 +354,10 @@ def test_the_chat_tokenizes_generates_and_keeps_what_it_produced(model):
         TPUDecoderChat("no-such-decoder", params=model["params"])
 
 
-def test_the_answer_route_end_to_end(model):
+def test_the_answer_route_end_to_end(served):
     """REST -> retrieve -> prompt -> TPUDecoderChat -> response, as
     ``BaseRAGQuestionAnswerer`` and ``QARestServer`` stand."""
+    model, GROUP = served, served["group"]
     from pathway_tpu.internals.parse_graph import G
     from pathway_tpu.stdlib.indexing import BruteForceKnnFactory
     from pathway_tpu.xpacks.llm import prompts
@@ -412,3 +464,18 @@ def test_the_fused_attention_kernel_compiles_for_a_v5e_at_the_published_widths(o
         shape((chunk, L), jnp.bool_), shape((), jnp.int32), block_k=512,
     ).compile()
     assert "selected_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chunk", [512, 2560])
+def test_the_fused_scan_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, chunk):
+    """``ops/selective_scan.py`` (the second architecture's prefill; its own
+    tests are ``test_hybrid_decoder.py``'s, this one stands here because one
+    file loads libtpu): 5,120 channels of 16 states over a prompt chunk, the
+    state of 1,024 channels on chip, ``B`` and ``C`` as scalars in SMEM."""
+    from pathway_tpu.ops.selective_scan import selective_scan
+
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    compiled = selective_scan.lower(
+        shape(chunk, 5120), shape(chunk, 5120), shape(16, 5120), shape(chunk, 16), shape(chunk, 16), shape(5120), shape(16, 5120)
+    ).compile()
+    assert "selective_scan" in compiled.as_text()
