@@ -4,7 +4,8 @@ Two implementations behind one interface:
 
 - :class:`HFTokenizer` — wraps a locally cached HuggingFace tokenizer
   when one is available (the environment has no network egress, so this
-  is gated on the local cache).
+  is gated on the local cache: :func:`local_tokenizer_dir` looks for its
+  files, and ``transformers`` is imported only once they are found).
 - :class:`HashTokenizer` — deterministic hashing WordPiece stand-in:
   lowercase, split on non-alphanumerics, id = stable 64-bit hash of the
   token folded into the vocab.  Preserves the shapes/FLOPs of the real
@@ -17,14 +18,23 @@ shapes for XLA (see :mod:`pathway_tpu.ops.bucketing`).
 from __future__ import annotations
 
 import hashlib
+import os
 import re
 from typing import Sequence
 
 import numpy as np
 
+from pathway_tpu.internals import tracing as _tracing
 from pathway_tpu.ops.bucketing import bucket_size
 
-__all__ = ["Tokenizer", "HashTokenizer", "HFTokenizer", "get_tokenizer"]
+__all__ = [
+    "Tokenizer",
+    "HashTokenizer",
+    "HFTokenizer",
+    "get_tokenizer",
+    "hub_cache_roots",
+    "local_tokenizer_dir",
+]
 
 _WORD_RE = re.compile(r"[a-z0-9]+", re.UNICODE)
 
@@ -145,11 +155,87 @@ class HFTokenizer(Tokenizer):
         return ids, mask, tps
 
 
+#: a directory holds a tokenizer if it holds one of these
+_TOKENIZER_FILES = (
+    "tokenizer.json",
+    "tokenizer_config.json",
+    "vocab.txt",
+    "vocab.json",
+    "spiece.model",
+    "sentencepiece.bpe.model",
+)
+
+
+def _holds_tokenizer(path: str) -> bool:
+    return any(os.path.isfile(os.path.join(path, f)) for f in _TOKENIZER_FILES)
+
+
+#: where a hub cache is kept, first to last: (variable, what its value is joined with)
+_HUB_CACHE_VARS = (
+    ("HF_HUB_CACHE", ()),
+    ("HUGGINGFACE_HUB_CACHE", ()),
+    ("TRANSFORMERS_CACHE", ()),
+    ("HF_HOME", ("hub",)),
+    ("XDG_CACHE_HOME", ("huggingface", "hub")),
+)
+
+
+def hub_cache_roots() -> list[str]:
+    """The HuggingFace hub-cache directories that exist here, in the order
+    :func:`local_tokenizer_dir` looks through them: ``$HF_HUB_CACHE``,
+    ``$HUGGINGFACE_HUB_CACHE``, ``$TRANSFORMERS_CACHE``, ``$HF_HOME/hub``,
+    ``$XDG_CACHE_HOME/huggingface/hub``, ``~/.cache/huggingface/hub``."""
+    candidates = [
+        os.path.join(os.environ[var], *below) for var, below in _HUB_CACHE_VARS if os.environ.get(var)
+    ]
+    candidates.append(os.path.join(os.path.expanduser("~"), ".cache", "huggingface", "hub"))
+    roots: list[str] = []
+    for root in candidates:
+        if root not in roots and os.path.isdir(root):
+            roots.append(root)
+    return roots
+
+
+def local_tokenizer_dir(name: str) -> str | None:
+    """Where ``AutoTokenizer.from_pretrained(name, local_files_only=True)``
+    would find its files, or ``None`` where it would find none; decided with
+    ``os`` alone (a handful of ``stat`` calls), so that a process with no
+    local tokenizer never imports ``transformers``.
+
+    ``name`` is a directory that holds a tokenizer file (``tokenizer.json``,
+    ``tokenizer_config.json``, ``vocab.txt``, ``vocab.json``,
+    ``spiece.model``, ``sentencepiece.bpe.model``), or a hub name
+    (``org/name``) with a snapshot ``models--org--name/snapshots/*/`` that
+    holds one, under one of :func:`hub_cache_roots`.  A cache kept anywhere
+    else is not looked at: point ``HF_HUB_CACHE`` at it, pass its snapshot
+    directory as the name, or pass a ``tokenizer=``."""
+    if os.path.isdir(name):
+        return name if _holds_tokenizer(name) else None
+    repo = "models--" + name.replace("/", "--")
+    for root in hub_cache_roots():
+        snapshots = os.path.join(root, repo, "snapshots")
+        if os.path.isdir(snapshots):
+            for rev in sorted(os.listdir(snapshots)):
+                path = os.path.join(snapshots, rev)
+                if _holds_tokenizer(path):
+                    return path
+    return None
+
+
 def get_tokenizer(model_name: str | None = None, vocab_size: int = 30522) -> Tokenizer:
-    """HF tokenizer if cached locally, else the deterministic hash stand-in."""
-    if model_name:
-        try:
-            return HFTokenizer(model_name)
-        except Exception:
-            pass
-    return HashTokenizer(vocab_size)
+    """HF tokenizer if its files are here (:func:`local_tokenizer_dir`),
+    else the deterministic hash stand-in, with nothing imported.  Recorded
+    as one ``tokenizer_resolve`` span: ``args.kind`` says which it was."""
+    with _tracing.span("tokenizer_resolve") as sp:
+        sp.args = {"kind": "hash", "roots": hub_cache_roots()}
+        if model_name and local_tokenizer_dir(model_name):
+            try:
+                tok = HFTokenizer(model_name)
+            except Exception:
+                # files that transformers will not load (another format, a
+                # half-written cache): the stand-in, as with none at all
+                pass
+            else:
+                sp.args["kind"] = "hf"
+                return tok
+        return HashTokenizer(vocab_size)

@@ -70,9 +70,17 @@ class BaseEmbedder(UDF):
 class TPUEncoderEmbedder(BaseEmbedder):
     """Flax sentence encoder on TPU; one jitted call per epoch.
 
-    ``model`` picks an architecture preset (MiniLM/BGE/E5 family); random
-    deterministic weights unless ``params`` (a flax pytree) is passed or a
-    local HF tokenizer/weights cache exists.
+    ``model`` picks an architecture preset (MiniLM/BGE/E5 family) with
+    random deterministic weights unless ``params`` (a flax pytree) is
+    passed, or names a local HF checkpoint directory (weights, config and
+    ``vocab.txt``).  The tokenizer of a preset is a local HuggingFace one
+    where its files are found under that name, as a directory or as a
+    hub-cache snapshot under ``$HF_HUB_CACHE``, ``$HUGGINGFACE_HUB_CACHE``,
+    ``$TRANSFORMERS_CACHE``, ``$HF_HOME/hub``,
+    ``$XDG_CACHE_HOME/huggingface/hub`` or ``~/.cache/huggingface/hub``
+    (``models.tokenizer.local_tokenizer_dir``); ``transformers`` is imported
+    only then.  Where none is found the tokenizer is the hashing stand-in
+    and neither ``transformers`` nor ``torch`` is loaded.
     """
 
     def __init__(
