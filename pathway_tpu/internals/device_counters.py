@@ -31,7 +31,11 @@ counters are monotonic:
   ``moe_rows_routed`` (token-expert pairs the experts held here computed /
   pairs the router chose anywhere) and ``dsa_keys_selected`` /
   ``dsa_keys_scored`` (keys the queries attended to / keys their indexer
-  scored), the last four counted on the device by the programs themselves;
+  scored), the last four counted on the device by the programs themselves,
+  as each further architecture's ``STATS`` are (``xdec_*``, ``swa_*``,
+  ``ssm_*``; ``moe_rows_zero``, the pairs whose expert computes nothing, and
+  ``mla_keys_visible`` / ``mla_keys_multiplied``, the query-key pairs that
+  count / those the attention's block loops multiplied);
   and once a ``GroupByNode.process`` call that had dirty groups:
   ``groupby_groups_emitted`` (groups whose change it emitted) and
   ``groupby_groups_consolidated`` (those of them whose two rows could not
@@ -110,6 +114,9 @@ _counters: dict[str, int] = {
     "swa_keys_multiplied": 0,
     "ssm_tokens_scanned": 0,
     "ssm_tokens_padded": 0,
+    "moe_rows_zero": 0,
+    "mla_keys_visible": 0,
+    "mla_keys_multiplied": 0,
     "groupby_groups_emitted": 0,
     "groupby_groups_consolidated": 0,
 }

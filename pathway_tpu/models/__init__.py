@@ -21,6 +21,7 @@ from pathway_tpu.models.encoder import (
     TextEncoderModel,
     encoder_param_specs,
 )
+from pathway_tpu.models.shortcut_moe_decoder import LONGCAT_FLASH_CHAT, ShortcutMoEDecoderConfig
 from pathway_tpu.models.tokenizer import HashTokenizer, Tokenizer, get_tokenizer
 from pathway_tpu.models.vision import SIGLIP_BASE, DualEncoderModel, VisionConfig
 
@@ -28,6 +29,8 @@ __all__ = [
     "EncoderConfig",
     "DecoderConfig",
     "DEEPSEEK_V32_EXP",
+    "ShortcutMoEDecoderConfig",
+    "LONGCAT_FLASH_CHAT",
     "TextEncoderModel",
     "CrossEncoderModel",
     "VisionConfig",

@@ -32,6 +32,16 @@ the scores' bit patterns, no sort), so prefill and decode share one rule.
 Weights and caches are ``config.dtype`` (bfloat16); products accumulate in
 float32; the residual stream, norms, the router, the indexer's scores and
 the softmax are float32.
+
+Three architectures give :class:`pathway_tpu.parallel.JittedDecoder` the five
+names ``init_cache``, ``prefill``, ``decode_step``, ``STATS`` and
+``DISPATCH_TOKENS``: this module, :mod:`pathway_tpu.models.hybrid_decoder`
+and :mod:`pathway_tpu.models.shortcut_moe_decoder`.  The last calls what this
+module has after the selection as it stands: the latent-attention core
+(:func:`_prefill_core`, :func:`_decode_core`, with the causal mask in the
+selection's place) and :func:`_experts_here`, with ``_logits``, ``_swiglu``,
+``_rms``, ``_rotate``, ``_mm`` and ``_rows_of``; a change to one of them for one
+generator is measured on the other.
 """
 
 from __future__ import annotations
@@ -235,8 +245,10 @@ def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig):
     expert's run is cut into blocks of ``expert_block`` pairs (the last one
     part empty), and a loop over exactly the blocks in use multiplies each by
     its expert's three matrices.  Work follows the pairs that came, not the
-    worst case.  Returns the result [T, hidden] float32 and the pairs
-    computed."""
+    worst case.  Expert ids outside the held range are not this chip's,
+    whatever they are: another chip's experts, or experts that hold nothing
+    (a router wider than ``n_routed_experts``, whose caller adds what those
+    give).  Returns the result [T, hidden] float32 and the pairs computed."""
     T, K = chosen.shape
     E, B, dt = cfg.experts_held, cfg.expert_block, cfg.dtype
     local = chosen - cfg.expert_offset
@@ -288,22 +300,18 @@ def _rows_of(cache, layer: int, slot):
 
 
 # ----------------------------------------------------------------- prefill
-def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg: DecoderConfig):
-    """Attention of a chunk's queries over their sequence's cached rows, in
-    the expanded form.  ``n_blocks`` key blocks are visited: those the
-    chunk's last token can see."""
+def _prefill_core(q_nope, q_rope, latent_rows, mask, n_blocks, lp, cfg):
+    """Latent attention of a chunk's queries over the cached rows ``mask``
+    [C, L] marks, in the expanded form, through ``W_o``: [C, hidden].
+    ``n_blocks`` key blocks are visited: those the chunk's last token can
+    see.  Every query's mask holds a key.  The core of every architecture
+    with a latent cache (this module passes its indexer's selection,
+    :mod:`pathway_tpu.models.shortcut_moe_decoder` the causal mask); of
+    ``cfg`` it reads the head sizes, ``kv_lora_rank``, ``key_block``,
+    ``dtype`` and ``softmax_scale``, of ``lp`` ``kv_b`` and ``o``."""
     C, KB, dt = q_nope.shape[0], cfg.key_block, cfg.dtype
     H, nope, vd, rank = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
     L = latent_rows.shape[0]
-
-    def score_block(b, scores):
-        keys = jax.lax.dynamic_slice_in_dim(index_rows, b * KB, KB)
-        per_head = jax.nn.relu(_mm("tjd,sd->tjs", qi, keys))
-        return jax.lax.dynamic_update_slice_in_dim(scores, jnp.sum(per_head * wi[:, :, None], axis=1), b * KB, axis=1)
-
-    scores = jax.lax.fori_loop(0, n_blocks, score_block, jnp.full((C, L), _NEG, jnp.float32))
-    visible = jnp.arange(L)[None, :] <= pos[:, None]
-    selected = _select(scores, visible, cfg.index_topk)
     if jax.default_backend() == "tpu":
         # the fused kernel (ops/selected_attention.py): keys and values expanded block by block into buffers per
         # head, then scores, softmax and weighted sum with nothing of a score tile leaving the chip
@@ -318,15 +326,15 @@ def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_b
 
         k_nope, v = jax.lax.fori_loop(0, n_blocks, expand_block, (jnp.zeros((H, L, nope), dt), jnp.zeros((H, L, vd), dt)))
         scaled = lambda q: (q.astype(jnp.float32) * cfg.softmax_scale).astype(dt).transpose(1, 0, 2)
-        out = selected_attention(scaled(q_nope), scaled(q_rope), k_nope, latent_rows[:, rank:], v, selected, n_blocks, block_k=KB)
-        return _mm("td,dc->tc", out.transpose(1, 0, 2).reshape(C, H * vd), lp["o"]), selected, visible
+        out = selected_attention(scaled(q_nope), scaled(q_rope), k_nope, latent_rows[:, rank:], v, mask, n_blocks, block_k=KB)
+        return _mm("td,dc->tc", out.transpose(1, 0, 2).reshape(C, H * vd), lp["o"])
 
     def attend_block(b, carry):
         top, mass, acc = carry
         rows = jax.lax.dynamic_slice_in_dim(latent_rows, b * KB, KB)
         kv = _mm("sr,rd->sd", rows[:, :rank], lp["kv_b"], dt).reshape(KB, H, nope + vd)
         s = (_mm("thd,shd->hts", q_nope, kv[..., :nope]) + _mm("thd,sd->hts", q_rope, rows[:, rank:])) * cfg.softmax_scale
-        sel = jax.lax.dynamic_slice_in_dim(selected, b * KB, KB, axis=1)[None]
+        sel = jax.lax.dynamic_slice_in_dim(mask, b * KB, KB, axis=1)[None]
         new_top = jnp.maximum(top, jnp.max(jnp.where(sel, s, _NEG), axis=-1))
         p = jnp.where(sel, jnp.exp(s - new_top[..., None]), 0.0)
         shrink = jnp.exp(top - new_top)
@@ -336,7 +344,25 @@ def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_b
     start = (jnp.full((H, C), _NEG, jnp.float32), jnp.zeros((H, C), jnp.float32), jnp.zeros((H, C, vd), jnp.float32))
     _, mass, acc = jax.lax.fori_loop(0, n_blocks, attend_block, start)
     out = (acc / mass[..., None]).astype(dt).transpose(1, 0, 2).reshape(C, H * vd)
-    return _mm("td,dc->tc", out, lp["o"]), selected, visible
+    return _mm("td,dc->tc", out, lp["o"])
+
+
+def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg: DecoderConfig):
+    """Attention of a chunk's queries over their sequence's cached rows: the
+    indexer's scores over the ``n_blocks`` key blocks the chunk's last token
+    can see, the selection, and the core over the keys selected."""
+    C, KB = q_nope.shape[0], cfg.key_block
+    L = latent_rows.shape[0]
+
+    def score_block(b, scores):
+        keys = jax.lax.dynamic_slice_in_dim(index_rows, b * KB, KB)
+        per_head = jax.nn.relu(_mm("tjd,sd->tjs", qi, keys))
+        return jax.lax.dynamic_update_slice_in_dim(scores, jnp.sum(per_head * wi[:, :, None], axis=1), b * KB, axis=1)
+
+    scores = jax.lax.fori_loop(0, n_blocks, score_block, jnp.full((C, L), _NEG, jnp.float32))
+    visible = jnp.arange(L)[None, :] <= pos[:, None]
+    selected = _select(scores, visible, cfg.index_topk)
+    return _prefill_core(q_nope, q_rope, latent_rows, selected, n_blocks, lp, cfg), selected, visible
 
 
 def prefill(params, ids, cache, slot, start, length, last=True, *, config: DecoderConfig):
@@ -373,22 +399,29 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Decod
 
 
 # ------------------------------------------------------------------ decode
-def _decode_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, lp, cfg: DecoderConfig):
-    """One query against its sequence's cached rows, in the absorbed form:
-    the query is carried into the latent space (``q_nope W_kvb^K``), scores
-    and the weighted sum are taken over the latent rows themselves, and
-    the result is carried out again (``W_kvb^V``)."""
+def _decode_core(q_nope, q_rope, latent_rows, mask, lp, cfg):
+    """One query against the cached rows ``mask`` [1, L] marks, in the
+    absorbed form: the query is carried into the latent space (``q_nope
+    W_kvb^K``), scores and the weighted sum are taken over the latent rows
+    themselves, and the result is carried out again (``W_kvb^V``):
+    [heads * v_head_dim], before ``W_o``.  Shared as :func:`_prefill_core` is."""
     dt, rank, nope = cfg.dtype, cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    H, L = cfg.num_attention_heads, latent_rows.shape[0]
-    kv_b = lp["kv_b"].reshape(rank, H, nope + cfg.v_head_dim)
+    kv_b = lp["kv_b"].reshape(rank, cfg.num_attention_heads, nope + cfg.v_head_dim)
+    q_latent = _mm("hd,rhd->hr", q_nope, kv_b[..., :nope], dt)
+    s = (_mm("hr,sr->hs", q_latent, latent_rows[:, :rank]) + _mm("hd,sd->hs", q_rope, latent_rows[:, rank:])) * cfg.softmax_scale
+    p = jax.nn.softmax(jnp.where(mask, s, _NEG), axis=-1)
+    mixed = _mm("hs,sr->hr", p.astype(dt), latent_rows[:, :rank], dt)
+    return _mm("hr,rhd->hd", mixed, kv_b[..., nope:], dt).reshape(-1)
+
+
+def _decode_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, lp, cfg: DecoderConfig):
+    """One query against its sequence's cached rows: the indexer's scores,
+    the selection, and the core over the keys selected."""
+    L = latent_rows.shape[0]
     index = jnp.sum(jax.nn.relu(_mm("jd,sd->js", qi, index_rows)) * wi[:, None], axis=0)
     visible = (jnp.arange(L) <= pos)[None, :]
     selected = _select(index[None, :], visible, cfg.index_topk)
-    q_latent = _mm("hd,rhd->hr", q_nope, kv_b[..., :nope], dt)
-    s = (_mm("hr,sr->hs", q_latent, latent_rows[:, :rank]) + _mm("hd,sd->hs", q_rope, latent_rows[:, rank:])) * cfg.softmax_scale
-    p = jax.nn.softmax(jnp.where(selected, s, _NEG), axis=-1)
-    mixed = _mm("hs,sr->hr", p.astype(dt), latent_rows[:, :rank], dt)
-    out = _mm("hr,rhd->hd", mixed, kv_b[..., nope:], dt).reshape(-1)
+    out = _decode_core(q_nope, q_rope, latent_rows, selected, lp, cfg)
     return out, jnp.sum(selected).astype(jnp.int32), jnp.sum(visible).astype(jnp.int32)
 
 

@@ -157,6 +157,8 @@ _DECODER_PRESETS = {
     "deepseek-v3.2-exp": ("decoder", "DEEPSEEK_V32_EXP"),
     "microsoft/phi-4-mini-flash-reasoning": ("hybrid_decoder", "PHI4_MINI_FLASH"),
     "phi-4-mini-flash-reasoning": ("hybrid_decoder", "PHI4_MINI_FLASH"),
+    "meituan-longcat/longcat-flash-chat": ("shortcut_moe_decoder", "LONGCAT_FLASH_CHAT"),
+    "longcat-flash-chat": ("shortcut_moe_decoder", "LONGCAT_FLASH_CHAT"),
 }
 
 

@@ -3,9 +3,11 @@
 ``TPUDecoderChat``, each held against the plain reference of the benchmark's
 ``deepseek_v32`` family (float32 ``jax.numpy``, no cache, no chunks, no
 absorbed form), on seeded weights.  The executor, the chat and the answer
-route are one code for both architectures and run here over both (``served``):
-the second, ``models/hybrid_decoder.py``, against the ``phi4flash`` family's
-reference (its own tests are ``test_hybrid_decoder.py``'s)."""
+route are one code for all three architectures and run here over each
+(``served``): the second, ``models/hybrid_decoder.py``, against the
+``phi4flash`` family's reference, the third, ``models/shortcut_moe_decoder.py``,
+against the ``longcat_flash`` family's (their own tests are
+``test_hybrid_decoder.py``'s and ``test_shortcut_moe_decoder.py``'s)."""
 
 from __future__ import annotations
 
@@ -23,11 +25,12 @@ import pytest
 
 import pathway_tpu as pw
 from benchmark.families import deepseek_v32 as family
+from benchmark.families import longcat_flash as shortcut_family
 from benchmark.families import phi4flash as hybrid_family
 from pathway_tpu.internals import device_counters as devctr
 from pathway_tpu.models import MINILM_L6, decoder
 from pathway_tpu.parallel import JittedDecoder
-from tests import hybrid_toy
+from tests import hybrid_toy, shortcut_toy
 from tests.utils import T
 
 ROPE_SCALING = {"beta_fast": 32, "beta_slow": 1, "factor": 4, "mscale": 1, "mscale_all_dim": 1, "original_max_position_embeddings": 16, "type": "yarn"}
@@ -213,7 +216,7 @@ def test_bfloat16_stays_near_the_reference(model):
 
 
 # ------------------------------------------------------------ the executor
-@pytest.fixture(scope="module", params=["deepseek_v32", "phi4flash"])
+@pytest.fixture(scope="module", params=["deepseek_v32", "phi4flash", "longcat_flash"])
 def served(request, model):
     """Each architecture the executor serves, at its toy size: the
     configuration, seeded float32 parameters, the family whose reference they
@@ -226,6 +229,23 @@ def served(request, model):
             return {"moe_rows_routed": 2 * 4 * tokens, "moe_rows_here": 2 * 4 * tokens, "dsa_keys_scored": 3 * sum(range(1, tokens + 1))}
 
         return {"cfg": model["cfg"], "params": model["params"], "family": family, "group": GROUP, "counted": counted, "silent": "xdec_tokens_seen"}
+
+    if request.param == "longcat_flash":
+        def counted(prompt, padded, steps):
+            # 2 layers = 4 attention sublayers; every live token routes 4 pairs a layer; a query sees the keys up to its own; a
+            # prompt chunk multiplies every one of its queries, padded ones too, with every key block of 8 its last token can see
+            # (the request of the test: a chunk of 16 at 0, two blocks, and one of 8 at 16, three), a decode step all 48 positions
+            tokens = prompt + steps
+            assert (prompt, padded) == (21, 24)
+            return {
+                "moe_rows_routed": 2 * 4 * tokens, "mla_keys_visible": 4 * sum(range(1, tokens + 1)),
+                "mla_keys_multiplied": 4 * (16 * 16 + 8 * 24 + steps * POSITIONS),
+            }
+
+        return {
+            "cfg": shortcut_toy.config_of(shortcut_toy.GROUP), "params": shortcut_toy.float32_params(shortcut_toy.GROUP), "family": shortcut_family,
+            "group": shortcut_toy.GROUP, "counted": counted, "silent": "dsa_keys_scored",
+        }
 
     def counted(prompt, padded, steps):
         tokens = prompt + steps
@@ -293,6 +313,8 @@ def test_generate_is_greedy_over_the_references_logits_and_moves_the_counters(mo
     if served["family"] is family:
         exact = 3 * sum(min(t, 8) for t in range(1, 27))
         assert exact <= moved["dsa_keys_selected"] <= exact + 12  # ties with the k-th score are selected with it
+    if served["family"] is shortcut_family:  # every routed pair is computed here (all 16 experts held) or costs nothing
+        assert moved["moe_rows_here"] + moved["moe_rows_zero"] == moved["moe_rows_routed"] and 0 < moved["moe_rows_zero"] < moved["moe_rows_routed"]
     again = executor.generate(prompt, 6)  # the next slot, and then the first again
     third = executor.generate(prompt, 6)
     assert np.array_equal(again["logits"], out["logits"]) and np.array_equal(third["logits"], out["logits"])
@@ -338,7 +360,8 @@ def test_the_chat_tokenizes_generates_and_keeps_what_it_produced(served):
     from pathway_tpu.xpacks.llm.llms import TPUDecoderChat, decoder_preset
 
     model, GROUP = served, served["group"]
-    assert type(decoder_preset({family: "deepseek-ai/DeepSeek-V3.2-Exp", hybrid_family: "microsoft/Phi-4-mini-flash-reasoning"}[served["family"]])) is type(served["cfg"])
+    presets = {family: "deepseek-ai/DeepSeek-V3.2-Exp", hybrid_family: "microsoft/Phi-4-mini-flash-reasoning", shortcut_family: "meituan-longcat/LongCat-Flash-Chat"}
+    assert type(decoder_preset(presets[served["family"]])) is type(served["cfg"])
     chat = TPUDecoderChat(config=model["cfg"], params=model["params"], max_new_tokens=4, slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
     before = devctr.snapshot()
     text = chat.__wrapped__([{"role": "user", "content": "What colour are bananas, then?"}])
@@ -451,14 +474,16 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("chunk", [512, 2048])
-def test_the_fused_attention_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, chunk):
-    """128 heads of 128 + 64, 8,704 keys in blocks of 512, a prompt chunk of
-    queries: what the chip's compiler refuses (tiling, VMEM) shows here."""
+@pytest.mark.parametrize("heads,chunk", [(128, 512), (128, 2048), (64, 512), (64, 2048), (64, 2560)])
+def test_the_fused_attention_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, heads, chunk):
+    """128 heads of 128 + 64 (``models/decoder.py``'s) and 64
+    (``models/shortcut_moe_decoder.py``'s, which calls the same kernel with
+    the causal mask), 8,704 keys in blocks of 512, a prompt chunk of queries:
+    what the chip's compiler refuses (tiling, VMEM) shows here."""
     from pathway_tpu.ops.selected_attention import selected_attention
 
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-    H, L, bf16 = 128, 8704, jnp.bfloat16
+    H, L, bf16 = heads, 8704, jnp.bfloat16
     compiled = selected_attention.lower(
         shape((H, chunk, 128), bf16), shape((H, chunk, 64), bf16), shape((H, L, 128), bf16), shape((L, 64), bf16), shape((H, L, 128), bf16),
         shape((chunk, L), jnp.bool_), shape((), jnp.int32), block_k=512,
