@@ -35,7 +35,8 @@ counters are monotonic:
   as each further architecture's ``STATS`` are (``xdec_*``, ``swa_*``,
   ``ssm_*``; ``moe_rows_zero``, the pairs whose expert computes nothing, and
   ``mla_keys_visible`` / ``mla_keys_multiplied``, the query-key pairs that
-  count / those the attention's block loops multiplied);
+  count / those the attention multiplied: in a prompt, the fused kernel's
+  query tiles against the key blocks each visits);
   and once a ``GroupByNode.process`` call that had dirty groups:
   ``groupby_groups_emitted`` (groups whose change it emitted) and
   ``groupby_groups_consolidated`` (those of them whose two rows could not

@@ -17,8 +17,10 @@ the caller donates it (:class:`pathway_tpu.parallel.JittedDecoder` does):
   its slot from ``start`` on.  Keys and values are expanded from the latent
   rows per head (the compute-bound form), block of keys by block with a
   running softmax, over as many blocks as the chunk's last token can see:
-  on a TPU in one fused kernel (``ops/selected_attention.py``), elsewhere as
-  the same loop written in ``jax.numpy``.
+  on a TPU in one fused kernel (``ops/selected_attention.py``), whose query
+  tiles each visit only the blocks their own last row can see and none where
+  the tile is padding, elsewhere as the same loop written in ``jax.numpy``
+  over every block for every query.
 - :func:`decode_step` -- one new token for each of a few sequences, in the
   absorbed form: 128 query heads against one 576-wide latent row a token.
 
@@ -300,12 +302,19 @@ def _rows_of(cache, layer: int, slot):
 
 
 # ----------------------------------------------------------------- prefill
-def _prefill_core(q_nope, q_rope, latent_rows, mask, n_blocks, lp, cfg):
+def _prefill_core(q_nope, q_rope, latent_rows, mask, n_blocks, lp, cfg, start=None, length=None):
     """Latent attention of a chunk's queries over the cached rows ``mask``
-    [C, L] marks, in the expanded form, through ``W_o``: [C, hidden].
-    ``n_blocks`` key blocks are visited: those the chunk's last token can
-    see.  Every query's mask holds a key.  The core of every architecture
-    with a latent cache (this module passes its indexer's selection,
+    [C, L] marks, in the expanded form, through ``W_o``: [C, hidden].  The
+    chunk's queries are positions ``start .. start + C`` of the sequence,
+    ``length`` of them real (without ``start``: the latest start that
+    ``n_blocks`` allows; without ``length``: every row), and the mask marks
+    no key after a query's position.  Keys and values are expanded over the
+    ``n_blocks`` key blocks the chunk's last token can see; on a TPU the
+    kernel's query tiles visit fewer (a tile the blocks its own last row can
+    see, a tile of padding none: :func:`pathway_tpu.ops.selected_attention.
+    query_tiles`), and a row of such a tile comes out zero.  Every query's
+    mask holds a key.  The core of every architecture with a latent cache
+    (this module passes its indexer's selection,
     :mod:`pathway_tpu.models.shortcut_moe_decoder` the causal mask); of
     ``cfg`` it reads the head sizes, ``kv_lora_rank``, ``key_block``,
     ``dtype`` and ``softmax_scale``, of ``lp`` ``kv_b`` and ``o``."""
@@ -326,7 +335,9 @@ def _prefill_core(q_nope, q_rope, latent_rows, mask, n_blocks, lp, cfg):
 
         k_nope, v = jax.lax.fori_loop(0, n_blocks, expand_block, (jnp.zeros((H, L, nope), dt), jnp.zeros((H, L, vd), dt)))
         scaled = lambda q: (q.astype(jnp.float32) * cfg.softmax_scale).astype(dt).transpose(1, 0, 2)
-        out = selected_attention(scaled(q_nope), scaled(q_rope), k_nope, latent_rows[:, rank:], v, mask, n_blocks, block_k=KB)
+        start = n_blocks * KB - C if start is None else start
+        length = C if length is None else length
+        out = selected_attention(scaled(q_nope), scaled(q_rope), k_nope, latent_rows[:, rank:], v, mask, start, length, block_k=KB)
         return _mm("td,dc->tc", out.transpose(1, 0, 2).reshape(C, H * vd), lp["o"])
 
     def attend_block(b, carry):
@@ -347,10 +358,12 @@ def _prefill_core(q_nope, q_rope, latent_rows, mask, n_blocks, lp, cfg):
     return _mm("td,dc->tc", out, lp["o"])
 
 
-def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg: DecoderConfig):
+def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg: DecoderConfig, start, length):
     """Attention of a chunk's queries over their sequence's cached rows: the
     indexer's scores over the ``n_blocks`` key blocks the chunk's last token
-    can see, the selection, and the core over the keys selected."""
+    can see, the selection, and the core over the keys selected (the chunk
+    at ``start`` with ``length`` real rows, as :func:`_prefill_core` takes
+    them)."""
     C, KB = q_nope.shape[0], cfg.key_block
     L = latent_rows.shape[0]
 
@@ -362,7 +375,7 @@ def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_b
     scores = jax.lax.fori_loop(0, n_blocks, score_block, jnp.full((C, L), _NEG, jnp.float32))
     visible = jnp.arange(L)[None, :] <= pos[:, None]
     selected = _select(scores, visible, cfg.index_topk)
-    return _prefill_core(q_nope, q_rope, latent_rows, selected, n_blocks, lp, cfg), selected, visible
+    return _prefill_core(q_nope, q_rope, latent_rows, selected, n_blocks, lp, cfg, start, length), selected, visible
 
 
 def prefill(params, ids, cache, slot, start, length, last=True, *, config: DecoderConfig):
@@ -386,7 +399,9 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Decod
         latent_all = jax.lax.dynamic_update_slice(latent_all, latent[None, None], (li, slot, start, 0))
         index_all = jax.lax.dynamic_update_slice(index_all, ki[None, None], (li, slot, start, 0))
         latent_rows, index_rows = _rows_of(latent_all, li, slot), _rows_of(index_all, li, slot)
-        attended, selected, visible = _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg)
+        attended, selected, visible = _prefill_attention(
+            q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg, start=start, length=length
+        )
         h = h + attended
         added, rows_here, rows_routed = _mlp(h, lp, live, cfg)
         h = h + added

@@ -60,8 +60,10 @@ __all__ = ["ShortcutMoEDecoderConfig", "LONGCAT_FLASH_CHAT", "init_cache", "pref
 #: pairs the experts held here computed / pairs the router chose anywhere, the
 #: zero-computation experts among them / those of them whose expert computes
 #: nothing; query-key pairs that count (live queries, visible keys) / pairs the
-#: block loops multiplied (a prompt chunk: every query against every block its
-#: last token can see; a decode step: every position of the cache)
+#: attention multiplies (a prompt chunk: the fused kernel's query tiles, each
+#: against the key blocks its own last row can see and a tile of padding
+#: against none, as :func:`pathway_tpu.ops.selected_attention.query_tiles`
+#: plans them for the kernel; a decode step: every position of the cache)
 STATS = ("moe_rows_here", "moe_rows_routed", "moe_rows_zero", "mla_keys_visible", "mla_keys_multiplied")
 
 #: what one more prefill dispatch costs beside its tokens, in tokens.  Little:
@@ -207,6 +209,8 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Short
     :data:`STATS`.  ``start + C`` may not pass the cache's positions.
     ``last`` (whether the prompt ends in this chunk) is the executor's to
     say and changes nothing here: every layer runs for every token."""
+    from pathway_tpu.ops.selected_attention import query_tiles  # Pallas: a second to import, so only where a prompt is traced
+
     cfg = config
     C = ids.shape[0]
     pos = start + jnp.arange(C, dtype=jnp.int32)
@@ -214,12 +218,14 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Short
     n_blocks = (start + C + cfg.key_block - 1) // cfg.key_block
     latent_all = cache["latent"]
     visible = jnp.arange(latent_all.shape[2])[None, :] <= pos[:, None]
-    keys = jnp.stack([jnp.sum(visible & live[:, None]).astype(jnp.int32), jnp.asarray(C * cfg.key_block * n_blocks, jnp.int32)])
+    rows, visits = query_tiles(start, length, C, block_k=cfg.key_block)
+    keys = jnp.stack([jnp.sum(visible & live[:, None]).astype(jnp.int32), (rows * cfg.key_block * jnp.sum(visits)).astype(jnp.int32)])
 
     def attend(h, ap, latent_all, sublayer):
         q_nope, q_rope, latent = _attention_inputs(h, ap, pos, cfg)
         latent_all = jax.lax.dynamic_update_slice(latent_all, latent[None, None], (sublayer, slot, start, 0))
-        return _prefill_core(q_nope, q_rope, _rows_of(latent_all, sublayer, slot), visible, n_blocks, ap, cfg), latent_all
+        rows_here = _rows_of(latent_all, sublayer, slot)
+        return _prefill_core(q_nope, q_rope, rows_here, visible, n_blocks, ap, cfg, start=start, length=length), latent_all
 
     h = params["embed"][ids].astype(jnp.float32)
     stats = jnp.zeros((len(STATS),), jnp.int32)

@@ -7,31 +7,49 @@ keys ``selected`` marks, and the weighted sum of the values.  Written with
 ``jax.numpy`` alone this is bound by memory, not by the MXU: every
 ``[heads, queries, keys]`` block of float32 scores goes out to HBM and is
 read back three times (the running maximum, the exponentials, the product
-with the values).  The kernel keeps a ``[queries, block_k]`` tile of one
-head's scores in VMEM from the first product to the last, with the running
-maximum, the normaliser and the accumulator in scratch across the key
-blocks (the usual online softmax), so HBM sees the operands and the result.
+with the values).  The kernel keeps a ``[block_q, block_k]`` tile of scores
+of a head in VMEM from the first product to the last, with the running
+maximum, the normaliser and the accumulator in scratch across the key blocks
+(the usual online softmax), so HBM sees the operands and the result.
 
-Grid ``(heads, key blocks)``, key blocks innermost.  ``key_blocks`` (a
-scalar, prefetched) says how many key blocks any query of the call can see:
-later grid steps neither compute nor fetch (their block index is pinned to
-the last visible one).  All queries of a call are one block: a prompt chunk.
-The kernel is bound by the vector unit, not the MXU (a score tile is a
-million exponentials), so what it does per score is kept to an add, a
-maximum, a subtraction and the exponential: the softmax scale comes in the
-queries, and the selection as a bfloat16 tile that is added (0 or -1e30).
+The queries of a call are a prompt chunk that starts at ``start`` of its
+sequence, ``length`` of its rows real and the rest padding.  They are cut
+into tiles of ``block_q`` rows, and a tile visits only the key blocks its own
+last row can see (:func:`query_tiles`): the chunk's upper triangle is not
+scored, and a tile that holds no real row visits none and writes zeros.  The
+grid is ``(heads / BLOCK_H, steps)``, ``BLOCK_H`` heads a step (they share
+the selection's tile): the steps are the (query tile, key block) pairs of
+that schedule in a flattened list, tile by tile and each tile's blocks from
+the first, one step for a tile of padding, so no step of the grid is idle;
+the list comes in as scalar-prefetch arrays and sets the grid's length.
+Every row sees its key blocks in the same order, and a block past a tile's
+last is one the causal bound masks from each of its rows (which would have
+left them as they were), so a real row's result is what attention over
+every block would give.  The kernel is bound by the vector unit, not the MXU
+(a head's score tile is 131,072 exponentials), so what it does per score
+is kept to an add, a maximum, a subtraction and the exponential: the
+softmax scale comes in the queries, and the selection as a bfloat16 tile
+that is added (0 or -1e30).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["selected_attention"]
+__all__ = ["selected_attention", "query_tiles"]
+
+#: rows of a query tile (the key block's or the chunk's where either has
+#: fewer) and heads a grid step (a divisor of the heads where they are not a
+#: multiple): tiles of 256 x 512 scores, eight heads a step, measured on a
+#: v5e at the answer cells' chunks against 128, 512 and 1,024 rows and 1, 2
+#: and 4 heads a step (PERF.md §6, PR 38)
+BLOCK_Q, BLOCK_H = 256, 8
 
 #: what an unselected key's score is moved by, and where the running maximum
 #: starts: far enough below any score that ``exp`` gives an exact zero, and the
@@ -40,8 +58,23 @@ __all__ = ["selected_attention"]
 _MASKED, _FLOOR = -1e30, -1e29
 
 
-def _kernel(nkb_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, bias_ref, o_ref, top_ref, mass_ref, acc_ref):
-    j = pl.program_id(1)
+def query_tiles(start, length, chunk: int, block_q: int = BLOCK_Q, block_k: int = 512):
+    """The schedule of a chunk of ``chunk`` queries at ``start`` with
+    ``length`` real rows: the rows of a query tile, and for each tile how many
+    key blocks of ``block_k`` from the first it visits -- those its last row
+    can see, none where every row of it is padding.  A tile multiplies
+    ``rows * block_k`` query-key pairs a block it visits."""
+    rows = min(block_q, block_k, chunk)
+    if chunk % rows:
+        raise ValueError(f"{chunk} queries are not a multiple of the query tile {rows}")
+    i = jnp.arange(chunk // rows, dtype=jnp.int32)
+    seen = (start + (i + 1) * rows + block_k - 1) // block_k
+    return rows, jnp.where(i * rows < length, seen, 0).astype(jnp.int32)
+
+
+def _kernel(tile_ref, block_ref, visits_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, bias_ref, o_ref, top_ref, mass_ref, acc_ref):
+    step = pl.program_id(1)
+    j, n = block_ref[step], visits_ref[tile_ref[step]]
 
     @pl.when(j == 0)
     def _():
@@ -49,53 +82,80 @@ def _kernel(nkb_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, bias_ref, o_ref, top
         mass_ref[...] = jnp.zeros(mass_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(j < nkb_ref[0])
+    @pl.when(j < n)
     def _():
         contract_last = (((1,), (1,)), ((), ()))
-        s = jax.lax.dot_general(qn_ref[0], kn_ref[0], contract_last, preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(qr_ref[0], kr_ref[...], contract_last, preferred_element_type=jnp.float32)
-        s = s + bias_ref[...].astype(jnp.float32)
-        top = top_ref[...]
-        new_top = jnp.maximum(top, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - new_top)  # an unselected key: exp(-1e30 - top) = 0
-        shrink = jnp.exp(top - new_top)
-        mass_ref[...] = mass_ref[...] * shrink + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * shrink + jnp.dot(p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32)
-        top_ref[...] = new_top
+        bias = bias_ref[...].astype(jnp.float32)
+        for g in range(qn_ref.shape[0]):
+            s = jax.lax.dot_general(qn_ref[g], kn_ref[g], contract_last, preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(qr_ref[g], kr_ref[...], contract_last, preferred_element_type=jnp.float32)
+            s = s + bias
+            top = top_ref[g]
+            new_top = jnp.maximum(top, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - new_top)  # an unselected key: exp(-1e30 - top) = 0
+            shrink = jnp.exp(top - new_top)
+            mass_ref[g] = mass_ref[g] * shrink + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[g] = acc_ref[g] * shrink + jnp.dot(p.astype(v_ref.dtype), v_ref[g], preferred_element_type=jnp.float32)
+            top_ref[g] = new_top
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == n - 1)
     def _():
-        o_ref[0] = (acc_ref[...] / mass_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / mass_ref[...]).astype(o_ref.dtype)
+
+    @pl.when(n == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def selected_attention(q_nope, q_rope, k_nope, k_rope, v, selected, key_blocks, *, block_k: int = 512, interpret: bool = False):
+def _steps(visits, key_blocks: int):
+    """The flattened schedule: for each step of a grid as long as the list,
+    the query tile and the key block (the list's length, which sets the
+    grid's, is the third value: entries past it are never visited); a tile
+    of padding gets one step, its key block pinned to the block before it
+    (padding ends a chunk, so that is the last real tile's last), which
+    fetches nothing new."""
+    tiles = visits.shape[0]
+    per_tile = jnp.maximum(visits, 1)
+    ends = jnp.cumsum(per_tile)
+    s = jnp.arange(tiles * key_blocks, dtype=jnp.int32)
+    tile = jnp.sum(s[:, None] >= ends[None, :], axis=1).astype(jnp.int32)
+    block = jnp.where(visits[tile] > 0, s - (ends - per_tile)[tile], jnp.max(visits) - 1).astype(jnp.int32)
+    return tile, block, ends[-1]
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret"))
+def selected_attention(q_nope, q_rope, k_nope, k_rope, v, selected, start, length, *, block_q: int = BLOCK_Q, block_k: int = 512, interpret: bool = False):
     """``q_nope`` [H, C, dn] and ``q_rope`` [H, C, dr], the softmax scale
     already in them; ``k_nope`` [H, L, dn], ``k_rope`` [L, dr], ``v`` [H, L,
-    dv]; ``selected`` [C, L] bool: the query attends to the key;
-    ``key_blocks`` int32 scalar: how many blocks of ``block_k`` keys, from
-    the first, hold every selected key.  Every query selects at least one
-    key.  Returns [H, C, dv] in ``v``'s type."""
+    dv]; ``selected`` [C, L] bool: the query attends to the key, inside the
+    causal bound (query ``t`` is position ``start + t`` and selects no key
+    after it); ``start`` and ``length`` int32 scalars: the chunk's first
+    position and its real rows.  Every query selects at least one key.
+    Returns [H, C, dv] in ``v``'s type: a real row's attention, zeros in a
+    query tile of padding (a row of padding in a tile with a real one has
+    its own attention)."""
     H, C, dn = q_nope.shape
     L, dr = k_rope.shape
     dv = v.shape[-1]
     if L % block_k:
         raise ValueError(f"{L} keys are not a multiple of the key block {block_k}")
+    hb = math.gcd(H, BLOCK_H)
+    rows, visits = query_tiles(start, length, C, block_q, block_k)
+    tile, block, steps = _steps(visits, L // block_k)
     bias = jnp.where(selected, 0.0, _MASKED).astype(jnp.bfloat16)
-    pinned = lambda j, nkb: jnp.minimum(j, nkb[0] - 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(H, L // block_k),
+        num_scalar_prefetch=3,
+        grid=(H // hb, steps),
         in_specs=[
-            pl.BlockSpec((1, C, dn), lambda h, j, nkb: (h, 0, 0)),
-            pl.BlockSpec((1, C, dr), lambda h, j, nkb: (h, 0, 0)),
-            pl.BlockSpec((1, block_k, dn), lambda h, j, nkb: (h, pinned(j, nkb), 0)),
-            pl.BlockSpec((block_k, dr), lambda h, j, nkb: (pinned(j, nkb), 0)),
-            pl.BlockSpec((1, block_k, dv), lambda h, j, nkb: (h, pinned(j, nkb), 0)),
-            pl.BlockSpec((C, block_k), lambda h, j, nkb: (0, pinned(j, nkb))),
+            pl.BlockSpec((hb, rows, dn), lambda h, s, tile, block, visits: (h, tile[s], 0)),
+            pl.BlockSpec((hb, rows, dr), lambda h, s, tile, block, visits: (h, tile[s], 0)),
+            pl.BlockSpec((hb, block_k, dn), lambda h, s, tile, block, visits: (h, block[s], 0)),
+            pl.BlockSpec((block_k, dr), lambda h, s, tile, block, visits: (block[s], 0)),
+            pl.BlockSpec((hb, block_k, dv), lambda h, s, tile, block, visits: (h, block[s], 0)),
+            pl.BlockSpec((rows, block_k), lambda h, s, tile, block, visits: (tile[s], block[s])),
         ],
-        out_specs=pl.BlockSpec((1, C, dv), lambda h, j, nkb: (h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((C, 1), jnp.float32), pltpu.VMEM((C, 1), jnp.float32), pltpu.VMEM((C, dv), jnp.float32)],
+        out_specs=pl.BlockSpec((hb, rows, dv), lambda h, s, tile, block, visits: (h, tile[s], 0)),
+        scratch_shapes=[pltpu.VMEM((hb, rows, 1), jnp.float32), pltpu.VMEM((hb, rows, 1), jnp.float32), pltpu.VMEM((hb, rows, dv), jnp.float32)],
     )
     return pl.pallas_call(
         _kernel,
@@ -108,4 +168,4 @@ def selected_attention(q_nope, q_rope, k_nope, k_rope, v, selected, key_blocks, 
         ),
         name="selected_attention",
         interpret=interpret,
-    )(jnp.reshape(key_blocks, (1,)).astype(jnp.int32), q_nope, q_rope, k_nope, k_rope, v, bias)
+    )(tile, block, visits, q_nope, q_rope, k_nope, k_rope, v, bias)

@@ -233,13 +233,14 @@ def served(request, model):
     if request.param == "longcat_flash":
         def counted(prompt, padded, steps):
             # 2 layers = 4 attention sublayers; every live token routes 4 pairs a layer; a query sees the keys up to its own; a
-            # prompt chunk multiplies every one of its queries, padded ones too, with every key block of 8 its last token can see
-            # (the request of the test: a chunk of 16 at 0, two blocks, and one of 8 at 16, three), a decode step all 48 positions
+            # prompt chunk multiplies its query tiles of 8 rows (a key block's), each with every key block of 8 its last row can
+            # see (the request of the test: a chunk of 16 at 0, tiles of one and two blocks, and one of 8 at 16, a tile of three),
+            # a decode step all 48 positions
             tokens = prompt + steps
             assert (prompt, padded) == (21, 24)
             return {
                 "moe_rows_routed": 2 * 4 * tokens, "mla_keys_visible": 4 * sum(range(1, tokens + 1)),
-                "mla_keys_multiplied": 4 * (16 * 16 + 8 * 24 + steps * POSITIONS),
+                "mla_keys_multiplied": 4 * (8 * 8 * (1 + 2 + 3) + steps * POSITIONS),
             }
 
         return {
@@ -441,7 +442,7 @@ def test_the_fused_attention_kernel_is_the_plain_softmax_over_the_selected_keys(
     """``ops/selected_attention.py`` in interpret mode (the TPU's prefill
     path; on the CPU the decoder runs the same loop in ``jax.numpy``): a
     chunk that starts inside its sequence, so that the last key blocks are
-    past every query and skipped."""
+    past every query and skipped, in two query tiles of a key block's rows."""
     from pathway_tpu.ops.selected_attention import selected_attention
 
     rng = np.random.default_rng(0)
@@ -455,11 +456,69 @@ def test_the_fused_attention_kernel_is_the_plain_softmax_over_the_selected_keys(
     kn, v = kn.at[:, blocks * bk :].set(jnp.nan), v.at[:, blocks * bk :].set(jnp.nan)  # never fetched
     sel[200:210, : 2 * bk] = False  # rows whose first tiles hold no selected key
     sel[200:210, 2 * bk] = True
-    got = selected_attention(qn, qr, kn, kr, v, jnp.asarray(sel), jnp.int32(blocks), block_k=bk, interpret=True)
+    got = selected_attention(qn, qr, kn, kr, v, jnp.asarray(sel), jnp.int32(start), jnp.int32(C), block_k=bk, interpret=True)
     want = _plain_selected_attention(qn, qr, kn[:, : blocks * bk], kr[: blocks * bk], v[:, : blocks * bk], sel[:, : blocks * bk], 1.0)
     assert got.shape == (H, C, 128) and float(jnp.abs(got.astype(jnp.float32) - want).max()) < 0.03
     with pytest.raises(ValueError, match="multiple of the key block"):
-        selected_attention(qn, qr, kn, kr, v, jnp.asarray(sel), jnp.int32(1), block_k=384, interpret=True)
+        selected_attention(qn, qr, kn, kr, v, jnp.asarray(sel), jnp.int32(0), jnp.int32(C), block_k=384, interpret=True)
+    with pytest.raises(ValueError, match="multiple of the query tile"):
+        selected_attention(qn, qr, kn, kr, v, jnp.asarray(sel), jnp.int32(0), jnp.int32(C), block_q=96, block_k=bk, interpret=True)
+
+
+@pytest.mark.parametrize(
+    "heads, chunk, keys, block_q, block_k, start, length",
+    [(2, 256, 512, 64, 128, 0, 256), (2, 256, 640, 64, 128, 100, 256), (4, 256, 640, 64, 128, 128, 100), (3, 128, 256, 32, 64, 40, 1)],
+    ids=["at_0", "mid_block", "padding_tiles", "one_live_row"],
+)
+def test_the_fused_kernels_query_tiles_are_the_plain_softmax_and_a_tile_of_padding_is_zero(heads, chunk, keys, block_q, block_k, start, length):
+    """The causal schedule in interpret mode: query tiles of ``block_q`` rows,
+    each over the key blocks its last row can see, a chunk at the sequence's
+    start or inside a block, every row real or the last tiles padding (a
+    tile with a real row keeps its rows of padding; three heads are a step
+    each, four one step).  Real rows are the plain softmax's; a tile of
+    padding is exactly zero; nothing is non-finite; keys past the chunk's
+    last are never fetched."""
+    from pathway_tpu.ops.selected_attention import query_tiles, selected_attention
+
+    rng = np.random.default_rng(start + length)
+    draw = lambda *shape: jnp.asarray(rng.normal(0, 1, shape), jnp.bfloat16)
+    H, C, L, bk = heads, chunk, keys, block_k
+    qn, qr, kn, kr, v = 0.12 * draw(H, C, 128), 0.12 * draw(H, C, 64), draw(H, L, 128), draw(L, 64), draw(H, L, 128)
+    sel = (np.arange(L)[None, :] <= start + np.arange(C)[:, None]) & (rng.random((C, L)) < 0.3)
+    sel[:, 0] = True
+    seen = (start + C + bk - 1) // bk * bk
+    kn, v = kn.at[:, seen:].set(jnp.nan), v.at[:, seen:].set(jnp.nan)
+    got = selected_attention(qn, qr, kn, kr, v, jnp.asarray(sel), jnp.int32(start), jnp.int32(length), block_q=block_q, block_k=bk, interpret=True)
+    want = _plain_selected_attention(qn, qr, kn[:, :seen], kr[:seen], v[:, :seen], sel[:, :seen], 1.0)
+    rows, visits = query_tiles(start, length, C, block_q, bk)
+    padding = np.repeat(np.asarray(visits) == 0, rows)
+    got = np.asarray(got, np.float32)
+    assert rows == block_q and padding.sum() == C - -(-length // rows) * rows
+    assert np.isfinite(got).all() and (got[:, padding] == 0).all()
+    assert float(np.abs(got[:, ~padding] - np.asarray(want)[:, ~padding]).max()) < 0.03
+
+
+def test_the_query_tiles_of_the_cells_plan():
+    """The schedule at the answer cells' chunk plan (a 6,335-token prompt:
+    2,560 at 0, 2,048 at 2,560, 2,048 at 4,608 of which 1,727 are real):
+    tiles of 512 visit 15, 30 and 46 key blocks of 512, tiles of 256 30, 60
+    and 79; of the pairs they multiply, the causally visible pairs of real
+    rows are 84 % and 90.6 %, where one tile a chunk multiplied 29.6 M, 68 %
+    of them visible.  A tile is no larger than the key block or the chunk."""
+    from pathway_tpu.ops.selected_attention import query_tiles
+
+    plan = [(2560, 0, 2560), (2048, 2560, 2048), (2048, 4608, 1727)]
+    visible = sum((start + 1 + start + length) * length // 2 for _, start, length in plan)
+    assert visible == 20_069_280
+    at_512 = [query_tiles(start, length, C, 512, 512) for C, start, length in plan]
+    assert [np.asarray(v).tolist() for _, v in at_512] == [[1, 2, 3, 4, 5], [6, 7, 8, 9], [10, 11, 12, 13]]
+    at_256 = [query_tiles(start, length, C, 256, 512) for C, start, length in plan]
+    assert [int(np.sum(v)) for _, v in at_256] == [30, 60, 79] and np.asarray(at_256[2][1]).tolist()[-1] == 0
+    pairs = lambda tiles: sum(rows * 512 * int(np.sum(v)) for rows, v in tiles)
+    assert (pairs(at_512), pairs(at_256)) == (23_855_104, 22_151_168)
+    assert round(100 * visible / pairs(at_512), 1) == 84.1 and round(100 * visible / pairs(at_256), 1) == 90.6
+    assert sum(C * 512 * -(-(start + C) // 512) for C, start, _ in plan) == 29_622_272
+    assert query_tiles(0, 16, 16, 256, 8)[0] == 8 and query_tiles(0, 4, 4, 256, 8)[0] == 4
 
 
 @pytest.fixture(scope="module")
@@ -478,15 +537,17 @@ def one_chip():
 def test_the_fused_attention_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, heads, chunk):
     """128 heads of 128 + 64 (``models/decoder.py``'s) and 64
     (``models/shortcut_moe_decoder.py``'s, which calls the same kernel with
-    the causal mask), 8,704 keys in blocks of 512, a prompt chunk of queries:
-    what the chip's compiler refuses (tiling, VMEM) shows here."""
-    from pathway_tpu.ops.selected_attention import selected_attention
+    the causal mask), 8,704 keys in blocks of 512, a prompt chunk of queries
+    in the query tiles and heads a step chosen on the chip (PR 38), the
+    chunk's start and its real rows as scalars: what the chip's compiler
+    refuses (tiling, VMEM, the grid the schedule sets) shows here."""
+    from pathway_tpu.ops.selected_attention import BLOCK_Q, selected_attention
 
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
     H, L, bf16 = heads, 8704, jnp.bfloat16
     compiled = selected_attention.lower(
         shape((H, chunk, 128), bf16), shape((H, chunk, 64), bf16), shape((H, L, 128), bf16), shape((L, 64), bf16), shape((H, L, 128), bf16),
-        shape((chunk, L), jnp.bool_), shape((), jnp.int32), block_k=512,
+        shape((chunk, L), jnp.bool_), shape((), jnp.int32), shape((), jnp.int32), block_q=BLOCK_Q, block_k=512,
     ).compile()
     assert "selected_attention" in compiled.as_text()
 

@@ -233,9 +233,9 @@ def _identity_dropped(x, lp, cfg, _route=decoder._route):
     return chosen, jnp.where(chosen >= cfg.n_routed_experts, 0.0, gates)
 
 
-def _mask_lagged(q_nope, q_rope, rows, mask, n_blocks, lp, cfg, _core=decoder._prefill_core):
+def _mask_lagged(q_nope, q_rope, rows, mask, n_blocks, lp, cfg, start=None, length=None, _core=decoder._prefill_core):
     newest = jnp.maximum(jnp.sum(mask, axis=1) - 1 - cfg.key_block, 0)  # every query loses its newest key block; key 0 stays
-    return _core(q_nope, q_rope, rows, jnp.arange(mask.shape[1])[None, :] <= newest[:, None], n_blocks, lp, cfg)
+    return _core(q_nope, q_rope, rows, jnp.arange(mask.shape[1])[None, :] <= newest[:, None], n_blocks, lp, cfg, start, length)
 
 
 def _caches_crossed(cache, sublayer, slot, _rows_of=decoder._rows_of):
@@ -304,9 +304,11 @@ def test_a_share_of_the_experts_counts_the_rows_it_computed_and_is_the_reference
 
 
 # ------------------------------------------- the core two architectures share
-def _prefill_attention_before_the_split(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg):
+def _prefill_attention_before_the_split(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg, start, length):
     """``models/decoder.py``'s ``_prefill_attention`` as it stood before its
-    core was split out (its ``jax.numpy`` branch, the one a CPU lowers)."""
+    core was split out (its ``jax.numpy`` branch, the one a CPU lowers; the
+    chunk's ``start`` and real rows, which only the kernel's schedule reads,
+    came in later)."""
     _mm, _NEG = mla_decoder._mm, mla_decoder._NEG
     C, KB, dt = q_nope.shape[0], cfg.key_block, cfg.dtype
     H, nope, vd, rank = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
@@ -404,3 +406,46 @@ def test_the_core_with_the_causal_mask_is_the_plain_softmax_over_every_visible_k
     assert np.abs(np.asarray(got) - np.asarray(plain @ ap["o"])).max() < 1e-5
     last = mla_decoder._decode_core(q_nope[-1], q_rope[-1], rows, visible[-1:], ap, cfg)
     assert np.abs(np.asarray(last) - np.asarray(plain[-1])).max() < 1e-5
+
+
+def test_the_prefill_through_the_fused_kernel_is_the_jax_numpy_branch_and_counts_its_tiles(model, monkeypatch):
+    """``_prefill_core``'s TPU branch, the fused kernel in interpret mode, over
+    a prompt of 21 tokens in a chunk of 16 and one of 24 that holds 5 (query
+    tiles of a key block's 8 rows: the last chunk's second and third are
+    padding, so the kernel writes their rows zero), then two decode steps:
+    the logits are the ``jax.numpy`` branch's, every row of the latent caches
+    is finite (a padding row's output reaches them and, through decode's
+    weighted sum, every later token), and ``mla_keys_multiplied`` is what the
+    schedule multiplies."""
+    from pathway_tpu.ops import selected_attention as kernel
+
+    cfg, params, ids = model["cfg"], model["params"], model["ids"]
+    multiplied = decoder.STATS.index("mla_keys_multiplied")
+
+    def generation():
+        prefill = jax.jit(lambda *a, **k: decoder.prefill(*a, **k), static_argnames=("config",))  # traced anew, on either branch
+        cache = decoder.init_cache(cfg, 1, POSITIONS)
+        first, cache, counted_first = prefill(params, jnp.asarray(ids[:16]), cache, 0, 0, 16, config=cfg)
+        second, cache, counted_second = prefill(params, jnp.asarray(np.pad(ids[16:21], (0, 19))), cache, 0, 16, 5, config=cfg)
+        steps = []
+        for t in (21, 22):
+            step, cache, _ = model["decode"](params, jnp.asarray(ids[t : t + 1]), cache, jnp.asarray([0]), jnp.asarray([t]), config=cfg)
+            steps.append(step[0])
+        return [np.asarray(a) for a in (first, second, *steps)], cache, [int(counted_first[multiplied]), int(counted_second[multiplied])]
+
+    plain, _, _ = generation()
+    traced = []
+
+    def interpreted(*args, _kernel=kernel.selected_attention, **kwargs):
+        traced.append(args[0].shape)
+        return _kernel(*args, **kwargs, interpret=True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel, "selected_attention", interpreted)
+    fused, cache, counted = generation()
+    assert traced == [(4, 16, 16)] * 4 + [(4, 24, 16)] * 4  # two chunks through four sublayers
+    assert all(np.abs(a - b).max() < TOLERANCE for a, b in zip(fused, plain))
+    assert np.isfinite(np.asarray(cache["latent"])).all()
+    tiles = [kernel.query_tiles(0, 16, 16, block_k=8), kernel.query_tiles(16, 5, 24, block_k=8)]
+    assert [np.asarray(v).tolist() for _, v in tiles] == [[1, 2], [3, 0, 0]]
+    assert counted == [4 * rows * 8 * int(np.sum(v)) for rows, v in tiles] == [4 * 8 * 8 * 3, 4 * 8 * 8 * 3]
