@@ -32,8 +32,8 @@ counters are monotonic:
   pairs the router chose anywhere) and ``dsa_keys_selected`` /
   ``dsa_keys_scored`` (keys the queries attended to / keys their indexer
   scored), the last four counted on the device by the programs themselves,
-  as each further architecture's ``STATS`` are (``xdec_*``, ``swa_*``,
-  ``ssm_*``; ``moe_rows_zero``, the pairs whose expert computes nothing, and
+  as each further architecture's ``STATS`` are (``xdec_*``, ``swa_*``;
+  ``moe_rows_zero``, the pairs whose expert computes nothing, and
   ``mla_keys_visible`` / ``mla_keys_multiplied``, the query-key pairs that
   count / those the attention multiplied: in a prompt, the fused kernel's
   query tiles against the key blocks each visits);
@@ -43,9 +43,16 @@ counters are monotonic:
   tell it whether they differ, so ``consolidate`` hashed the pair).
 
 :func:`snapshot` is the one door through which the benchmark reads the
-program: the counters above and the span recorder's stage totals
-(``internals/tracing.stage_totals``) as flat keys ``span_ns.<stage>`` /
-``span_count.<stage>``.  Every key is exported on ``/metrics`` as
+program: the counters above, the span recorder's stage totals
+(``internals/tracing.stage_totals`` / ``stage_idle``) as flat keys
+``span_ns.<stage>`` / ``span_count.<stage>`` / ``span_idle_ns.<stage>``
+(the chip account's idle time inside the stage's spans), and, present
+from the process's start and zero under ``PATHWAY_TRACE=0``, the chip
+account's ``chip_idle_ns`` (time with no device work of the program's
+outstanding) over ``chip_watch_ns`` (time since the account started)
+and the stall watchdog's ``stall_count`` / ``stall_ns`` /
+``stall_cpu_ns`` / ``stall_steal_ns`` (``internals/tracing.py`` has
+both).  Every key is exported on ``/metrics`` as
 ``pathway_tpu_<key>_total`` (the stage totals with a ``stage`` label)
 and the counters are joined against the static prediction on ``/status``.
 Importing this module never imports jax; ``install()`` is called lazily
@@ -113,8 +120,6 @@ _counters: dict[str, int] = {
     "xdec_tokens_seen": 0,
     "swa_keys_in_window": 0,
     "swa_keys_multiplied": 0,
-    "ssm_tokens_scanned": 0,
-    "ssm_tokens_padded": 0,
     "moe_rows_zero": 0,
     "mla_keys_visible": 0,
     "mla_keys_multiplied": 0,
@@ -191,9 +196,18 @@ def snapshot() -> dict[str, int]:
     with _lock:
         out = dict(_counters)
     out["listener_installed"] = 1 if _installed else 0
+    if tracing.enabled():
+        now = tracing.now_ns()
+        out["chip_idle_ns"] = tracing.chip.idle_at(now)
+        out["chip_watch_ns"] = now - tracing.chip.t_start
+        out.update(tracing.stall_totals())
+    else:
+        out.update(chip_idle_ns=0, chip_watch_ns=0, stall_count=0, stall_ns=0, stall_cpu_ns=0, stall_steal_ns=0)
+    idle = tracing.stage_idle()
     for stage, (count, total_ns) in tracing.stage_totals().items():
         out[f"span_ns.{stage}"] = total_ns
         out[f"span_count.{stage}"] = count
+        out[f"span_idle_ns.{stage}"] = idle.get(stage, 0)
     return out
 
 

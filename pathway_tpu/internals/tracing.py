@@ -4,9 +4,10 @@ Every span is one tuple appended into a **per-thread fixed-size ring
 buffer** — the record path is a tuple build plus a list-slot assignment
 and an index increment, with **no locks, no allocation beyond the tuple,
 no syscalls** (``scripts/check_locks.py`` lints this file; the LK007
-whole-repo lock graph must stay cycle-free and the only lock here is the
-leaf-level ring registry mutex, taken once per thread at ring creation
-and on the dump path — never per record).
+whole-repo lock graph must stay cycle-free and the locks here are two
+leaves: the ring registry mutex, taken once per thread at ring creation
+and on the dump path, and the chip account's, taken once a device
+enqueue and once a wait — never per record).
 
 Record layout (one tuple per span)::
 
@@ -31,11 +32,37 @@ with no translation (a TraceMe costs 0.4 us outside a session; jax is
 looked up in ``sys.modules``, never imported from here).
 
 **Stage totals.**  Every recorded span also adds its duration to a
-per-stage ``[count, total_ns]`` on the recording thread's ring (no lock,
-no further clock read); :func:`stage_totals` sums them over rings.  They
-never wrap with the ring, grow for the life of the process and stay
-zero under ``PATHWAY_TRACE=0``: what ``device_counters.snapshot()``
-carries to the benchmark as ``span_ns.<stage>`` / ``span_count.<stage>``.
+per-stage ``[count, total_ns, idle_ns]`` on the recording thread's ring
+(no lock, no further clock read); :func:`stage_totals` and
+:func:`stage_idle` sum them over rings.  They never wrap with the ring,
+grow for the life of the process and stay zero under
+``PATHWAY_TRACE=0``: what ``device_counters.snapshot()`` carries to the
+benchmark as ``span_ns.<stage>`` / ``span_count.<stage>`` /
+``span_idle_ns.<stage>``.
+
+**The chip account** (:class:`ChipAccount`, one a process: :data:`chip`).
+The program cannot see the chip's timeline, but it knows where it hands
+the chip work it will wait on and where the wait returns: each such
+enqueue takes a ticket (``chip.ticket()``) and each wait that returns
+marks its ticket collected (``chip.collected(ticket)``).  A device runs its
+programs in the order they were enqueued, so collecting ticket *t* says
+every ticket up to *t* is done, and the chip has work while the highest
+ticket taken is above the highest collected.  The account keeps the
+cumulative time with no ticket outstanding and a ring of its last
+transitions, so every span adds to its stage the idle time between its
+two ends (``idle_ns``: the chip waited on the host while the stage ran).
+A ``span`` block reads the account's current state at enter and exit, an
+O(1) read; a span recorded after the fact with a past ``t0``
+(``record_span``) looks its ends up in the transition ring.
+
+**The stall watchdog** (:class:`StallWatchdog`, one daemon thread a
+process, started with the first ring): it sleeps a 20 ms tick and, when
+it wakes more than 100 ms late, records a ``process_stall`` span from the
+wake it expected to the one it got, with the process's CPU time, the
+machine's steal time and the major faults over it and the stage each
+other thread had open (``ring.open``).  A stall with ``cpu_ms`` about its
+length is a thread holding the GIL computing; with neither CPU nor steal,
+the process was stopped or paged.
 
 Sampling: the ring is **always on** (that is what makes it a flight
 recorder — the last ``ring_size`` spans per thread are always there for
@@ -83,6 +110,8 @@ import traceback
 from typing import Any, Callable, Iterator
 
 __all__ = [
+    "ChipAccount",
+    "StallWatchdog",
     "TraceContext",
     "chrome_events",
     "configure",
@@ -90,6 +119,7 @@ __all__ = [
     "current_rank",
     "dump",
     "dump_stacks",
+    "chip",
     "enabled",
     "finish_request",
     "flush",
@@ -103,7 +133,9 @@ __all__ = [
     "set_ambient",
     "set_rank",
     "span",
+    "stage_idle",
     "stage_totals",
+    "stall_totals",
     "use",
 ]
 
@@ -167,7 +199,7 @@ _atexit_installed = False
 class _Ring:
     """One thread's span ring: preallocated slots, lock-free append."""
 
-    __slots__ = ("buf", "idx", "cap", "thread_name", "id_next", "totals")
+    __slots__ = ("buf", "idx", "cap", "thread_name", "id_next", "totals", "open")
 
     def __init__(self, cap: int, thread_name: str, id_seed: int):
         self.cap = cap
@@ -175,8 +207,10 @@ class _Ring:
         self.idx = 0
         self.thread_name = thread_name
         self.id_next = id_seed
-        #: stage -> [count, total_ns]; written by the owning thread only
+        #: stage -> [count, total_ns, idle_ns]; written by the owning thread only
         self.totals: dict[str, list[int]] = {}
+        #: the innermost ``span`` block open on the owning thread (the watchdog reads it)
+        self.open: str | None = None
 
     def snapshot(self) -> list[tuple]:
         """Copy the live records in append order (dump path; the copy is
@@ -200,6 +234,136 @@ class _Tls(threading.local):
 _tls = _Tls()
 
 
+# -------------------------------------------------------- the chip account
+
+#: transitions the chip account keeps for looking up past idle time (two a
+#: request in the retrieve cell: about 30 s of it)
+_CHIP_RING = 4096
+
+
+class ChipAccount:
+    """When the chip has work of the program's, from the host's side.
+
+    The ticket sites are the enqueues the program later waits on:
+    ``JittedEncoder._dispatch`` (when a readback follows),
+    ``ShardedKnnIndex.dispatch`` and each prefill chunk and decode step of
+    ``JittedDecoder.generate``; the waits that collect are
+    ``JittedEncoder._readback``, ``ShardedKnnIndex.collect`` and the two
+    blocks of ``generate``.  Fire-and-forget enqueues take no ticket and
+    count as idle: the slab's scatters (``slab_scatter``), the encoder's
+    dispatches under ``encode_into``, uploads and eager ops; the error is
+    at most their device time, a few ms an epoch.  A readback that finds
+    the work long done counts the chip busy until it returns.  A ticket
+    whose wait never comes (an exception in between) is cleared by the
+    next collect of a later one.
+
+    ``state`` is one immutable tuple ``(t_ns, idle_ns, idle)``: the instant
+    of the last transition, the idle time accumulated before it, and
+    whether the chip has been idle since; readers take it in one load and
+    need no lock.  ``ring`` holds the last :data:`_CHIP_RING` states."""
+
+    __slots__ = ("clock", "lock", "enq", "col", "state", "ring", "idx", "t_start")
+
+    def __init__(self, clock: Callable[[], int] | None = None):
+        self.clock = clock or time.monotonic_ns
+        #: leaf lock: ticket and collect only, nothing acquired under it
+        self.lock = threading.Lock()
+        self.restart()
+
+    def restart(self) -> None:
+        """Start the account anew, idle from now (process start; tests)."""
+        with self.lock:
+            t = self.clock()
+            self.t_start = t
+            self.enq = self.col = 0
+            self.state = (t, 0, True)
+            self.ring: list[Any] = [None] * _CHIP_RING
+            self.ring[0] = self.state
+            self.idx = 1
+
+    def _turn(self, idle: bool) -> None:
+        t_last, base, was_idle = self.state
+        if was_idle == idle:
+            return
+        t = max(self.clock(), t_last)
+        s = (t, base + (t - t_last if was_idle else 0), idle)
+        self.ring[self.idx % _CHIP_RING] = s
+        self.state = s
+        self.idx += 1
+
+    def ticket(self) -> int:
+        """Device work was just enqueued that a later wait will collect."""
+        if not _cfg.on:
+            return 0
+        with self.lock:
+            self.enq += 1
+            self._turn(False)
+            return self.enq
+
+    def collected(self, ticket: int) -> None:
+        """A wait for ``ticket`` returned: it and every earlier one are done."""
+        if ticket <= self.col:
+            return
+        with self.lock:
+            if ticket > self.col:
+                self.col = min(ticket, self.enq)
+                if self.col == self.enq:
+                    self._turn(True)
+
+    def outstanding(self) -> int:
+        return self.enq - self.col
+
+    def idle_at(self, t: int) -> int:
+        """Cumulative idle ns up to instant ``t`` (an instant before the
+        ring's oldest transition reads the oldest one's total)."""
+        s = self.state
+        if t < s[0]:  # the last transition at or before t, by bisection over the ring
+            ring, hi = self.ring, self.idx - 1
+            lo = max(self.idx - _CHIP_RING, 0)
+            s = ring[lo % _CHIP_RING]
+            if t < s[0]:
+                return s[1]
+            while lo < hi:
+                mid = (lo + hi + 1) // 2
+                if ring[mid % _CHIP_RING][0] <= t:
+                    lo = mid
+                else:
+                    hi = mid - 1
+            s = ring[lo % _CHIP_RING]
+        return s[1] + (t - s[0] if s[2] else 0)
+
+    def idle_intervals(self, since_ns: int | None = None) -> list[tuple[int, int]]:
+        """The idle ``(t0_ns, t1_ns)`` intervals the ring still holds, the
+        open one ending now."""
+        states = list(self.ring)
+        i = self.idx
+        if i > _CHIP_RING:
+            head = i % _CHIP_RING
+            states = states[head:] + states[:head]
+        states = [st for st in states if st is not None]
+        now = self.clock()
+        out = []
+        for st, nxt in zip(states, states[1:] + [None]):
+            if st[2]:
+                t1 = nxt[0] if nxt is not None else now
+                if since_ns is None or t1 >= since_ns:
+                    out.append((st[0], t1))
+        return out
+
+
+#: the process's chip account
+chip = ChipAccount()
+
+
+#: the watchdog's totals (``device_counters.snapshot()``'s ``stall_*``)
+_stalls: dict[str, int] = {"stall_count": 0, "stall_ns": 0, "stall_cpu_ns": 0, "stall_steal_ns": 0}
+
+
+def stall_totals() -> dict[str, int]:
+    """The stall watchdog's totals since the process started."""
+    return dict(_stalls)
+
+
 def _make_ring() -> _Ring:
     t = threading.current_thread()
     # seeded per ring so span ids are unique across threads/processes
@@ -209,6 +373,8 @@ def _make_ring() -> _Ring:
     with _registry_mutex:
         _rings.append(ring)
     _tls.ring = ring
+    if _watchdog is None and _cfg.on:
+        _start_watchdog()
     global _atexit_installed
     if _cfg.spool_dir and not _atexit_installed:
         _atexit_installed = True
@@ -285,6 +451,9 @@ def reset() -> None:
         _kept[i] = 0
     _kept_idx = 0
     _cfg.reload()
+    chip.restart()
+    for key in _stalls:
+        _stalls[key] = 0
 
 
 # ------------------------------------------------------------ record path
@@ -370,12 +539,18 @@ def record_span(
         rec = (0, span_id, 0, stage, _rank, t0_ns, t1_ns, False, args)
     ring.buf[ring.idx % ring.cap] = rec
     ring.idx += 1
+    s = chip.state
+    if t0_ns >= s[0]:  # no transition since the span began: its idle is all or nothing
+        idle = t1_ns - t0_ns if s[2] else 0
+    else:
+        idle = chip.idle_at(t1_ns) - chip.idle_at(t0_ns)
     tot = ring.totals.get(stage)
     if tot is None:
-        ring.totals[stage] = [1, t1_ns - t0_ns]
+        ring.totals[stage] = [1, t1_ns - t0_ns, idle]
     else:
         tot[0] += 1
         tot[1] += t1_ns - t0_ns
+        tot[2] += idle
     return span_id
 
 
@@ -398,17 +573,20 @@ def record_spans(
     trace_id, parent, sampled = ctx.trace_id, ctx.span_id, ctx.sampled
     rank = _rank
     totals = ring.totals
+    idle_at = chip.idle_at
     for stage, t0_ns, t1_ns, args in spans:
         nid += 1
         buf[i % cap] = (trace_id, nid, parent, stage, rank,
                         t0_ns, t1_ns, sampled, args)
         i += 1
+        idle = idle_at(t1_ns) - idle_at(t0_ns)
         tot = totals.get(stage)
         if tot is None:
-            totals[stage] = [1, t1_ns - t0_ns]
+            totals[stage] = [1, t1_ns - t0_ns, idle]
         else:
             tot[0] += 1
             tot[1] += t1_ns - t0_ns
+            tot[2] += idle
     ring.id_next = nid
     ring.idx = i
 
@@ -436,7 +614,7 @@ class _Span:
     record_span reads), so entering a span allocates no extra object."""
 
     __slots__ = ("stage", "args", "parent", "t0_ns", "prev",
-                 "trace_id", "span_id", "sampled", "ann")
+                 "trace_id", "span_id", "sampled", "ann", "chip0", "open0")
 
     def __init__(self, stage: str, args: dict | None, ctx: TraceContext | None):
         self.stage = stage
@@ -454,12 +632,14 @@ class _Span:
             return self
         ctx = self.parent if self.parent is not None else self.prev
         self.parent = ctx
+        ring = tls.ring
+        if ring is None:
+            ring = _make_ring()
+        self.open0 = ring.open
+        ring.open = self.stage
         if ctx is not None:
             # pre-allocate this span's id so children recorded inside the
             # block parent onto it (the record at exit reuses the id)
-            ring = tls.ring
-            if ring is None:
-                ring = _make_ring()
             ring.id_next += 1
             self.trace_id = ctx.trace_id
             self.span_id = ring.id_next
@@ -471,6 +651,7 @@ class _Span:
             self.ann.__enter__()
         else:
             self.ann = None
+        self.chip0 = chip.state
         self.t0_ns = _monotonic_ns()
         return self
 
@@ -480,11 +661,19 @@ class _Span:
         if not _cfg.on or self.t0_ns == 0:
             return
         t1 = _monotonic_ns()
+        s1 = chip.state
         if self.ann is not None:
             self.ann.__exit__(et, ev, tb)
         ring = tls.ring
         if ring is None:
             ring = _make_ring()
+        ring.open = self.open0
+        t0, s0 = self.t0_ns, self.chip0
+        if s1 is s0:  # no transition inside the block
+            idle = t1 - t0 if s0[2] else 0
+        else:  # the idle clock at each end, from the state read there
+            idle = (s1[1] + (t1 - s1[0] if s1[2] and t1 > s1[0] else 0)
+                    - s0[1] - (t0 - s0[0] if s0[2] else 0))
         parent = self.parent
         if parent is not None:
             rec = (self.trace_id, self.span_id, parent.span_id,
@@ -498,10 +687,11 @@ class _Span:
         ring.idx += 1
         tot = ring.totals.get(self.stage)
         if tot is None:
-            ring.totals[self.stage] = [1, t1 - self.t0_ns]
+            ring.totals[self.stage] = [1, t1 - t0, idle]
         else:
             tot[0] += 1
-            tot[1] += t1 - self.t0_ns
+            tot[1] += t1 - t0
+            tot[2] += idle
 
 
 def span(stage: str, args: dict | None = None,
@@ -547,9 +737,22 @@ def stage_totals() -> dict[str, tuple[int, int]]:
     out: dict[str, tuple[int, int]] = {}
     for ring in rings:
         # one C-level copy under the GIL: the owner may add a stage meanwhile
-        for stage, (count, total_ns) in list(ring.totals.items()):
+        for stage, (count, total_ns, _idle) in list(ring.totals.items()):
             c, t = out.get(stage, (0, 0))
             out[stage] = (c + count, t + total_ns)
+    return out
+
+
+def stage_idle() -> dict[str, int]:
+    """``{stage: idle_ns}``: the chip account's idle time inside every span
+    of each stage since the process started, summed over threads (the
+    time the chip had no work of the program's while the stage ran)."""
+    with _registry_mutex:
+        rings = list(_rings)
+    out: dict[str, int] = {}
+    for ring in rings:
+        for stage, (_count, _total_ns, idle_ns) in list(ring.totals.items()):
+            out[stage] = out.get(stage, 0) + idle_ns
     return out
 
 
@@ -566,7 +769,11 @@ def chrome_events(
 
     Export filter: spans of sampled traces, spans of tail-kept traces,
     and context-free spans (``trace_id == 0`` — flight-recorder noise
-    floor) — or everything with ``all_spans=True``."""
+    floor, and the watchdog's ``process_stall``) — or everything with
+    ``all_spans=True``.  Once the process has handed the chip work, a
+    track ``chip`` holds the chip account's idle intervals
+    (``chip_idle``) that its transition ring still has, on the same clock
+    as the stages."""
     kept = set(_kept) - {0}
     events: list[dict] = []
     with _registry_mutex:
@@ -592,6 +799,12 @@ def chrome_events(
                 "ts": t0 / 1e3,
                 "dur": max(t1 - t0, 0) / 1e3,
                 "args": ev_args,
+            })
+    if chip.enq:
+        for t0, t1 in chip.idle_intervals(since_ns):
+            events.append({
+                "ph": "X", "name": "chip_idle", "cat": "chip", "pid": _rank,
+                "tid": "chip", "ts": t0 / 1e3, "dur": (t1 - t0) / 1e3, "args": {},
             })
     events.sort(key=lambda e: e["ts"])
     return events
@@ -665,6 +878,8 @@ def merge_trace_dir(spool: str, out_path: str | None = None) -> str | None:
             sid = ev.get("args", {}).get("span_id")
             if sid:
                 by_key[(ev.get("pid"), sid)] = ev
+            elif ev.get("tid") == "chip":  # one idle interval, whichever flush saw it last
+                by_key[(ev.get("pid"), "chip", ev.get("ts"))] = ev
             else:
                 loose.append(ev)
     events = list(by_key.values()) + loose
@@ -719,3 +934,174 @@ def install_sigusr2() -> bool:
         return True
     except (ValueError, OSError, AttributeError):
         return False  # not the main thread, or no SIGUSR2 (non-POSIX)
+
+
+# ------------------------------------------------------- stall watchdog
+
+#: the watchdog's tick, and how much later than due a wake is a stall
+STALL_TICK_NS = 20_000_000
+STALL_LATE_NS = 100_000_000
+#: faulthandler dumps every stack when the watchdog has not re-armed it for
+#: this long; it re-arms at most every ``_REARM_NS`` (each arm starts an OS
+#: thread)
+_STALL_DUMP_S = 0.2
+_REARM_NS = 100_000_000
+#: /proc/stat is read this often between stalls (a read formats every CPU)
+_STEAL_EVERY_NS = 200_000_000
+
+try:
+    _CLK_TCK = os.sysconf("SC_CLK_TCK")
+except (AttributeError, ValueError, OSError):
+    _CLK_TCK = 100
+
+
+def _read_steal_ns() -> int | None:
+    """The machine's steal time from ``/proc/stat``, in ns a CPU (a VM
+    paused whole reads about the pause); None where there is none."""
+    try:
+        with open("/proc/stat", "rb") as f:
+            lines = f.read().split(b"\n")
+        fields = lines[0].split()
+        ncpu = sum(1 for line in lines[1:] if line.startswith(b"cpu")) or 1
+        return int(fields[8]) * (1_000_000_000 // _CLK_TCK) // ncpu
+    except (OSError, IndexError, ValueError):
+        return None
+
+
+def _read_majflt() -> int:
+    try:
+        import resource
+
+        return resource.getrusage(resource.RUSAGE_SELF).ru_majflt
+    except (ImportError, OSError):
+        return 0
+
+
+class StallWatchdog:
+    """Records whole-process stalls: a thread that sleeps a tick of
+    :data:`STALL_TICK_NS` and, when it wakes more than
+    :data:`STALL_LATE_NS` after it was due, records a ``process_stall``
+    span from the due wake to the actual one and adds to ``_stalls``.
+    Its args: ``cpu_ms``, the process's CPU time over the stall and the
+    tick before it (about the stall's length: a thread held the GIL
+    computing; the runtime's own threads add theirs); ``steal_ms``, the
+    machine's steal time a CPU since a reading at most 200 ms before the
+    stall (a paused VM); ``majflt``, major faults over it; ``open``, the
+    innermost stage each other thread had open at the wake.
+
+    Where ``PATHWAY_TRACE_DIR`` is set, the thread also keeps
+    ``faulthandler.dump_traceback_later`` armed, so a stall of 100 ms
+    more writes every thread's stack into the spool from a C thread while
+    it lasts (the one way to see a GIL holder), and flushes the spool
+    after it.  Without a spool it never touches ``faulthandler``.  The
+    clocks are injectable: :meth:`tick` is the whole decision."""
+
+    def __init__(self, clock: Callable[[], int] | None = None,
+                 cpu: Callable[[], int] | None = None,
+                 steal: Callable[[], "int | None"] | None = None,
+                 majflt: Callable[[], int] | None = None,
+                 totals: dict[str, int] | None = None):
+        self.clock = clock or time.monotonic_ns
+        self.cpu = cpu or time.process_time_ns
+        self.steal = steal or _read_steal_ns
+        self.majflt = majflt or _read_majflt
+        self.totals = _stalls if totals is None else totals
+        now = self.clock()
+        self.last = (now, self.cpu(), self.majflt())
+        self.steal_last = (now, self.steal())
+        self.armed_ns = 0
+        self.stacks: Any = None
+
+    def tick(self, now: int) -> int:
+        """One wake at ``now``: record a stall if it is one; returns the
+        stall's ns (0 for a wake on time)."""
+        t_prev, cpu_prev, flt_prev = self.last
+        late = now - (t_prev + STALL_TICK_NS)
+        cpu, flt = self.cpu(), self.majflt()
+        stalled = 0
+        if late > STALL_LATE_NS and _cfg.on:
+            stalled = late
+            steal_prev = self.steal_last[1]
+            steal_now = self.steal()
+            steal = steal_now - steal_prev if steal_now is not None and steal_prev is not None else 0
+            self.steal_last = (now, steal_now)
+            totals = self.totals
+            totals["stall_count"] += 1
+            totals["stall_ns"] += late
+            totals["stall_cpu_ns"] += cpu - cpu_prev
+            totals["stall_steal_ns"] += steal
+            record_span("process_stall", now - late, now, ctx=None, args={
+                "cpu_ms": (cpu - cpu_prev) / 1e6,
+                "steal_ms": steal / 1e6,
+                "majflt": flt - flt_prev,
+                "open": self._open_elsewhere(),
+            })
+        elif now - self.steal_last[0] >= _STEAL_EVERY_NS:
+            self.steal_last = (now, self.steal())
+        self.last = (now, cpu, flt)
+        return stalled
+
+    @staticmethod
+    def _open_elsewhere() -> dict[str, str]:
+        me = _tls.ring
+        with _registry_mutex:
+            rings = list(_rings)
+        return {r.thread_name: r.open for r in rings if r is not me and r.open is not None}
+
+    def _arm(self, now: int) -> None:
+        import faulthandler
+
+        spool = _cfg.spool_dir
+        if not spool:
+            if self.stacks is not None:
+                faulthandler.cancel_dump_traceback_later()
+                self.stacks.close()
+                self.stacks = None
+            return
+        if now - self.armed_ns < _REARM_NS:
+            return
+        if self.stacks is None:
+            os.makedirs(spool, exist_ok=True)
+            self.stacks = open(os.path.join(spool, f"stacks-r{_rank}-p{os.getpid()}.txt"), "a")
+        faulthandler.dump_traceback_later(_STALL_DUMP_S, repeat=False, file=self.stacks)
+        self.armed_ns = now
+
+    def run(self) -> None:
+        while True:
+            time.sleep(STALL_TICK_NS / 1e9)
+            now = self.clock()
+            try:
+                if self.tick(now) and _cfg.spool_dir:
+                    flush("stall")
+                    t = self.clock()  # the flush's own time is no stall
+                    self.last = (t, self.cpu(), self.majflt())
+                if _cfg.spool_dir or self.stacks is not None:
+                    self._arm(now)
+            except Exception:  # noqa: BLE001 — the watchdog must outlive a bad reading
+                pass
+
+
+_watchdog: StallWatchdog | None = None
+
+
+def _start_watchdog() -> None:
+    global _watchdog
+    with _registry_mutex:
+        if _watchdog is not None:
+            return
+        _watchdog = wd = StallWatchdog()
+    threading.Thread(target=wd.run, name="pathway-stall-watchdog", daemon=True).start()
+
+
+def _after_fork_in_child() -> None:
+    """A forked child has neither the watchdog thread nor the parent's
+    chip: start both anew (the locks may have been held at the fork)."""
+    global _watchdog, _registry_mutex
+    _watchdog = None
+    _registry_mutex = threading.Lock()
+    chip.lock = threading.Lock()
+    chip.restart()
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_after_fork_in_child)

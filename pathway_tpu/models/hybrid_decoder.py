@@ -61,8 +61,8 @@ __all__ = ["HybridDecoderConfig", "PHI4_MINI_FLASH", "init_cache", "prefill", "d
 
 #: what both programs count, in the order of the vector they return: rows sent
 #: through the cross-decoder / tokens seen; keys inside a window query's window /
-#: keys its layer multiplied; tokens that advanced a recurrent state / tokens scanned
-STATS = ("xdec_tokens_run", "xdec_tokens_seen", "swa_keys_in_window", "swa_keys_multiplied", "ssm_tokens_scanned", "ssm_tokens_padded")
+#: keys its layer multiplied
+STATS = ("xdec_tokens_run", "xdec_tokens_seen", "swa_keys_in_window", "swa_keys_multiplied")
 
 #: what one more prefill dispatch costs beside its tokens, in tokens: the
 #: self-decoder's weights are read again (3.9 GB; on a v5e 5.4 ms, where 512
@@ -468,7 +468,6 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Hybri
     stats = jnp.stack([
         jnp.asarray(last, jnp.int32), length,
         n_pairs * in_window, n_pairs * C * 2 * W,
-        (n_pairs + 1) * length, (n_pairs + 1) * C,
     ]).astype(jnp.int32)
     return logits, {"ssm": ssm, "conv": conv, "ring_k": ring_k, "ring_v": ring_v, "k": k_all, "v": v_all}, stats
 
@@ -511,6 +510,6 @@ def decode_step(params, ids, cache, slots, lengths, *, config: HybridDecoderConf
     logits = _cross_decoder(params, h, m, k_all, v_all, slots, lengths, cfg)
     in_window = sum(jnp.minimum(n + 1, W) for n in lengths)
     stats = jnp.stack([
-        jnp.int32(B), jnp.int32(B), n_pairs * in_window, jnp.int32(n_pairs * B * W), jnp.int32((n_pairs + 1) * B), jnp.int32((n_pairs + 1) * B),
+        jnp.int32(B), jnp.int32(B), n_pairs * in_window, jnp.int32(n_pairs * B * W),
     ]).astype(jnp.int32)
     return logits, {"ssm": ssm, "conv": conv, "ring_k": ring_k, "ring_v": ring_v, "k": k_all, "v": v_all}, stats

@@ -278,13 +278,15 @@ class JittedEncoder:
         first: np.ndarray | None = None,
         start_host_copy: bool = True,
     ):
-        """Enqueue one padded chunk; returns (device_out, n_real_outputs).
-        With ``first`` the rows are packed (:meth:`_pack`): ``mask`` numbers
-        each row's texts and the output has a row per text, not per row.
-        The device->host copy is started immediately (non-blocking), so
-        the readback of chunk i overlaps the tokenize+compute of chunk
-        i+1.  ``start_host_copy=False`` for consumers that keep the
-        output on device (``encode_into``)."""
+        """Enqueue one padded chunk; returns (device_out, n_real_outputs,
+        chip ticket).  With ``first`` the rows are packed (:meth:`_pack`):
+        ``mask`` numbers each row's texts and the output has a row per
+        text, not per row.  The device->host copy is started immediately
+        (non-blocking), so the readback of chunk i overlaps the
+        tokenize+compute of chunk i+1, and the dispatch takes a ticket of
+        the chip account that :meth:`_readback` collects.
+        ``start_host_copy=False`` for consumers that keep the output on
+        device (``encode_into``): nothing waits on it, so no ticket (0)."""
         with _tracing.span("encoder_dispatch") as sp:
             tokens = np.count_nonzero(mask)
             ids, mask, tps, rows = self._pad_batch(ids, mask, tps)
@@ -320,18 +322,21 @@ class JittedEncoder:
                 args.append(jax.device_put(first, self._out_sharding))
             _devctr.record_h2d(h2d)
             out = self._apply(self.params, *args)
+            ticket = 0
             if start_host_copy:
+                ticket = _tracing.chip.ticket()
                 out.copy_to_host_async()
-        return out, n
+        return out, n, ticket
 
     def _run(self, ids: np.ndarray, mask: np.ndarray, tps: np.ndarray) -> np.ndarray:
-        out, n = self._dispatch(ids, mask, tps)
-        return self._readback(out)[:n]
+        out, n, ticket = self._dispatch(ids, mask, tps)
+        return self._readback(out, ticket)[:n]
 
     @staticmethod
-    def _readback(out: Any) -> np.ndarray:
+    def _readback(out: Any, ticket: int) -> np.ndarray:
         with _tracing.span("encoder_readback"):
             host = np.asarray(out)
+            _tracing.chip.collected(ticket)
         _devctr.record_d2h(host.nbytes)
         return host
 
@@ -428,8 +433,8 @@ class JittedEncoder:
 
         def collect():
             nonlocal ordered
-            out, n, at = inflight.popleft()
-            host = self._readback(out)[:n]
+            out, n, ticket, at = inflight.popleft()
+            host = self._readback(out, ticket)[:n]
             if ordered is None:
                 ordered = np.empty((len(texts),) + host.shape[1:], host.dtype)
             ordered[at] = host
@@ -470,7 +475,7 @@ class JittedEncoder:
             return 0
         inflight: deque = deque()
         for arrays, at in self._chunks(texts, None):
-            out, n = self._dispatch(*arrays, start_host_copy=False)
+            out, n, _ = self._dispatch(*arrays, start_host_copy=False)
             inflight.append((out, n, [keys[a] for a in at]))
             if len(inflight) >= self.pipeline_depth:
                 out, n, kchunk = inflight.popleft()

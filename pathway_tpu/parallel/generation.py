@@ -147,19 +147,24 @@ class JittedDecoder:
                     token, logits, self.cache, st = self._prefill(
                         self.params, ids, self.cache, np.int32(slot), np.int32(start), np.int32(real), np.bool_(start + real == prompt.size)
                     )
+                    prefilled = _tracing.chip.ticket()
                     stats.append(st)
                 rows, tokens = [logits], [token]
+                decoded = prefilled
                 for i in range(steps):
                     token, logits, self.cache, st = self._decode(
                         self.params, token, self.cache, slot_arr, np.asarray([prompt.size + i], np.int32)
                     )
+                    decoded = _tracing.chip.ticket()
                     rows.append(logits)
                     tokens.append(token)
                     stats.append(st)
             rows[0].block_until_ready()
+            _tracing.chip.collected(prefilled)
         with _tracing.span("generate_decode") as sp:
             sp.args = {"steps": steps}
             rows, tokens, stats = jax.device_get((rows, tokens, stats))
+            _tracing.chip.collected(decoded)
         logits = np.stack(rows)
         _devctr.record_d2h(logits.nbytes)
         counted = np.sum(np.stack(stats).astype(np.int64), axis=0)

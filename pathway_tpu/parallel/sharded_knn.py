@@ -389,7 +389,7 @@ class ShardedKnnIndex:
         queries = np.atleast_2d(np.asarray(queries, np.float32))
         nq = queries.shape[0]
         if nq == 0 or not self._slot_of:
-            return (None, nq, k, self._version)
+            return (None, nq, k, self._version, 0)
         # k is baked into the compiled program, and callers' k moves with
         # the corpus (the segment layer over-fetches by its mask size, the
         # adapter clamps to the live key count): bucket it like every
@@ -401,6 +401,7 @@ class ShardedKnnIndex:
             search_dispatches=1, search_queries=nq, search_queries_padded=qb.shape[0]
         )
         out = self._search_jit(k_eff)(jnp.asarray(qb), self._vectors, self._valid)
+        ticket = _tracing.chip.ticket()  # collect() waits on it
         # start the device->host copy NOW, without blocking: the result
         # transfer then overlaps later dispatches, so a serving loop with
         # several handles in flight waits for the device once per
@@ -408,7 +409,7 @@ class ShardedKnnIndex:
         for a in out:
             a.copy_to_host_async()
         self._inflight += 1
-        return (out, nq, k, self._version)
+        return (out, nq, k, self._version, ticket)
 
     def collect(self, handle) -> list[list[tuple[Any, float]]]:
         """Resolve a :meth:`dispatch` handle to [[(key, score), ...], ...].
@@ -421,7 +422,7 @@ class ShardedKnnIndex:
         replaces the slot->key map wholesale, so the generation recorded
         in the handle gates the decode and a pre-restore handle raises
         instead of resolving to arbitrary wrong keys."""
-        out, nq, k, version = handle
+        out, nq, k, version, ticket = handle
         if out is None:
             return [[] for _ in range(nq)]
         if version < self._reset_version:
@@ -438,6 +439,7 @@ class ShardedKnnIndex:
         # host<->device round trip; they dominate single-query latency)
         with _tracing.span("search_readback"):
             vals, idx = jax.device_get(out)
+            _tracing.chip.collected(ticket)
         _devctr.record_d2h(vals.nbytes + idx.nbytes)
         vals = vals[:nq]
         idx = idx[:nq]

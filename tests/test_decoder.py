@@ -251,7 +251,7 @@ def served(request, model):
     def counted(prompt, padded, steps):
         tokens = prompt + steps
         return {
-            "xdec_tokens_run": 1 + steps, "xdec_tokens_seen": tokens, "ssm_tokens_scanned": 3 * tokens, "ssm_tokens_padded": 3 * (padded + steps),
+            "xdec_tokens_run": 1 + steps, "xdec_tokens_seen": tokens,
             "swa_keys_in_window": 2 * sum(min(t + 1, 8) for t in range(tokens)), "swa_keys_multiplied": 2 * (padded * 16 + steps * 8),
         }
 

@@ -331,3 +331,106 @@ def test_sigusr2_dumps_stacks_and_flushes_flight_recorder(tmp_path, capfd):
         assert any("sigusr2" in f for f in dumps)
     finally:
         tracing.configure(PATHWAY_TRACE_DIR=None)
+
+
+# ------------------------------------ the chip account and the stall watchdog
+
+_ALWAYS = ("chip_idle_ns", "chip_watch_ns", "stall_count", "stall_ns", "stall_cpu_ns", "stall_steal_ns")
+
+
+@pytest.fixture
+def recorder():
+    from pathway_tpu.internals import tracing
+
+    tracing.configure(PATHWAY_TRACE="1", PATHWAY_TRACE_SAMPLE="1.0")
+    tracing.reset()
+    yield tracing
+    tracing.configure(PATHWAY_TRACE=None, PATHWAY_TRACE_SAMPLE=None)
+    tracing.reset()
+
+
+def test_snapshot_and_metrics_carry_the_chip_account_and_the_stalls(recorder):
+    """The one door carries the account, the watchdog's totals and each
+    stage's idle time from the process's start; /metrics the same."""
+    from pathway_tpu.engine.scheduler import Scheduler
+    from pathway_tpu.internals import device_counters
+    from pathway_tpu.internals.monitoring_server import _metrics_text
+    from pathway_tpu.internals.parse_graph import G
+
+    first = device_counters.snapshot()
+    assert all(k in first for k in _ALWAYS)  # before any stage or stall
+    with recorder.span("host_work"):
+        time.sleep(0.01)  # no ticket outstanding: the chip waits on this stage
+    ticket = recorder.chip.ticket()
+    with recorder.span("device_wait"):
+        time.sleep(0.01)
+        recorder.chip.collected(ticket)
+    snap = device_counters.snapshot()
+    assert snap["chip_watch_ns"] > first["chip_watch_ns"] and snap["chip_idle_ns"] > first["chip_idle_ns"]
+    assert snap["chip_idle_ns"] <= snap["chip_watch_ns"]
+    assert snap["span_idle_ns.host_work"] == snap["span_ns.host_work"] >= 10_000_000
+    # busy until the collect, idle only for the exit's few microseconds after it
+    assert snap["span_idle_ns.device_wait"] < snap["span_ns.device_wait"] / 100
+    pw.G.clear()
+    pw.debug.table_from_markdown("a\n1").select(b=pw.this.a)._capture_node()
+    body = _metrics_text(Scheduler(G.engine_graph, autocommit_ms=20))
+    pw.G.clear()
+    assert 'pathway_tpu_span_idle_ns_total{stage="host_work"} ' in body
+    for name in _ALWAYS:
+        assert f"pathway_tpu_{name}_total " in body, name
+
+
+def test_the_account_and_its_counters_stay_zero_under_trace_zero(recorder):
+    from pathway_tpu.internals import device_counters
+
+    recorder.configure(PATHWAY_TRACE="0")
+    recorder.reset()
+    ticket = recorder.chip.ticket()
+    with recorder.span("off"):
+        recorder.chip.collected(ticket)
+    snap = device_counters.snapshot()
+    assert ticket == 0 and recorder.chip.enq == 0
+    assert {k: snap[k] for k in _ALWAYS} == dict.fromkeys(_ALWAYS, 0)
+    assert not [k for k in snap if k.startswith("span_")]
+
+
+def test_the_encoder_and_the_slab_leave_no_ticket_outstanding(recorder):
+    import numpy as np
+
+    from pathway_tpu.models.encoder import EncoderConfig
+    from pathway_tpu.models.tokenizer import HashTokenizer
+    from pathway_tpu.parallel.executor import JittedEncoder
+    from pathway_tpu.parallel.sharded_knn import ShardedKnnIndex
+
+    chip = recorder.chip
+    cfg = EncoderConfig(hidden=32, layers=1, heads=2, mlp_dim=64, vocab_size=512, max_len=64)
+    enc = JittedEncoder(cfg, tokenizer=HashTokenizer(512), max_batch=4)
+    texts = ["w%d " % i * (1 + i % 5) for i in range(11)]  # three batches, pipelined
+    taken = chip.enq
+    vecs = enc.encode(texts)
+    assert chip.enq - taken >= 3 and chip.outstanding() == 0
+    idx = ShardedKnnIndex(32, capacity=64)
+    idx.add_batch([f"k{i}" for i in range(len(texts))], vecs)  # a scatter takes no ticket
+    taken = chip.enq
+    hits = idx.search(vecs[:2], k=3)
+    assert [len(h) for h in hits] == [3, 3]
+    assert chip.enq - taken == 1 and chip.outstanding() == 0
+
+
+def test_a_generation_leaves_no_ticket_outstanding(recorder):
+    """A ticket for each prefill chunk and each decode step, all collected
+    by the two blocks of ``generate``."""
+    import numpy as np
+
+    from pathway_tpu.parallel import JittedDecoder
+    from tests import hybrid_toy
+
+    executor = JittedDecoder(
+        hybrid_toy.config_of(hybrid_toy.GROUP), params=hybrid_toy.float32_params(hybrid_toy.GROUP),
+        slots=2, positions=hybrid_toy.POSITIONS, chunk_buckets=(8, 16),
+    )
+    chip = recorder.chip
+    taken = chip.enq
+    out = executor.generate(np.arange(1000, 1029, dtype=np.int32), 4)  # chunks of 16 and 16, 3 steps
+    assert out["ids"].shape == (4,)
+    assert chip.enq - taken == 2 + 3 and chip.outstanding() == 0

@@ -645,3 +645,246 @@ def test_a_collector_sweep_is_a_span():
     sweeps = [e["args"]["generation"] for e in _events() if e["name"] == "gc_sweep"]
     assert sweeps == [1, 1, 1, 1, 1, 1, 1, 2]  # every eighth is a full collection
     assert tracing.stage_totals()["gc_sweep"][0] == 8
+
+
+# ------------------------------------------------------ the chip account
+
+
+class _Clock:
+    """A clock the test sets by hand (ns)."""
+
+    def __init__(self, t: int = 1_000):
+        self.t = t
+
+    def __call__(self) -> int:
+        return self.t
+
+
+@pytest.fixture
+def clock():
+    """The account and the span clock on one hand-set clock, the account
+    restarted idle at t = 1,000."""
+    c = _Clock()
+    real = tracing.chip.clock
+    tracing.chip.clock = c
+    tracing._monotonic_ns = c
+    tracing.chip.restart()
+    yield c
+    tracing.chip.clock = real
+    tracing._monotonic_ns = time.monotonic_ns
+    tracing.chip.restart()
+
+
+def _at(clock, t, fn, *args):
+    clock.t = t
+    return fn(*args)
+
+
+def test_the_account_is_idle_with_no_ticket_outstanding(clock):
+    chip = tracing.chip
+    t1 = _at(clock, 1_100, tracing.chip.ticket)
+    t2 = _at(clock, 1_150, tracing.chip.ticket)  # already busy: no transition
+    _at(clock, 1_200, tracing.chip.collected, t1)  # t2 still outstanding
+    assert chip.outstanding() == 1 and chip.idle_at(1_200) == 100
+    _at(clock, 1_300, tracing.chip.collected, t2)
+    assert chip.outstanding() == 0
+    assert [chip.idle_at(t) for t in (1_000, 1_050, 1_100, 1_250, 1_300, 1_400)] == [0, 50, 100, 100, 100, 200]
+    assert chip.idle_intervals(since_ns=None) == [(1_000, 1_100), (1_300, 1_300)]
+
+
+def test_tickets_from_two_threads_interleave_in_enqueue_order(clock):
+    """Two threads enqueue and wait in turn; collecting a later ticket says
+    the earlier ones are done, and collecting an earlier one after it
+    changes nothing."""
+    import queue
+
+    inboxes = {"a": queue.Queue(), "b": queue.Queue()}
+    done: queue.Queue = queue.Queue()
+    tickets: dict[str, int] = {}
+
+    def worker(name):
+        while True:
+            op = inboxes[name].get()
+            if op is None:
+                return
+            kind, key = op
+            if kind == "take":
+                tickets[key] = tracing.chip.ticket()
+            else:
+                tracing.chip.collected(tickets[key])
+            done.put(op)
+
+    threads = [threading.Thread(target=worker, args=(n,)) for n in inboxes]
+    for t in threads:
+        t.start()
+    # (instant, thread, op, ticket name): idle 1,000-1,010, 1,040-1,050, 1,080-1,090, 1,110-
+    plan = [
+        (1_010, "a", "take", 1), (1_020, "b", "take", 2), (1_030, "a", "collect", 1), (1_040, "b", "collect", 2),
+        (1_050, "b", "take", 3), (1_060, "a", "take", 4), (1_070, "b", "collect", 3), (1_080, "a", "collect", 4),
+        (1_090, "a", "take", 5), (1_100, "b", "take", 6), (1_110, "b", "collect", 6), (1_120, "a", "collect", 5),
+    ]
+    try:
+        for t, name, kind, key in plan:
+            clock.t = t
+            inboxes[name].put((kind, key))
+            assert done.get(timeout=30) == (kind, key)
+    finally:
+        for q in inboxes.values():
+            q.put(None)
+        for t in threads:
+            t.join(timeout=30)
+    assert tickets == {1: 1, 2: 2, 3: 3, 4: 4, 5: 5, 6: 6}
+    assert tracing.chip.outstanding() == 0
+    clock.t = 1_130
+    assert tracing.chip.idle_at(1_130) == 10 + 10 + 10 + 20
+    assert tracing.chip.idle_intervals() == [(1_000, 1_010), (1_040, 1_050), (1_080, 1_090), (1_110, 1_130)]
+
+
+def test_a_span_adds_the_idle_time_between_its_ends_to_its_stage(clock):
+    clock.t = 1_100
+    with tracing.span("host_stage"):
+        ticket = _at(clock, 1_120, tracing.chip.ticket)
+        with tracing.span("wait"):
+            _at(clock, 1_150, tracing.chip.collected, ticket)
+        clock.t = 1_170
+    # the outer stage: idle 1,100-1,120 and 1,150-1,170; the wait: 1,120-1,150 busy
+    assert tracing.stage_totals()["host_stage"] == (1, 70)
+    assert tracing.stage_idle() == {"host_stage": 40, "wait": 0}
+    clock.t = 1_200
+    with tracing.span("idle_all_through"):
+        clock.t = 1_260
+    assert tracing.stage_idle()["idle_all_through"] == 60
+
+
+def test_a_span_recorded_after_the_fact_looks_its_ends_up_in_the_ring(clock):
+    """``record_span`` with a past ``t0`` (as ``epoch_cut_wait``,
+    ``groupby_emit`` and ``dispatch_segments`` are recorded) gets the idle
+    time between its ends from the transition ring."""
+    for busy_from, busy_to in ((1_100, 1_200), (1_300, 1_350), (1_400, 1_500)):
+        ticket = _at(clock, busy_from, tracing.chip.ticket)
+        _at(clock, busy_to, tracing.chip.collected, ticket)
+    clock.t = 1_600
+    tracing.record_span("past", 1_150, 1_450)  # idle 1,200-1,300 and 1,350-1,400
+    tracing.record_span("past", 1_520, 1_580)  # after the last transition: all idle
+    tracing.record_span("before_the_account", 10, 20)  # nothing known before its start
+    tracing.record_spans(tracing.new_trace(), [("batch", 1_050, 1_320, None), ("batch", 1_210, 1_290, None)])
+    idle = tracing.stage_idle()
+    assert idle["past"] == 100 + 50 + 60
+    assert idle["before_the_account"] == 0
+    assert idle["batch"] == (50 + 100) + 80
+
+
+def test_the_ring_forgets_its_oldest_transitions(clock, monkeypatch):
+    monkeypatch.setattr(tracing, "_CHIP_RING", 8)
+    tracing.chip.restart()
+    for i in range(10):  # 20 transitions: the ring keeps the last 8
+        ticket = _at(clock, 2_000 + 100 * i, tracing.chip.ticket)
+        _at(clock, 2_050 + 100 * i, tracing.chip.collected, ticket)
+    chip = tracing.chip
+    assert chip.idle_at(2_975) == chip.idle_at(2_950) + 25
+    assert chip.idle_at(2_900) - chip.idle_at(2_650) == 3 * 50
+    oldest = chip.idle_at(2_600)  # the oldest kept transition (busy from 2,600)
+    assert chip.idle_at(1_500) == oldest
+    tracing.chip.restart()
+
+
+def test_the_account_and_the_watchdog_are_off_under_trace_zero(clock):
+    tracing.configure(PATHWAY_TRACE="0")
+    assert _at(clock, 1_100, tracing.chip.ticket) == 0
+    assert tracing.chip.outstanding() == 0 and tracing.chip.enq == 0
+    wd = tracing.StallWatchdog(clock=_Clock(0), cpu=_Clock(0), steal=_Clock(0), majflt=_Clock(0), totals=dict.fromkeys(tracing._stalls, 0))
+    assert wd.tick(5_000_000_000) == 0 and not any(wd.totals.values())
+
+
+# ------------------------------------------------------ the stall watchdog
+
+
+def test_the_watchdog_records_a_late_wake_with_what_it_can_see():
+    now, cpu, steal, flt = _Clock(0), _Clock(0), _Clock(0), _Clock(0)
+    totals = dict.fromkeys(tracing._stalls, 0)
+    wd = tracing.StallWatchdog(clock=now, cpu=cpu, steal=steal, majflt=flt, totals=totals)
+    held, release = threading.Event(), threading.Event()
+
+    def holder():
+        with tracing.span("epoch_process"):
+            with tracing.span("encoder_tokenize"):
+                held.set()
+                release.wait(30)
+
+    t = threading.Thread(target=holder, name="engine-worker")
+    t.start()
+    try:
+        assert held.wait(30)
+        # on time: due at 20 ms, woken at 25 (5 ms of scheduling is no stall), and 90 ms late is none either
+        now.t, cpu.t = 25_000_000, 3_000_000
+        assert wd.tick(now.t) == 0
+        now.t = 135_000_000
+        assert wd.tick(now.t) == 0
+        # due at 155 ms, woken at 2.155 s: 2 s late
+        now.t, cpu.t, steal.t, flt.t = 2_155_000_000, 43_000_000, 1_950_000_000, 7
+        assert wd.tick(now.t) == 2_000_000_000
+        now.t += 21_000_000
+        assert wd.tick(now.t) == 0
+    finally:
+        release.set()
+        t.join(timeout=30)
+    here = threading.current_thread().name  # not the process's own watchdog, which may meet a real stall meanwhile
+    (ev,) = [e for e in _events() if e["name"] == "process_stall" and e["tid"] == here]
+    assert ev["ts"] == 155_000 and ev["dur"] == 2_000_000  # µs
+    assert ev["args"]["cpu_ms"] == 40.0 and ev["args"]["steal_ms"] == 1950.0 and ev["args"]["majflt"] == 7
+    assert ev["args"]["open"] == {"engine-worker": "encoder_tokenize"}
+    assert totals == {"stall_count": 1, "stall_ns": 2_000_000_000, "stall_cpu_ns": 40_000_000, "stall_steal_ns": 1_950_000_000}
+    assert ev in tracing.chrome_events()  # context-free: exported by default
+
+
+def test_one_watchdog_thread_runs_with_the_recorder():
+    with tracing.span("starts_it"):
+        pass
+    names = [t.name for t in threading.enumerate()]
+    assert names.count("pathway-stall-watchdog") == 1
+    assert tracing._watchdog is not None
+
+
+def test_a_stopped_process_leaves_one_stall_with_no_cpu(tmp_path):
+    """SIGSTOP from outside for 0.6 s: the process's own watchdog records
+    one ``process_stall`` of about that length, with little CPU time in it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import json, os, signal, subprocess, sys, time\n"
+        "from pathway_tpu.internals import tracing\n"
+        "with tracing.span('s'):\n"
+        "    pass\n"
+        "time.sleep(0.2)\n"
+        "subprocess.Popen(['sh', '-c', f'kill -STOP {os.getpid()}; sleep 0.6; kill -CONT {os.getpid()}'])\n"
+        "time.sleep(1.5)\n"
+        "print(json.dumps([e for e in tracing.chrome_events() if e['name'] == 'process_stall']))\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    stalls = json.loads(proc.stdout.strip().splitlines()[-1])
+    longest = max(stalls, key=lambda e: e["dur"])
+    assert 0.45e6 <= longest["dur"] <= 1.2e6, stalls
+    assert longest["args"]["cpu_ms"] < 100, stalls
+
+
+# ------------------------------------------------ the chip on a dumped trace
+
+
+def test_a_dumped_trace_shows_the_chip_track_beside_the_stages(clock, tmp_path):
+    assert not [e for e in _events() if e["tid"] == "chip"]  # no device work yet: no track
+    ticket = _at(clock, 1_500, tracing.chip.ticket)
+    clock.t = 1_600
+    with tracing.span("encoder_readback"):
+        clock.t = 2_500
+        tracing.chip.collected(ticket)
+    clock.t = 4_000
+    path = tracing.dump(str(tmp_path / "t.json"))
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    chip = [(e["name"], e["ts"], e["dur"]) for e in events if e["tid"] == "chip"]
+    assert chip == [("chip_idle", 1.0, 0.5), ("chip_idle", 2.5, 1.5)]  # µs
+    assert {e["pid"] for e in events} == {tracing.current_rank()}
+    assert [e["name"] for e in events if e["tid"] == threading.current_thread().name] == ["encoder_readback"]
