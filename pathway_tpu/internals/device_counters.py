@@ -27,7 +27,9 @@ counters are monotonic:
   cut), ``rest_requests`` / ``rest_responses`` (``io/http``) and, once a
   generated answer (``JittedDecoder.generate``): ``gen_*`` (the prompt's
   tokens as given and as padded to chunk buckets, its prefill dispatches,
-  the tokens chosen and the decode steps), ``moe_rows_here`` /
+  the tokens chosen and the decode steps; ``gen_logit_rows``, the rows of
+  logits kept, and ``gen_logit_rows_early``, those in their host array
+  while the answer's last step was still running), ``moe_rows_here`` /
   ``moe_rows_routed`` (token-expert pairs the experts held here computed /
   pairs the router chose anywhere) and ``dsa_keys_selected`` /
   ``dsa_keys_scored`` (keys the queries attended to / keys their indexer
@@ -112,6 +114,8 @@ _counters: dict[str, int] = {
     "gen_prefill_dispatches": 0,
     "gen_new_tokens": 0,
     "gen_decode_steps": 0,
+    "gen_logit_rows": 0,
+    "gen_logit_rows_early": 0,
     "moe_rows_here": 0,
     "moe_rows_routed": 0,
     "dsa_keys_selected": 0,

@@ -22,11 +22,15 @@ compiles nothing.
 
 :meth:`generate` is the loop that yields tokens: the prompt's chunks are
 enqueued back to back, then the decode steps, each taking the token the
-step before it chose on the device, so the host runs ahead of the chip and
-blocks twice a request, outside the lock that orders the enqueues: for the
-prompt (span ``generate_prefill``) and for the answer (``generate_decode``).
-Requests generate one after another on the device (the engine gives this
-stage one request an epoch); the slots are taken in turn.
+step before it chose on the device, so the host runs ahead of the chip.
+Outside the lock that orders the enqueues it starts every output's copy to
+the host and waits for the prompt's logits (span ``generate_prefill``), then
+walks the steps in order (``generate_decode``): each step's row of logits
+lands in its place of one host array while the chip runs the steps after
+it, so that once the last step has run only its row, the ids and the
+counters are left (``generate_keep``).  Requests generate one after
+another on the device (the engine gives this stage one request an epoch);
+the slots are taken in turn.
 
 The two programs lower as ``jit__prefill_chunk`` and ``jit__decode_token``:
 the benchmark finds their device time by these names and a tier-1 test
@@ -159,25 +163,47 @@ class JittedDecoder:
                     rows.append(logits)
                     tokens.append(token)
                     stats.append(st)
+            # the copies start once the lock is released, so that no enqueue waits behind them: each array then
+            # leaves the device as soon as the step that makes it has run
+            for array in (*rows, *tokens, *stats):
+                array.copy_to_host_async()
             rows[0].block_until_ready()
             _tracing.chip.collected(prefilled)
+        # each row lands in its place of the one host array while the chip runs the steps after it
+        logits = np.empty((max_new_tokens, rows[0].shape[-1]), np.float32)
+        ids = np.empty(max_new_tokens, np.int32)
+        counted = np.zeros(len(self.architecture.STATS), np.int64)
+        made = [stats[: len(chunks)], *([st] for st in stats[len(chunks) :])]  # row i's dispatches' counts
+
+        def land(i: int) -> None:
+            logits[i] = rows[i]
+            ids[i : i + 1] = tokens[i]
+            for st in made[i]:
+                np.add(counted, st, out=counted)
+
+        early = 0
         with _tracing.span("generate_decode") as sp:
             sp.args = {"steps": steps}
-            rows, tokens, stats = jax.device_get((rows, tokens, stats))
+            for i in range(steps):
+                land(i)
+                early += not rows[-1].is_ready()
+            rows[-1].block_until_ready()
+        with _tracing.span("generate_keep"):
+            land(steps)
             _tracing.chip.collected(decoded)
-        logits = np.stack(rows)
-        _devctr.record_d2h(logits.nbytes)
-        counted = np.sum(np.stack(stats).astype(np.int64), axis=0)
-        _devctr.bump(
-            gen_requests=1,
-            gen_prompt_tokens=prompt.size,
-            gen_prompt_tokens_padded=sum(bucket for _s, _r, bucket in chunks),
-            gen_prefill_dispatches=len(chunks),
-            gen_new_tokens=max_new_tokens,
-            gen_decode_steps=steps,
-            **dict(zip(self.architecture.STATS, (int(c) for c in counted))),
-        )
-        return {"ids": np.concatenate(tokens).astype(np.int32), "logits": logits}
+            _devctr.record_d2h(logits.nbytes)
+            _devctr.bump(
+                gen_requests=1,
+                gen_prompt_tokens=prompt.size,
+                gen_prompt_tokens_padded=sum(bucket for _s, _r, bucket in chunks),
+                gen_prefill_dispatches=len(chunks),
+                gen_new_tokens=max_new_tokens,
+                gen_decode_steps=steps,
+                gen_logit_rows=max_new_tokens,
+                gen_logit_rows_early=early,
+                **dict(zip(self.architecture.STATS, (int(c) for c in counted))),
+            )
+        return {"ids": ids, "logits": logits}
 
     def warm(self, max_new_tokens: int = 2) -> None:
         """Run every program once: a prompt of each chunk bucket's size (one

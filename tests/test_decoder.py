@@ -308,9 +308,11 @@ def test_generate_is_greedy_over_the_references_logits_and_moves_the_counters(mo
     want = {
         "gen_requests": 1, "gen_prompt_tokens": 21, "gen_prompt_tokens_padded": 24, "gen_prefill_dispatches": 2,
         "gen_new_tokens": 6, "gen_decode_steps": 5, "span_count.generate_prefill": 1, "span_count.generate_decode": 1,
+        "gen_logit_rows": 6, "span_count.generate_keep": 1,
         **served["counted"](21, 24, 5), served["silent"]: 0,  # the other architecture's counters stay where they were
     }
     assert {k: moved[k] for k in want} == want
+    assert 0 <= moved["gen_logit_rows_early"] <= 5  # the last row lands once the last step has run
     if served["family"] is family:
         exact = 3 * sum(min(t, 8) for t in range(1, 27))
         assert exact <= moved["dsa_keys_selected"] <= exact + 12  # ties with the k-th score are selected with it
@@ -319,6 +321,56 @@ def test_generate_is_greedy_over_the_references_logits_and_moves_the_counters(mo
     again = executor.generate(prompt, 6)  # the next slot, and then the first again
     third = executor.generate(prompt, 6)
     assert np.array_equal(again["logits"], out["logits"]) and np.array_equal(third["logits"], out["logits"])
+
+
+def _stepwise(executor, prompt, new):
+    """One request through the executor's two programs with nothing kept on
+    the way: every row and id fetched after the last step and stacked."""
+    slot = executor._turn % executor.slots
+    executor._turn += 1
+    for start, real, bucket in executor.plan(prompt.size):
+        ids = np.zeros(bucket, np.int32)
+        ids[:real] = prompt[start : start + real]
+        token, logits, executor.cache, _ = executor._prefill(
+            executor.params, ids, executor.cache, np.int32(slot), np.int32(start), np.int32(real), np.bool_(start + real == prompt.size)
+        )
+    rows, tokens = [logits], [token]
+    for i in range(new - 1):
+        token, logits, executor.cache, _ = executor._decode(
+            executor.params, token, executor.cache, np.asarray([slot], np.int32), np.asarray([prompt.size + i], np.int32)
+        )
+        rows.append(logits)
+        tokens.append(token)
+    return np.stack(jax.device_get(rows)), np.concatenate(jax.device_get(tokens))
+
+
+@pytest.mark.parametrize("new", [1, 6])
+def test_generate_lands_each_row_in_one_host_array_bit_for_bit(model, served, executor, new):
+    """The kept logits are written row by row into their final array while
+    later steps run: what comes back is what stacking the programs' rows
+    gives, and the counters say how many rows landed before the last step
+    had run (never the last row itself)."""
+    prompt = model["ids"][:21]
+    before = devctr.snapshot()
+    out = executor.generate(prompt, new)
+    moved = {k: v - before.get(k, 0) for k, v in devctr.snapshot().items()}
+    rows, tokens = _stepwise(executor, prompt, new)
+    assert out["logits"].dtype == np.float32 and out["logits"].flags.c_contiguous and out["logits"].shape == rows.shape
+    assert np.array_equal(out["logits"], rows)
+    assert out["ids"].dtype == np.int32 and np.array_equal(out["ids"], rows.argmax(axis=1)) and np.array_equal(out["ids"], tokens)
+    assert moved["gen_logit_rows"] == new and 0 <= moved["gen_logit_rows_early"] <= new - 1
+    assert moved["span_count.generate_keep"] == 1 and moved["d2h_bytes"] == rows.nbytes
+
+
+@pytest.mark.parametrize("last_step_done, early", [(False, 5), (True, 0)])
+def test_a_row_counts_early_while_the_last_step_has_not_finished(model, executor, monkeypatch, last_step_done, early):
+    """Whether the last step had finished is asked of its row after each
+    copy; on the CPU the answer is a race, so the test gives it."""
+    monkeypatch.setattr(type(jnp.zeros(0)), "is_ready", lambda self: last_step_done)
+    before = devctr.snapshot()
+    executor.generate(model["ids"][:21], 6)
+    moved = {k: v - before.get(k, 0) for k, v in devctr.snapshot().items()}
+    assert (moved["gen_logit_rows"], moved["gen_logit_rows_early"]) == (6, early)
 
 
 def test_a_share_of_the_experts_counts_the_rows_it_computed(model):
