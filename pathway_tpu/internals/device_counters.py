@@ -38,7 +38,8 @@ counters are monotonic:
   ``moe_rows_zero``, the pairs whose expert computes nothing, and
   ``mla_keys_visible`` / ``mla_keys_multiplied``, the query-key pairs that
   count / those the attention multiplied: in a prompt, the fused kernel's
-  query tiles against the key blocks each visits);
+  query tiles against the key blocks each visits; ``moe_rows_multiplied``,
+  the rows the expert loop multiplied, its blocks' padding included);
   and once a ``GroupByNode.process`` call that had dirty groups:
   ``groupby_groups_emitted`` (groups whose change it emitted) and
   ``groupby_groups_consolidated`` (those of them whose two rows could not
@@ -118,6 +119,7 @@ _counters: dict[str, int] = {
     "gen_logit_rows_early": 0,
     "moe_rows_here": 0,
     "moe_rows_routed": 0,
+    "moe_rows_multiplied": 0,
     "dsa_keys_selected": 0,
     "dsa_keys_scored": 0,
     "xdec_tokens_run": 0,

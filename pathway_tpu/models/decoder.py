@@ -35,15 +35,18 @@ Weights and caches are ``config.dtype`` (bfloat16); products accumulate in
 float32; the residual stream, norms, the router, the indexer's scores and
 the softmax are float32.
 
-Three architectures give :class:`pathway_tpu.parallel.JittedDecoder` the five
+Four architectures give :class:`pathway_tpu.parallel.JittedDecoder` the five
 names ``init_cache``, ``prefill``, ``decode_step``, ``STATS`` and
-``DISPATCH_TOKENS``: this module, :mod:`pathway_tpu.models.hybrid_decoder`
-and :mod:`pathway_tpu.models.shortcut_moe_decoder`.  The last calls what this
+``DISPATCH_TOKENS``: this module, :mod:`pathway_tpu.models.hybrid_decoder`,
+:mod:`pathway_tpu.models.shortcut_moe_decoder` and
+:mod:`pathway_tpu.models.window_moe_decoder`.  The third calls what this
 module has after the selection as it stands: the latent-attention core
 (:func:`_prefill_core`, :func:`_decode_core`, with the causal mask in the
 selection's place) and :func:`_experts_here`, with ``_logits``, ``_swiglu``,
-``_rms``, ``_rotate``, ``_mm`` and ``_rows_of``; a change to one of them for one
-generator is measured on the other.
+``_rms``, ``_rotate``, ``_mm`` and ``_rows_of``; the fourth calls
+:func:`_experts_here` with its experts' activation and every expert held,
+with ``_logits``, ``_rms`` and ``_mm``.  A change to one of them for one
+generator is measured on the others.
 """
 
 from __future__ import annotations
@@ -238,9 +241,11 @@ def _route(x, lp, cfg: DecoderConfig):
     return chosen, weight / jnp.sum(weight, axis=1, keepdims=True) * cfg.routed_scaling_factor
 
 
-def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig):
+def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig, activation=_swiglu):
     """The part of the routed result that the experts held here give:
-    ``sum over chosen experts e held here of gates_e SwiGLU_e(x)``.
+    ``sum over chosen experts e held here of gates_e FFN_e(x)``, where the
+    expert's feed-forward is ``activation(x, expert, dtype)`` (SwiGLU unless
+    the architecture's experts are another gated unit).
 
     A grouped product over uneven groups with nothing dropped: the
     token-expert pairs that fall to this chip are ordered by expert, each
@@ -270,7 +275,7 @@ def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig):
         pair = order[jnp.clip(first_pair[e] + within, 0, T * K - 1)]
         token = pair // K
         p = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(w, e, keepdims=False), experts)
-        y = _swiglu(x[token], p, dt) * jnp.where(valid, flat_gates[pair], 0.0)[:, None]
+        y = activation(x[token], p, dt) * jnp.where(valid, flat_gates[pair], 0.0)[:, None]
         return out.at[token].add(y)
 
     out = jax.lax.fori_loop(0, last_block[-1], one_block, jnp.zeros((T, x.shape[1]), jnp.float32))

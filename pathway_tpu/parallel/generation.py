@@ -8,10 +8,12 @@ class (:mod:`pathway_tpu.models.decoder`: for every layer latent rows and
 indexer keys by position; :mod:`pathway_tpu.models.hybrid_decoder`:
 recurrent states, rings, one layer's keys and values, each of its own shape
 and lifetime; :mod:`pathway_tpu.models.shortcut_moe_decoder`: two caches of
-latent rows a layer, one an attention sublayer) gives ``init_cache``,
-``prefill``, ``decode_step``, the ``STATS`` both count and
-``DISPATCH_TOKENS``: three architectures give the five names, and the third
-needed no edit here.  The executor owns the rest:
+latent rows a layer, one an attention sublayer;
+:mod:`pathway_tpu.models.window_moe_decoder`: keys and values by position
+for its global layers and a ring for each window layer) gives
+``init_cache``, ``prefill``, ``decode_step``, the ``STATS`` both count and
+``DISPATCH_TOKENS``: four architectures give the five names, and the third
+and fourth needed no edit here.  The executor owns the rest:
 the state is pre-sized and never grows, ``slots`` sequences of
 ``positions`` tokens, updated in place through donation as the index slab
 is.  Shapes come from a small fixed set: a prompt is cut into chunks of the

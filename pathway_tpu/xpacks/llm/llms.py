@@ -8,7 +8,9 @@ client packages; :class:`HFPipelineChat` (``:441``) on a locally cached
 model.  ``prompt_chat_single_qa`` matches the reference helper.
 
 :class:`TPUDecoderChat` is the chat that stays on the chip: a causal
-decoder (``models/decoder.py``) behind :class:`JittedDecoder`, so that
+decoder (one of the architectures of ``models/``: ``decoder``,
+``hybrid_decoder``, ``shortcut_moe_decoder``, ``window_moe_decoder``, each
+named by its presets) behind :class:`JittedDecoder`, so that
 ``BaseRAGQuestionAnswerer(llm=TPUDecoderChat(...))`` answers without the
 request leaving the device that retrieved for it.
 """
@@ -159,6 +161,8 @@ _DECODER_PRESETS = {
     "phi-4-mini-flash-reasoning": ("hybrid_decoder", "PHI4_MINI_FLASH"),
     "meituan-longcat/longcat-flash-chat": ("shortcut_moe_decoder", "LONGCAT_FLASH_CHAT"),
     "longcat-flash-chat": ("shortcut_moe_decoder", "LONGCAT_FLASH_CHAT"),
+    "powerinfer/smallthinker-21ba3b-instruct": ("window_moe_decoder", "SMALLTHINKER_21BA3B"),
+    "smallthinker-21ba3b-instruct": ("window_moe_decoder", "SMALLTHINKER_21BA3B"),
 }
 
 

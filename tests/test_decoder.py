@@ -3,11 +3,13 @@
 ``TPUDecoderChat``, each held against the plain reference of the benchmark's
 ``deepseek_v32`` family (float32 ``jax.numpy``, no cache, no chunks, no
 absorbed form), on seeded weights.  The executor, the chat and the answer
-route are one code for all three architectures and run here over each
+route are one code for all four architectures and run here over each
 (``served``): the second, ``models/hybrid_decoder.py``, against the
 ``phi4flash`` family's reference, the third, ``models/shortcut_moe_decoder.py``,
-against the ``longcat_flash`` family's (their own tests are
-``test_hybrid_decoder.py``'s and ``test_shortcut_moe_decoder.py``'s)."""
+against the ``longcat_flash`` family's, the fourth,
+``models/window_moe_decoder.py``, against the ``smallthinker`` family's (their
+own tests are ``test_hybrid_decoder.py``'s, ``test_shortcut_moe_decoder.py``'s
+and ``test_window_moe_decoder.py``'s)."""
 
 from __future__ import annotations
 
@@ -27,10 +29,11 @@ import pathway_tpu as pw
 from benchmark.families import deepseek_v32 as family
 from benchmark.families import longcat_flash as shortcut_family
 from benchmark.families import phi4flash as hybrid_family
+from benchmark.families import smallthinker as window_family
 from pathway_tpu.internals import device_counters as devctr
 from pathway_tpu.models import MINILM_L6, decoder
 from pathway_tpu.parallel import JittedDecoder
-from tests import hybrid_toy, shortcut_toy
+from tests import hybrid_toy, shortcut_toy, window_moe_toy
 from tests.utils import T
 
 ROPE_SCALING = {"beta_fast": 32, "beta_slow": 1, "factor": 4, "mscale": 1, "mscale_all_dim": 1, "original_max_position_embeddings": 16, "type": "yarn"}
@@ -216,7 +219,7 @@ def test_bfloat16_stays_near_the_reference(model):
 
 
 # ------------------------------------------------------------ the executor
-@pytest.fixture(scope="module", params=["deepseek_v32", "phi4flash", "longcat_flash"])
+@pytest.fixture(scope="module", params=["deepseek_v32", "phi4flash", "longcat_flash", "smallthinker"])
 def served(request, model):
     """Each architecture the executor serves, at its toy size: the
     configuration, seeded float32 parameters, the family whose reference they
@@ -246,6 +249,24 @@ def served(request, model):
         return {
             "cfg": shortcut_toy.config_of(shortcut_toy.GROUP), "params": shortcut_toy.float32_params(shortcut_toy.GROUP), "family": shortcut_family,
             "group": shortcut_toy.GROUP, "counted": counted, "silent": "dsa_keys_scored",
+        }
+
+    if request.param == "smallthinker":
+        def counted(prompt, padded, steps):
+            # 1 global + 3 window layers; every live token routes 2 pairs a layer, all 8 experts held here; a window query sees up
+            # to 16 keys; a prompt chunk's query tiles of 8 rows visit key blocks of 8 from the one their window reaches (the
+            # request of the test: a chunk of 16 at 0, tiles of one and two blocks past the empty ring, and one of 8 at 16, three),
+            # a decode step the ring's 16
+            tokens = prompt + steps
+            assert (prompt, padded) == (21, 24)
+            return {
+                "moe_rows_routed": 4 * 2 * tokens, "moe_rows_here": 4 * 2 * tokens,
+                "swa_keys_in_window": 3 * sum(min(t + 1, 16) for t in range(tokens)), "swa_keys_multiplied": 3 * (8 * 8 * (3 + 3) + steps * 16),
+            }
+
+        return {
+            "cfg": window_moe_toy.config_of(window_moe_toy.GROUP), "params": window_moe_toy.float32_params(window_moe_toy.GROUP),
+            "family": window_family, "group": window_moe_toy.GROUP, "counted": counted, "silent": "mla_keys_multiplied",
         }
 
     def counted(prompt, padded, steps):
@@ -413,7 +434,10 @@ def test_the_chat_tokenizes_generates_and_keeps_what_it_produced(served):
     from pathway_tpu.xpacks.llm.llms import TPUDecoderChat, decoder_preset
 
     model, GROUP = served, served["group"]
-    presets = {family: "deepseek-ai/DeepSeek-V3.2-Exp", hybrid_family: "microsoft/Phi-4-mini-flash-reasoning", shortcut_family: "meituan-longcat/LongCat-Flash-Chat"}
+    presets = {
+        family: "deepseek-ai/DeepSeek-V3.2-Exp", hybrid_family: "microsoft/Phi-4-mini-flash-reasoning", shortcut_family: "meituan-longcat/LongCat-Flash-Chat",
+        window_family: "PowerInfer/SmallThinker-21BA3B-Instruct",
+    }
     assert type(decoder_preset(presets[served["family"]])) is type(served["cfg"])
     chat = TPUDecoderChat(config=model["cfg"], params=model["params"], max_new_tokens=4, slots=2, positions=POSITIONS, chunk_buckets=(8, 16))
     before = devctr.snapshot()
@@ -600,6 +624,25 @@ def test_the_fused_attention_kernel_compiles_for_a_v5e_at_the_published_widths(o
     compiled = selected_attention.lower(
         shape((H, chunk, 128), bf16), shape((H, chunk, 64), bf16), shape((H, L, 128), bf16), shape((L, 64), bf16), shape((H, L, 128), bf16),
         shape((chunk, L), jnp.bool_), shape((), jnp.int32), shape((), jnp.int32), block_q=BLOCK_Q, block_k=512,
+    ).compile()
+    assert "selected_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chunk,window", [(512, 4096), (2048, 4096), (2560, 4096), (2048, None), (2560, None)])
+def test_the_grouped_attention_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, chunk, window):
+    """``models/window_moe_decoder.py``'s form of the kernel: 28 query heads
+    over 4 K/V heads of 128, seven query heads a grid step over one K/V
+    head's block; a window layer's 4,096 keys of the ring and the chunk's own,
+    a global layer's 16,384 positions; the chunk's start, its first key and
+    its real rows as scalars."""
+    from pathway_tpu.ops.selected_attention import BLOCK_Q, grouped_attention
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    L, bf16 = (window + chunk) if window else 16384, jnp.bfloat16
+    scalar = shape((), jnp.int32)
+    compiled = grouped_attention.lower(
+        shape((28, chunk, 128), bf16), shape((4, L, 128), bf16), shape((4, L, 128), bf16), shape((chunk, L), jnp.bool_), scalar, scalar, scalar,
+        window=window, block_q=BLOCK_Q, block_k=512,
     ).compile()
     assert "selected_attention" in compiled.as_text()
 
