@@ -39,7 +39,9 @@ counters are monotonic:
   ``mla_keys_visible`` / ``mla_keys_multiplied``, the query-key pairs that
   count / those the attention multiplied: in a prompt, the fused kernel's
   query tiles against the key blocks each visits; ``moe_rows_multiplied``,
-  the rows the expert loop multiplied, its blocks' padding included);
+  the rows the expert product multiplied, its blocks' padding included;
+  ``moe_grouped_calls``, the layers of a dispatch whose experts took the
+  grouped product, one weight read an expert: prompt chunks only);
   and once a ``GroupByNode.process`` call that had dirty groups:
   ``groupby_groups_emitted`` (groups whose change it emitted) and
   ``groupby_groups_consolidated`` (those of them whose two rows could not
@@ -120,6 +122,7 @@ _counters: dict[str, int] = {
     "moe_rows_here": 0,
     "moe_rows_routed": 0,
     "moe_rows_multiplied": 0,
+    "moe_grouped_calls": 0,
     "dsa_keys_selected": 0,
     "dsa_keys_scored": 0,
     "xdec_tokens_run": 0,

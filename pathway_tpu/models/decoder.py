@@ -62,7 +62,7 @@ import numpy as np
 __all__ = ["DecoderConfig", "DEEPSEEK_V32_EXP", "init_cache", "prefill", "decode_step", "STATS", "DISPATCH_TOKENS"]
 
 #: what both programs count, in the order of the vector they return
-STATS = ("moe_rows_here", "moe_rows_routed", "dsa_keys_selected", "dsa_keys_scored")
+STATS = ("moe_rows_here", "moe_rows_routed", "dsa_keys_selected", "dsa_keys_scored", "moe_grouped_calls")
 
 #: what one more prefill dispatch costs beside its tokens, in tokens: every
 #: weight is read again and the sequence's keys and values are expanded again
@@ -241,6 +241,43 @@ def _route(x, lp, cfg: DecoderConfig):
     return chosen, weight / jnp.sum(weight, axis=1, keepdims=True) * cfg.routed_scaling_factor
 
 
+#: bytes of VMEM the grouped product's kernel may hold (``ops/grouped_experts.py``):
+#: an expert's matrices twice, the run's and the next expert's fetched while it
+#: computes, in half of it, the tiles of rows and their temporaries in the rest
+_GROUPED_VMEM = 64 * 2**20
+
+
+def _tiles(pairs: int, cfg) -> int:
+    """The most tiles of ``expert_block`` rows that ``pairs`` token-expert
+    pairs fill when each held expert's run is cut into whole tiles: the
+    grouped product's grid and the block loop's longest run."""
+    E, B = cfg.experts_held, cfg.expert_block
+    return min(pairs, (pairs + E * (B - 1)) // B)
+
+
+def _grouped(pairs: int, experts, cfg) -> bool:
+    """Whether :func:`_experts_here` multiplies ``pairs`` (the static ``T *
+    K``) by the grouped product rather than the block loop: where the pairs
+    can fill more tiles than there are experts held, so that the loop would
+    read some expert's matrices more than once (a prompt chunk, not a decode
+    step's few pairs), and an expert's matrices fit twice in half of the
+    kernel's VMEM (SmallThinker's 11.8 MB do, DeepSeek's 88 MB and LongCat's
+    75 MB do not: their share of a chunk's pairs fills about one block an
+    expert, which the loop reads once)."""
+    expert_bytes = sum(math.prod(w.shape[1:]) * jnp.dtype(w.dtype).itemsize for w in jax.tree.leaves(experts))
+    return _tiles(pairs, cfg) > cfg.experts_held and 4 * expert_bytes <= _GROUPED_VMEM
+
+
+def _expert_counts(chosen, live, cfg):
+    """Each token-expert pair's expert among those held here, flat
+    (``experts_held`` where it is not this chip's: another chip's expert, an
+    expert that holds nothing, a padding row), and each held expert's pairs."""
+    E = cfg.experts_held
+    local = chosen - cfg.expert_offset
+    expert_of = jnp.where((local >= 0) & (local < E) & live[:, None], local, E).reshape(-1)
+    return expert_of, jnp.sum(expert_of[:, None] == jnp.arange(E)[None, :], axis=0).astype(jnp.int32)
+
+
 def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig, activation=_swiglu):
     """The part of the routed result that the experts held here give:
     ``sum over chosen experts e held here of gates_e FFN_e(x)``, where the
@@ -248,25 +285,30 @@ def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig, activatio
     the architecture's experts are another gated unit).
 
     A grouped product over uneven groups with nothing dropped: the
-    token-expert pairs that fall to this chip are ordered by expert, each
+    token-expert pairs that fall to this chip are ordered by expert and each
     expert's run is cut into blocks of ``expert_block`` pairs (the last one
-    part empty), and a loop over exactly the blocks in use multiplies each by
-    its expert's three matrices.  Work follows the pairs that came, not the
-    worst case.  Expert ids outside the held range are not this chip's,
-    whatever they are: another chip's experts, or experts that hold nothing
-    (a router wider than ``n_routed_experts``, whose caller adds what those
-    give).  Returns the result [T, hidden] float32 and the pairs computed."""
+    part empty).  Where :func:`_grouped` says so (a prompt chunk), the rows
+    are gathered once in that layout, each expert's matrices multiply all of
+    its blocks in one read (``ops/grouped_experts.py`` on a TPU, the same
+    tiles in ``jax.numpy`` elsewhere), and each token sums its pairs' rows;
+    otherwise (a decode step) a loop over exactly the blocks in use
+    multiplies each by its expert's three matrices and adds it in.  Either
+    way work follows the pairs that came, not the worst case, and the blocks
+    multiplied are the same.  Expert ids outside the held range are not this
+    chip's, whatever they are: another chip's experts, or experts that hold
+    nothing (a router wider than ``n_routed_experts``, whose caller adds what
+    those give).  Returns the result [T, hidden] float32 and the pairs
+    computed."""
     T, K = chosen.shape
     E, B, dt = cfg.experts_held, cfg.expert_block, cfg.dtype
-    local = chosen - cfg.expert_offset
-    here = (local >= 0) & (local < E) & live[:, None]
-    expert_of = jnp.where(here, local, E).reshape(-1)  # E: not ours
+    expert_of, count = _expert_counts(chosen, live, cfg)
     order = jnp.argsort(expert_of, stable=True).astype(jnp.int32)
-    count = jnp.sum(expert_of[:, None] == jnp.arange(E)[None, :], axis=0).astype(jnp.int32)
     first_pair = jnp.cumsum(count) - count
     blocks = (count + B - 1) // B
     last_block = jnp.cumsum(blocks)
     flat_gates = gates.reshape(-1)
+    if _grouped(T * K, experts, cfg):
+        return _grouped_product(x, expert_of, order, first_pair, blocks, last_block, flat_gates, experts, cfg, activation), jnp.sum(count)
 
     def one_block(b, out):
         e = jnp.sum(b >= last_block).astype(jnp.int32)  # the expert whose run holds block b
@@ -282,16 +324,57 @@ def _experts_here(x, chosen, gates, live, experts, cfg: DecoderConfig, activatio
     return out, jnp.sum(count)
 
 
+def _grouped_product(x, expert_of, order, first_pair, blocks, last_block, flat_gates, experts, cfg, activation):
+    """:func:`_experts_here`'s product for a prompt chunk: every pair here
+    gets a row of a buffer of :func:`_tiles` tiles, expert by expert from the
+    first tile, each expert's run starting a tile (the rows left in its last
+    tile carry a zero gate); a tile's rows are multiplied by its expert's
+    matrices and scaled by their gates, and each token sums its pairs' rows.
+    [T, hidden] float32."""
+    (T, H), N = x.shape, expert_of.shape[0]
+    E, B, dt = cfg.experts_held, cfg.expert_block, cfg.dtype
+    n_tiles = _tiles(N, cfg)
+    pair = jnp.arange(N, dtype=jnp.int32)
+    rank = jnp.zeros(N, jnp.int32).at[order].set(pair)  # where each pair stands in the expert order
+    here = expert_of < E
+    e = jnp.minimum(expert_of, E - 1)
+    row = jnp.where(here, (last_block[e] - blocks[e]) * B + rank - first_pair[e], n_tiles * B)  # past the end: not here
+    token = jnp.zeros(n_tiles * B, jnp.int32).at[row].set(pair // (N // T), mode="drop")
+    row_gates = jnp.zeros((n_tiles * B, 1), jnp.float32).at[row, 0].set(flat_gates, mode="drop")
+    used = last_block[-1]
+    # a step past the tiles in use reads the last one's blocks again: nothing is fetched, nothing computed
+    at = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), jnp.maximum(used - 1, 0))
+    tile_expert = jnp.minimum(jnp.sum(at[:, None] >= last_block[None, :], axis=1), E - 1).astype(jnp.int32)
+    xs = x[token]
+    if jax.default_backend() == "tpu":
+        from pathway_tpu.ops.grouped_experts import grouped_experts  # Pallas: a second to import, so only where it runs
+
+        ys = grouped_experts(
+            xs, row_gates, tile_expert, at, used[None], experts, activation=activation, dtype=dt, block=B, vmem_bytes=_GROUPED_VMEM
+        )
+    else:
+
+        def one_tile(args):
+            rows, g, e = args
+            p = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(w, e, keepdims=False), experts)
+            return activation(rows, p, dt) * g
+
+        ys = jax.lax.map(one_tile, (xs.reshape(n_tiles, B, H), row_gates.reshape(n_tiles, B, 1), tile_expert)).reshape(n_tiles * B, H)
+    mine = jnp.where(here[:, None], ys[jnp.minimum(row, n_tiles * B - 1)], 0.0)
+    return jnp.sum(mine.reshape(T, N // T, H), axis=1)
+
+
 def _mlp(h, lp, live, cfg: DecoderConfig):
-    """The layer's feed-forward half; returns what it adds and the
-    token-expert pairs (computed here, chosen anywhere)."""
+    """The layer's feed-forward half; returns what it adds, the
+    token-expert pairs (computed here, chosen anywhere) and whether the
+    experts took the grouped product (1 or 0)."""
     x = _rms(h, lp["mlp_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
     if "mlp" in lp:
-        return _swiglu(x, lp["mlp"], cfg.dtype), jnp.int32(0), jnp.int32(0)
+        return _swiglu(x, lp["mlp"], cfg.dtype), jnp.int32(0), jnp.int32(0), 0
     chosen, gates = _route(x, lp, cfg)
     routed, rows_here = _experts_here(x, chosen, gates, live, lp["experts"], cfg)
     rows_routed = jnp.sum(live).astype(jnp.int32) * cfg.num_experts_per_tok
-    return _swiglu(x, lp["shared"], cfg.dtype) + routed, rows_here, rows_routed
+    return _swiglu(x, lp["shared"], cfg.dtype) + routed, rows_here, rows_routed, int(_grouped(chosen.size, lp["experts"], cfg))
 
 
 def _logits(h, params, cfg: DecoderConfig):
@@ -408,11 +491,11 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Decod
             q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_blocks, lp, cfg, start=start, length=length
         )
         h = h + attended
-        added, rows_here, rows_routed = _mlp(h, lp, live, cfg)
+        added, rows_here, rows_routed, grouped = _mlp(h, lp, live, cfg)
         h = h + added
         stats = stats + jnp.stack([
             rows_here, rows_routed,
-            jnp.sum(selected & live[:, None]).astype(jnp.int32), jnp.sum(visible & live[:, None]).astype(jnp.int32),
+            jnp.sum(selected & live[:, None]).astype(jnp.int32), jnp.sum(visible & live[:, None]).astype(jnp.int32), jnp.int32(grouped),
         ])
     last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1)
     return _logits(last, params, cfg)[0], {"latent": latent_all, "index_k": index_all}, stats
@@ -467,7 +550,7 @@ def decode_step(params, ids, cache, slots, lengths, *, config: DecoderConfig):
             ))
         out, selected, visible = (jnp.stack(parts) for parts in zip(*outs))
         h = h + _mm("td,dc->tc", out, lp["o"])
-        added, rows_here, rows_routed = _mlp(h, lp, live, cfg)
+        added, rows_here, rows_routed, grouped = _mlp(h, lp, live, cfg)
         h = h + added
-        stats = stats + jnp.stack([rows_here, rows_routed, jnp.sum(selected), jnp.sum(visible)])
+        stats = stats + jnp.stack([rows_here, rows_routed, jnp.sum(selected), jnp.sum(visible), jnp.int32(grouped)])
     return _logits(h, params, cfg), {"latent": latent_all, "index_k": index_all}, stats

@@ -52,19 +52,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pathway_tpu.models.decoder import _decode_core, _experts_here, _logits, _mm, _prefill_core, _rms, _rotate, _rows_of, _swiglu
+from pathway_tpu.models.decoder import _decode_core, _experts_here, _grouped, _logits, _mm, _prefill_core, _rms, _rotate, _rows_of, _swiglu
 
 __all__ = ["ShortcutMoEDecoderConfig", "LONGCAT_FLASH_CHAT", "init_cache", "prefill", "decode_step", "STATS", "DISPATCH_TOKENS"]
 
 #: what both programs count, in the order of the vector they return: token-expert
 #: pairs the experts held here computed / pairs the router chose anywhere, the
 #: zero-computation experts among them / those of them whose expert computes
-#: nothing; query-key pairs that count (live queries, visible keys) / pairs the
-#: attention multiplies (a prompt chunk: the fused kernel's query tiles, each
+#: nothing / whether the experts took the grouped product (a layer a dispatch:
+#: :func:`pathway_tpu.models.decoder._grouped`); query-key pairs that count (live
+#: queries, visible keys) / pairs the attention multiplies (a prompt chunk: the fused kernel's query tiles, each
 #: against the key blocks its own last row can see and a tile of padding
 #: against none, as :func:`pathway_tpu.ops.selected_attention.query_tiles`
 #: plans them for the kernel; a decode step: every position of the cache)
-STATS = ("moe_rows_here", "moe_rows_routed", "moe_rows_zero", "mla_keys_visible", "mla_keys_multiplied")
+STATS = ("moe_rows_here", "moe_rows_routed", "moe_rows_zero", "moe_grouped_calls", "mla_keys_visible", "mla_keys_multiplied")
 
 #: what one more prefill dispatch costs beside its tokens, in tokens.  Little:
 #: the chunk's own products hide the read of the weights, and what a further
@@ -172,15 +173,16 @@ def _route(x, lp, cfg: ShortcutMoEDecoderConfig):
 
 def _moe(x, lp, live, cfg: ShortcutMoEDecoderConfig):
     """The routed branch of the normed rows ``x``: what the experts held here
-    give and the identity term, and the counts of the first three
+    give and the identity term, and the counts of the first four
     :data:`STATS` (pairs computed here, chosen anywhere, chosen among the
-    zero-computation experts)."""
+    zero-computation experts; whether the grouped product ran)."""
     chosen, gates = _route(x, lp, cfg)
     routed, rows_here = _experts_here(x, chosen, gates, live, lp["experts"], cfg)
     zero = chosen >= cfg.n_routed_experts
     identity = jnp.sum(jnp.where(zero, gates, 0.0), axis=1, keepdims=True) * x.astype(jnp.float32)
     rows_routed = jnp.sum(live).astype(jnp.int32) * cfg.moe_topk
-    return routed + identity, jnp.stack([rows_here, rows_routed, jnp.sum(zero & live[:, None]).astype(jnp.int32)])
+    grouped = jnp.int32(_grouped(chosen.size, lp["experts"], cfg))
+    return routed + identity, jnp.stack([rows_here, rows_routed, jnp.sum(zero & live[:, None]).astype(jnp.int32), grouped])
 
 
 def _layer(h, lp, cache, first, attend, live, cfg: ShortcutMoEDecoderConfig):

@@ -44,9 +44,11 @@ stored rotated at their absolute position).
   and cache.
 
 The routed experts go through :func:`pathway_tpu.models.decoder._experts_here`
-with ReGLU as their activation.  Weights and caches are ``config.dtype``
-(bfloat16); products accumulate in float32; the residual stream, norms, the
-router and the softmax are float32.
+with ReGLU as their activation: a prompt chunk's through its grouped product
+(``ops/grouped_experts.py`` on a TPU: every expert's matrices read once a
+chunk), a decode step's through its block loop.  Weights and caches are
+``config.dtype`` (bfloat16); products accumulate in float32; the residual
+stream, norms, the router and the softmax are float32.
 """
 
 from __future__ import annotations
@@ -58,18 +60,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pathway_tpu.models.decoder import _experts_here, _logits, _mm, _rms
+from pathway_tpu.models.decoder import _expert_counts, _experts_here, _grouped, _logits, _mm, _rms
 
 __all__ = ["WindowMoEDecoderConfig", "SMALLTHINKER_21BA3B", "init_cache", "prefill", "decode_step", "STATS", "DISPATCH_TOKENS"]
 
 #: what both programs count, in the order of the vector they return: token-expert
 #: pairs the experts held here computed / pairs the router chose / rows the expert
-#: loop multiplied (its blocks of ``expert_block``, padding included); query-key
-#: pairs inside a live query's window / pairs the window layers multiplied (a prompt
+#: product multiplied (its blocks of ``expert_block``, padding included, the same
+#: on either path of :func:`pathway_tpu.models.decoder._experts_here`) / whether it
+#: took the grouped product (a layer a dispatch); query-key pairs inside a live
+#: query's window / pairs the window layers multiplied (a prompt
 #: chunk: the fused kernel's query tiles against the key blocks each visits, as
 #: :func:`pathway_tpu.ops.selected_attention.window_tiles` plans them; a decode
 #: step: the whole ring)
-STATS = ("moe_rows_here", "moe_rows_routed", "moe_rows_multiplied", "swa_keys_in_window", "swa_keys_multiplied")
+STATS = ("moe_rows_here", "moe_rows_routed", "moe_rows_multiplied", "moe_grouped_calls", "swa_keys_in_window", "swa_keys_multiplied")
 
 #: what one more prefill dispatch costs beside its tokens, in tokens: every weight
 #: is read again (6.4 GB of eight whole layers at the published widths, 7.8 ms at
@@ -207,17 +211,16 @@ def _qkv(h, lp, pos, rope: bool, cfg: WindowMoEDecoderConfig):
 
 def _moe(a, lp, chosen, gates, live, cfg: WindowMoEDecoderConfig):
     """What the experts held here add to the rows ``a``, and the counts of the
-    first three :data:`STATS`: the pairs computed here, the pairs chosen, the
-    rows the expert loop multiplies (each held expert's pairs in whole blocks)."""
+    first four :data:`STATS`: the pairs computed here, the pairs chosen, the
+    rows the expert product multiplies (each held expert's pairs in whole
+    blocks, on either path), whether it took the grouped product."""
     x = _rms(a, lp["mlp_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
     added, rows_here = _experts_here(x, chosen, gates, live, lp["experts"], cfg, activation=_reglu)
-    E, B = cfg.experts_held, cfg.expert_block
-    local = chosen - cfg.expert_offset
-    held = jnp.where((local >= 0) & (local < E) & live[:, None], local, E)
-    per_expert = jnp.sum(held[..., None] == jnp.arange(E), axis=(0, 1))
-    multiplied = jnp.sum((per_expert + B - 1) // B) * B
+    B = cfg.expert_block
+    multiplied = jnp.sum((_expert_counts(chosen, live, cfg)[1] + B - 1) // B) * B
     routed = jnp.sum(live) * cfg.moe_num_active_primary_experts
-    return added, jnp.stack([rows_here, routed, multiplied]).astype(jnp.int32)
+    grouped = _grouped(chosen.size, lp["experts"], cfg)
+    return added, jnp.stack([rows_here, routed, multiplied, jnp.int32(grouped)]).astype(jnp.int32)
 
 
 def _attend(q, keys, values, visible, start, first_key, length, window, cfg: WindowMoEDecoderConfig):

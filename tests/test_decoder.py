@@ -175,8 +175,8 @@ def test_the_shares_add_up(model):
     gkey = family._group_key(GROUP)
     x, shared, chosen, gates = family._route(h, lp, gkey=gkey, precision="f32")
     uncut = np.asarray(shared + family._routed(x, chosen, gates, lp, GROUP, "f32"))
-    whole, here, routed = decoder._mlp(h, lp, live, cfg)
-    assert np.abs(np.asarray(whole) - uncut).max() < 2e-5 and int(here) == int(routed) == 24 * 4
+    whole, here, routed, grouped = decoder._mlp(h, lp, live, cfg)
+    assert np.abs(np.asarray(whole) - uncut).max() < 2e-5 and int(here) == int(routed) == 24 * 4 and grouped == 1
     total, pairs = np.zeros_like(uncut), 0
     for share in range(4):
         group = dict(GROUP, n_routed_experts=4, expert_offset=4 * share)
@@ -184,7 +184,7 @@ def test_the_shares_add_up(model):
         mine = jax.tree.map(lambda w: w[4 * share : 4 * share + 4], lp["experts"])
         # another offset draws another share of the same experts
         assert all(np.array_equal(np.asarray(a, np.float32), np.asarray(b)) for a, b in zip(jax.tree.leaves(drawn), jax.tree.leaves(mine)))
-        part, here, routed = decoder._mlp(h, dict(lp, experts=mine), live, config_of(group))
+        part, here, routed, _ = decoder._mlp(h, dict(lp, experts=mine), live, config_of(group))
         reference_part = shared + family._routed(x, chosen, gates, dict(lp, experts=mine), group, "f32")
         assert np.abs(np.asarray(part) - np.asarray(reference_part)).max() < 2e-5
         total += np.asarray(part)
@@ -645,6 +645,28 @@ def test_the_grouped_attention_kernel_compiles_for_a_v5e_at_the_published_widths
         window=window, block_q=BLOCK_Q, block_k=512,
     ).compile()
     assert "selected_attention" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chunk", [512, 2048, 2560])
+def test_the_grouped_experts_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, chunk):
+    """``ops/grouped_experts.py`` as ``models/window_moe_decoder.py`` calls it
+    for a prompt chunk: 64 ReGLU experts of 2,560 -> 768 -> 2,560, one
+    expert's three matrices a block (11.8 MB, twice, in the kernel's VMEM),
+    the chunk's 6 pairs a token in as many tiles of 128 rows as they can
+    fill, the schedule as scalars."""
+    from pathway_tpu.models import decoder as mla_decoder
+    from pathway_tpu.models.window_moe_decoder import SMALLTHINKER_21BA3B, _reglu
+    from pathway_tpu.ops.grouped_experts import grouped_experts
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    tiles, bf16 = mla_decoder._tiles(chunk * 6, SMALLTHINKER_21BA3B), jnp.bfloat16
+    experts = {"gate": shape((64, 2560, 768), bf16), "up": shape((64, 2560, 768), bf16), "down": shape((64, 768, 2560), bf16)}
+    steps = shape((tiles,), jnp.int32)
+    compiled = grouped_experts.lower(
+        shape((tiles * 128, 2560), bf16), shape((tiles * 128, 1), jnp.float32), steps, steps, shape((1,), jnp.int32), experts,
+        activation=_reglu, dtype=bf16, block=128, vmem_bytes=mla_decoder._GROUPED_VMEM,
+    ).compile()
+    assert "grouped_experts" in compiled.as_text()
 
 
 @pytest.mark.parametrize("chunk", [512, 2560])

@@ -152,7 +152,7 @@ def test_the_shares_add_up(model):
     whole, _, counted = decoder._layer(h, lp, None, 0, still, live, cfg)
     zero = int(np.sum(np.asarray(chosen) >= 16))
     assert np.abs(np.asarray(whole) - uncut).max() < TOLERANCE and 0 < zero < 24 * 4
-    assert list(np.asarray(counted)) == [24 * 4 - zero, 24 * 4, zero]
+    assert list(np.asarray(counted)) == [24 * 4 - zero, 24 * 4, zero, 1]  # 96 pairs fill more tiles of 4 than 16 experts: grouped
     dense_path = np.asarray(h + dense0 + dense1)  # what every chip computes alike
     total, pairs = np.zeros_like(uncut), 0
     for share in range(4):
@@ -182,7 +182,7 @@ def test_zero_computation_experts_alone_return_the_gated_input(model):
     s = jax.nn.softmax(jnp.einsum("tc,ce->te", x, lp["router"], precision=jax.lax.Precision.HIGHEST), axis=-1)
     gated = 6.0 * jnp.sum(jax.lax.top_k(s[:, 16:], 4)[0], axis=1, keepdims=True) * x
     assert np.abs(np.asarray(branch) - np.asarray(gated)).max() < 1e-6
-    assert list(np.asarray(counted)) == [0, 7 * 4, 7 * 4]  # live rows only
+    assert list(np.asarray(counted)) == [0, 7 * 4, 7 * 4, 1]  # live rows only; the grouped product, over no pair here
     # the gates are the scores times the factor, not renormalised: they do not add up to it
     _chosen, gates = decoder._route(x, lp, cfg)
     assert float(jnp.abs(jnp.sum(gates, axis=1) - 6.0).min()) > 0.5
@@ -416,7 +416,9 @@ def test_the_prefill_through_the_fused_kernel_is_the_jax_numpy_branch_and_counts
     the logits are the ``jax.numpy`` branch's, every row of the latent caches
     is finite (a padding row's output reaches them and, through decode's
     weighted sum, every later token), and ``mla_keys_multiplied`` is what the
-    schedule multiplies."""
+    schedule multiplies (the experts' kernel, ``ops/grouped_experts.py``, in
+    interpret mode too)."""
+    from pathway_tpu.ops import grouped_experts as experts_kernel
     from pathway_tpu.ops import selected_attention as kernel
 
     cfg, params, ids = model["cfg"], model["params"], model["ids"]
@@ -442,6 +444,7 @@ def test_the_prefill_through_the_fused_kernel_is_the_jax_numpy_branch_and_counts
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(kernel, "selected_attention", interpreted)
+    monkeypatch.setattr(experts_kernel, "grouped_experts", lambda *a, _k=experts_kernel.grouped_experts, **kw: _k(*a, **kw, interpret=True))
     fused, cache, counted = generation()
     assert traced == [(4, 16, 16)] * 4 + [(4, 24, 16)] * 4  # two chunks through four sublayers
     assert all(np.abs(a - b).max() < TOLERANCE for a, b in zip(fused, plain))

@@ -121,7 +121,10 @@ def test_what_the_counters_count(model):
     """A chunk's window pairs are its live queries' keys inside the window
     and what the kernel's query tiles multiply (8 rows, blocks of 8 from the
     one each tile's window reaches); a decode step multiplies the ring; the
-    expert loop multiplies whole blocks of 4 rows an expert."""
+    expert product multiplies whole blocks of 4 rows an expert, through the
+    grouped product in each layer of a chunk (32 and 16 pairs can fill 14 and
+    10 tiles, more than the 8 experts) and the block loop in a decode step's
+    (2 pairs)."""
     cache = decoder.init_cache(model["cfg"], 1, POSITIONS)
     _, cache, first = _prefill(model, cache, 0, 0, model["ids"][:16], 16)
     _, cache, second = _prefill(model, cache, 0, 16, model["ids"][16:21], 8)
@@ -135,7 +138,8 @@ def test_what_the_counters_count(model):
     assert [c["moe_rows_routed"] for c in counts] == [4 * 2 * 16, 4 * 2 * 5, 4 * 2] == [c["moe_rows_here"] for c in counts]
     for c in counts:
         assert c["moe_rows_multiplied"] % 4 == 0 and c["moe_rows_here"] <= c["moe_rows_multiplied"] <= c["moe_rows_here"] + 4 * 8 * 3
-    assert set(names) == {"moe_rows_here", "moe_rows_routed", "moe_rows_multiplied", "swa_keys_in_window", "swa_keys_multiplied"}
+    assert [c["moe_grouped_calls"] for c in counts] == [4, 4, 0]
+    assert set(names) == {"moe_rows_here", "moe_rows_routed", "moe_rows_multiplied", "moe_grouped_calls", "swa_keys_in_window", "swa_keys_multiplied"}
 
 
 def _per_token(x, chosen, gates, experts, activation, dt=jnp.float32):
@@ -148,13 +152,19 @@ def _per_token(x, chosen, gates, experts, activation, dt=jnp.float32):
     return out
 
 
+@pytest.mark.parametrize("path", ["grouped", "loop"])
 @pytest.mark.parametrize("activation", ["reglu", "swiglu by default"])
-def test_the_expert_loop_is_the_per_token_sum_with_either_activation(model, activation):
+def test_the_expert_loop_is_the_per_token_sum_with_either_activation(model, monkeypatch, activation, path):
+    """12 tokens, 2 pairs each, can fill 12 tiles of 4 against 8 experts: the
+    grouped product; the block loop where ``_grouped`` is told no."""
     cfg = model["cfg"]
     lp = model["params"]["layers"][2]
     x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (12, 64)), jnp.float32)
     chosen, gates = decoder._route(x, lp, cfg)
     live = jnp.ones((12,), bool)
+    assert mla_decoder._grouped(chosen.size, lp["experts"], cfg)
+    if path == "loop":
+        monkeypatch.setattr(mla_decoder, "_grouped", lambda *a: False)
     if activation == "reglu":
         got, pairs = mla_decoder._experts_here(x, chosen, gates, live, lp["experts"], cfg, activation=decoder._reglu)
         want = _per_token(x, chosen, gates, lp["experts"], decoder._reglu)
@@ -164,6 +174,114 @@ def test_the_expert_loop_is_the_per_token_sum_with_either_activation(model, acti
         got, pairs = mla_decoder._experts_here(x, chosen, gates, live, lp["experts"], cfg)
         want = _per_token(x, chosen, gates, lp["experts"], mla_decoder._swiglu)
     assert int(pairs) == 24 and np.abs(np.asarray(got) - want).max() < 1e-5
+
+
+#: the token-expert pairs of 16 tokens, 2 a token, the last two tokens padding: in blocks of 4, one held expert gets no
+#: pair, one exactly a block and one more than two blocks.  "whole": the 8 experts held; "share": experts 4-7 held, ids
+#: 0-3 another chip's and 9 past the router's 8 experts (a router wider than the experts that hold parameters, as
+#: LongCat's, whose caller adds what those give)
+PAIRS = {
+    "whole": [(1, 2)] * 4 + [(2, 3)] * 5 + [(4, 5), (6, 7), (3, 4), (5, 6), (7, 3), (4, 6), (5, 7)],
+    "share": [(5, 0)] * 4 + [(6, 9)] * 9 + [(7, 2)] * 3,
+}
+
+
+def _pairs_case(model, held: str):
+    """The layer's experts held in ``held``'s share, its configuration, the rows, pairs, gates and live rows."""
+    lp = model["params"]["layers"][2]
+    offset, E = (0, 8) if held == "whole" else (4, 4)
+    cfg = config_of(dict(GROUP, experts_held=E, expert_offset=offset))
+    experts = jax.tree.map(lambda w: w[offset : offset + E], lp["experts"])
+    chosen = jnp.asarray(PAIRS[held], jnp.int32)
+    gates = jnp.asarray(np.random.default_rng(2).uniform(0.1, 1.0, chosen.shape), jnp.float32)
+    x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (16, 64)), jnp.float32)
+    local = chosen - offset
+    here = (local >= 0) & (local < E) & (jnp.arange(16) < 14)[:, None]
+    return dict(lp, experts=experts), cfg, x, chosen, gates, jnp.arange(16) < 14, np.bincount(np.asarray(local)[np.asarray(here)], minlength=E)
+
+
+@pytest.mark.parametrize("activation", ["reglu", "swiglu"])
+@pytest.mark.parametrize("held", sorted(PAIRS))
+def test_the_grouped_product_is_the_block_loop(model, monkeypatch, held, activation):
+    """The grouped product (rows gathered once, each expert's tiles in one
+    read, each token's pairs summed) gives the block loop's result to float32's
+    order of summation, and the per-token sum, with the same pairs counted:
+    over an expert held with no pair, one with exactly a block, one with more,
+    pairs of another chip's experts and past the router's, and padding rows,
+    which get nothing."""
+    lp, cfg, x, chosen, gates, live, count = _pairs_case(model, held)
+    act = {"reglu": decoder._reglu, "swiglu": mla_decoder._swiglu}[activation]
+    assert count.min() == 0 and 4 in count and count.max() == 9
+    assert mla_decoder._grouped(chosen.size, lp["experts"], cfg)
+    grouped, pairs = mla_decoder._experts_here(x, chosen, gates, live, lp["experts"], cfg, activation=act)
+    monkeypatch.setattr(mla_decoder, "_grouped", lambda *a: False)
+    loop, loop_pairs = mla_decoder._experts_here(x, chosen, gates, live, lp["experts"], cfg, activation=act)
+    local = chosen - cfg.expert_offset
+    here = (local >= 0) & (local < cfg.experts_held) & live[:, None]
+    want = _per_token(x, jnp.where(here, local, 0), jnp.where(here, gates, 0.0), lp["experts"], act)
+    assert int(pairs) == int(loop_pairs) == int(count.sum()) == int(jnp.sum(here))
+    assert np.abs(np.asarray(grouped) - np.asarray(loop)).max() < 1e-5 and np.abs(np.asarray(grouped) - want).max() < 1e-5
+    assert float(jnp.abs(grouped[14:]).max()) == 0.0 and np.abs(want).max() > SEEN
+
+
+@pytest.mark.parametrize("held", sorted(PAIRS))
+def test_the_experts_kernel_multiplies_the_rows_the_counter_counts(model, monkeypatch, held):
+    """``_experts_here``'s TPU branch, ``ops/grouped_experts.py`` in interpret
+    mode: the kernel's grid computes its ``used`` tiles of 4 rows, expert by
+    expert (each expert's tiles one run: one weight read), and repeats the
+    last one's blocks past them; ``_moe``'s ``moe_rows_multiplied`` is those
+    rows, so ``expert_rows_useful_pct`` reads what the grouped product
+    multiplies; the result is the ``jax.numpy`` branch's."""
+    from pathway_tpu.ops import grouped_experts as kernel
+
+    lp, cfg, x, chosen, gates, live, count = _pairs_case(model, held)
+    plain, plain_counted = decoder._moe(x, lp, chosen, gates, live, cfg)
+    seen = []
+
+    def interpreted(*args, _kernel=kernel.grouped_experts, **kwargs):
+        seen.append((args[0].shape[0] // kwargs["block"], int(args[4][0]), np.asarray(args[2]).tolist(), np.asarray(args[3]).tolist()))
+        return _kernel(*args, **kwargs, interpret=True)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kernel, "grouped_experts", interpreted)
+    fused, counted = decoder._moe(x, lp, chosen, gates, live, cfg)
+    (tiles, used, tile_expert, tile_at), = seen
+    names = dict(zip(decoder.STATS, np.asarray(counted).tolist()))
+    assert used == int(np.sum(-(-count // 4))) and names["moe_rows_multiplied"] == 4 * used and names["moe_grouped_calls"] == 1
+    assert tiles == mla_decoder._tiles(chosen.size, cfg) > used
+    assert tile_expert[:used] == sorted(np.repeat(np.arange(cfg.experts_held), -(-count // 4)).tolist())
+    assert tile_at == list(range(used)) + [used - 1] * (tiles - used) and tile_expert[used:] == [tile_expert[used - 1]] * (tiles - used)
+    assert np.asarray(counted).tolist() == np.asarray(plain_counted).tolist()
+    assert np.abs(np.asarray(fused) - np.asarray(plain)).max() < 1e-5
+
+
+def _experts_of(E: int, hidden: int, ffn: int):
+    leaf = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16)
+    return {"gate": leaf(E, hidden, ffn), "up": leaf(E, hidden, ffn), "down": leaf(E, ffn, hidden)}
+
+
+@pytest.mark.parametrize(
+    "cell, tokens, grouped",
+    [
+        ("smallthinker", 2560, True), ("smallthinker", 2048, True), ("smallthinker", 512, True),  # prompt chunks
+        ("smallthinker", 8, False), ("smallthinker", 1, False),  # decode steps
+        ("deepseek-ep16", 2560, False), ("deepseek-ep16", 8, False), ("longcat-ep32", 2560, False),  # experts too large
+    ],
+)
+def test_the_path_follows_the_shapes(cell, tokens, grouped):
+    """At the cells' published widths: SmallThinker's prompt chunks take the
+    grouped product (6 pairs a token fill more tiles of 128 than its 64
+    experts) and its decode steps (6-48 pairs) the loop; DeepSeek's and
+    LongCat's 16 held experts of 88 and 75 MB cannot stay in the kernel's
+    VMEM while the next is fetched, so their chunks keep the loop."""
+    from pathway_tpu.models.shortcut_moe_decoder import ShortcutMoEDecoderConfig
+
+    cfg, K, experts = {
+        "smallthinker": (decoder.SMALLTHINKER_21BA3B, 6, _experts_of(64, 2560, 768)),
+        "deepseek-ep16": (mla_decoder.DecoderConfig(experts_held=16), 8, _experts_of(16, 7168, 2048)),
+        "longcat-ep32": (ShortcutMoEDecoderConfig(experts_held=16), 12, _experts_of(16, 6144, 2048)),
+    }[cell]
+    assert mla_decoder._grouped(tokens * K, experts, cfg) is grouped
 
 
 def test_the_two_halves_of_the_experts_add_up_to_the_uncut_layer(model):
@@ -265,10 +383,12 @@ def test_bfloat16_stays_near_the_reference_and_the_fp8_control_does_not(model):
 
 
 def test_the_prefill_through_the_fused_kernel_is_the_jax_numpy_branch(model, monkeypatch):
-    """``_attend``'s TPU branch, the grouped kernel in interpret mode, over the
+    """``_attend``'s TPU branch, the grouped kernel in interpret mode (and the
+    experts' kernel, ``ops/grouped_experts.py``, so), over the
     plan's three chunks (one starts inside a window; the last holds padding,
     one of its tiles whole), then decode: the logits are the ``jax.numpy``
     branch's and every row of every cache is finite."""
+    from pathway_tpu.ops import grouped_experts as experts_kernel
     from pathway_tpu.ops import selected_attention as kernel
 
     plain, _ = _generation(model, PLAN, 40)
@@ -280,6 +400,7 @@ def test_the_prefill_through_the_fused_kernel_is_the_jax_numpy_branch(model, mon
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(kernel, "grouped_attention", interpreted)
+    monkeypatch.setattr(experts_kernel, "grouped_experts", lambda *a, _k=experts_kernel.grouped_experts, **kw: _k(*a, **kw, interpret=True))
     prefill = jax.jit(lambda *a, **k: decoder.prefill(*a, **k), static_argnames=("config",))  # traced anew, on this branch
     fused, cache = _generation(dict(model, prefill=prefill), PLAN, 40)
     assert traced[:4] == [((4, 16, 16), (2, 48, 16), None)] + [((4, 16, 16), (2, 32, 16), 16)] * 3
