@@ -29,7 +29,10 @@ counters are monotonic:
   tokens as given and as padded to chunk buckets, its prefill dispatches,
   the tokens chosen and the decode steps; ``gen_logit_rows``, the rows of
   logits kept, and ``gen_logit_rows_early``, those in their host array
-  while the answer's last step was still running), ``moe_rows_here`` /
+  while the answer's last step was still running), ``mtp_drafts`` /
+  ``mtp_drafts_accepted`` (drafts the MTP layer made that a step verified /
+  those it kept: in the drafting loop a step is one verify, and
+  ``gen_decode_steps`` counts them), ``moe_rows_here`` /
   ``moe_rows_routed`` (token-expert pairs the experts held here computed /
   pairs the router chose anywhere) and ``dsa_keys_selected`` /
   ``dsa_keys_scored`` (keys the queries attended to / keys their indexer
@@ -119,6 +122,8 @@ _counters: dict[str, int] = {
     "gen_decode_steps": 0,
     "gen_logit_rows": 0,
     "gen_logit_rows_early": 0,
+    "mtp_drafts": 0,
+    "mtp_drafts_accepted": 0,
     "moe_rows_here": 0,
     "moe_rows_routed": 0,
     "moe_rows_multiplied": 0,

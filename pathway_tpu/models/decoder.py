@@ -1,13 +1,19 @@
-"""A causal decoder with a latent (MLA) cache, lightning-indexer sparse
-attention and a share of routed experts -- the generation stage.
+"""A causal decoder with a latent (MLA) cache, optional lightning-indexer
+sparse attention, routed experts of which this chip holds a share, and an
+optional multi-token-prediction (MTP) layer that drafts the next token but
+one -- the generation stage.
 
-The architecture is DeepSeek-V3.2-Exp's (``config.json`` keys keep their
-published names in :class:`DecoderConfig`); three more keys say which share
-of a layer this chip holds: ``experts_held`` / ``expert_offset`` (the router
-keeps its published width and its experts per token, the chip computes its
-own experts' part of the result and leaves out what the absent ones would
-add) and ``vocab_held`` (embedding, head, logits and the greedy choice are
-over that slice).
+Two architectures share this module.  DeepSeek-V3.2-Exp's
+(:data:`DEEPSEEK_V32_EXP`; ``config.json`` keys keep their published names in
+:class:`DecoderConfig`) has the indexer; GLM-4.7-Flash's
+(:data:`GLM_4_7_FLASH`) has none (``index_topk`` 0: no indexer weights, no
+``index_k`` cache, every earlier key visible), plain rope (``rope_scaling``
+``None``) and one MTP layer (``num_nextn_predict_layers`` 1).  Three more
+keys say which share of a layer this chip holds: ``experts_held`` /
+``expert_offset`` (the router keeps its published width and its experts per
+token, the chip computes its own experts' part of the result and leaves out
+what the absent ones would add) and ``vocab_held`` (embedding, head, logits
+and the greedy choice are over that slice).
 
 Two programs over one pre-sized cache, both pure functions of ``(params,
 ..., cache)`` that return the cache they were given, updated in place when
@@ -22,14 +28,23 @@ the caller donates it (:class:`pathway_tpu.parallel.JittedDecoder` does):
   the tile is padding, elsewhere as the same loop written in ``jax.numpy``
   over every block for every query.
 - :func:`decode_step` -- one new token for each of a few sequences, in the
-  absorbed form: 128 query heads against one 576-wide latent row a token.
+  absorbed form: the query heads against one 576-wide latent row a token.
 
-Per layer the cache holds two kinds of state side by side: the latent row
-``[cKV; k_rope]`` (``kv_lora_rank + qk_rope_head_dim`` values a token) and
-the indexer's key (``index_head_dim`` values a token).  A query attends to
-the ``index_topk`` keys its indexer scores highest among those before it;
-the selection is a mask at the exact k-th largest score (a bisection over
-the scores' bit patterns, no sort), so prefill and decode share one rule.
+A configuration with an MTP layer is served by two more programs in their
+place, which the executor takes where the module offers them
+(:func:`prefill_drafted`, :func:`verify_step`): the prompt's chunks fill the
+MTP layer's own latent cache beside the main one and the last returns the
+first draft; each step then runs the pending token and the draft through
+the main stack at once, two queries causal between them, keeps the draft
+where the main model would have chosen it, and drafts again (below).
+
+Per layer the cache holds the latent row ``[cKV; k_rope]``
+(``kv_lora_rank + qk_rope_head_dim`` values a token) and, where there is an
+indexer, the indexer's key (``index_head_dim`` values a token).  A query
+attends to the ``index_topk`` keys its indexer scores highest among those
+before it; the selection is a mask at the exact k-th largest score (a
+bisection over the scores' bit patterns, no sort), so prefill and decode
+share one rule.
 
 Weights and caches are ``config.dtype`` (bfloat16); products accumulate in
 float32; the residual stream, norms, the router, the indexer's scores and
@@ -47,6 +62,20 @@ selection's place) and :func:`_experts_here`, with ``_logits``, ``_swiglu``,
 :func:`_experts_here` with its experts' activation and every expert held,
 with ``_logits``, ``_rms`` and ``_mm``.  A change to one of them for one
 generator is measured on the others.
+
+**The MTP layer** (DeepSeek-V3 report section 2.2; vLLM's
+``deepseek_mtp.py``).  Position ``i`` pairs the main model's final-normed
+hidden state ``ĥ_i`` with the next token ``t_{i+1}``: ``x_i = [RMS(E
+t_{i+1}; w_e) ; RMS(ĥ_i; w_h)] W_eh`` (the embedding half zero at position
+0), ``u_i`` one full routed layer over ``x_i`` with its own latent cache row
+at ``i``, and ``draft_i = argmax RMS(u_i; w_sh) W_head``, the guess for
+``t_{i+2}``; embedding and head are the main model's.  A verify step holds a
+length ``L``, the pending token ``t_L`` and a draft ``d``: the main stack runs
+``[t_L, d]`` at ``L`` and ``L + 1``; where ``argmax row_L`` is ``d`` both ``d``
+and ``argmax row_{L+1}`` are emitted and ``L' = L + 2``, else ``argmax row_L``
+alone and ``L' = L + 1`` (the rows at ``L + 1`` are written again by the next
+step, the cache being indexed by position); the MTP layer runs at ``L`` and
+``L + 1`` and the next draft is the one from ``L' - 1``.
 """
 
 from __future__ import annotations
@@ -59,7 +88,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["DecoderConfig", "DEEPSEEK_V32_EXP", "init_cache", "prefill", "decode_step", "STATS", "DISPATCH_TOKENS"]
+__all__ = [
+    "DecoderConfig", "DEEPSEEK_V32_EXP", "GLM_4_7_FLASH", "init_cache", "prefill", "decode_step", "prefill_drafted", "verify_step", "STATS",
+    "DISPATCH_TOKENS",
+]
 
 #: what both programs count, in the order of the vector they return
 STATS = ("moe_rows_here", "moe_rows_routed", "dsa_keys_selected", "dsa_keys_scored", "moe_grouped_calls")
@@ -93,14 +125,16 @@ class DecoderConfig:
     v_head_dim: int = 128
     index_n_heads: int = 64
     index_head_dim: int = 128
-    index_topk: int = 2048
+    index_topk: int = 2048  # 0: no indexer, every earlier key visible
     rope_theta: float = 10000.0
-    rope_scaling: tuple = (
+    rope_scaling: tuple | None = (  # None: plain rope, and the plain softmax scale
         ("beta_fast", 32), ("beta_slow", 1), ("factor", 40), ("mscale", 1),
         ("mscale_all_dim", 1), ("original_max_position_embeddings", 4096), ("type", "yarn"),
     )
     rms_norm_eps: float = 1e-6
     vocab_size: int = 129280  # published; ``vocab_held`` rows of it live here
+    # MTP layers served; DeepSeek-V3.2-Exp publishes one, which is not served here (its configuration says so)
+    num_nextn_predict_layers: int = 0
     # --- this chip's share of a layer
     experts_held: int = 256
     expert_offset: int = 0
@@ -117,6 +151,8 @@ class DecoderConfig:
 
     @property
     def softmax_scale(self) -> float:
+        if self.rope_scaling is None:
+            return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
         rs = dict(self.rope_scaling)
         m = 0.1 * rs["mscale_all_dim"] * math.log(rs["factor"]) + 1.0
         return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m
@@ -124,9 +160,13 @@ class DecoderConfig:
     def inv_freq(self) -> np.ndarray:
         """YaRN: the published frequencies where a rotation completes often
         within the original context, the interpolated ones where it does not,
-        a linear ramp between."""
-        rs, dim = dict(self.rope_scaling), self.qk_rope_head_dim
+        a linear ramp between; the published ones alone without
+        ``rope_scaling``."""
+        dim = self.qk_rope_head_dim
         freq = self.rope_theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        if self.rope_scaling is None:
+            return freq
+        rs = dict(self.rope_scaling)
 
         def dim_of(rotations: float) -> float:
             return dim * math.log(rs["original_max_position_embeddings"] / (rotations * 2 * math.pi)) / (2 * math.log(self.rope_theta))
@@ -140,15 +180,30 @@ class DecoderConfig:
 #: the published configuration, uncut
 DEEPSEEK_V32_EXP = DecoderConfig()
 
+#: GLM-4.7-Flash's published configuration (``glm4_moe_lite``), uncut: MLA with
+#: no indexer and plain rope over all 64 rope dimensions, one dense layer, 64
+#: routed experts of 1,536 with 4 a token in one group, one MTP layer
+GLM_4_7_FLASH = DecoderConfig(
+    hidden_size=2048, num_hidden_layers=47, first_k_dense_replace=1, intermediate_size=10240, moe_intermediate_size=1536,
+    n_routed_experts=64, n_shared_experts=1, num_experts_per_tok=4, n_group=1, topk_group=1, routed_scaling_factor=1.8,
+    num_attention_heads=20, q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    index_n_heads=0, index_head_dim=0, index_topk=0, rope_theta=1000000.0, rope_scaling=None, rms_norm_eps=1e-5,
+    vocab_size=154880, num_nextn_predict_layers=1, experts_held=64, vocab_held=154880,
+)
+
 
 def init_cache(config: DecoderConfig, slots: int, positions: int) -> dict:
-    """The two kinds of state of every layer, for ``slots`` sequences of up
-    to ``positions`` tokens, zeroed."""
+    """The state of every layer, for ``slots`` sequences of up to
+    ``positions`` tokens, zeroed: latent rows, the indexer's keys where there
+    is an indexer, and the MTP layer's own latent rows where there is one."""
     shape = (config.num_hidden_layers, slots, positions)
-    return {
-        "latent": jnp.zeros((*shape, config.latent_width), config.dtype),
-        "index_k": jnp.zeros((*shape, config.index_head_dim), config.dtype),
-    }
+    cache = {"latent": jnp.zeros((*shape, config.latent_width), config.dtype)}
+    if config.index_topk:
+        cache["index_k"] = jnp.zeros((*shape, config.index_head_dim), config.dtype)
+    if config.num_nextn_predict_layers:
+        # one MTP layer drafts one token a step (a second, chained, is not served)
+        cache["mtp"] = jnp.zeros((1, slots, positions, config.latent_width), config.dtype)
+    return cache
 
 
 # ------------------------------------------------------------------ pieces
@@ -201,7 +256,8 @@ def _select(scores, visible, k: int):
 
 def _attention_inputs(h, lp, pos, cfg: DecoderConfig):
     """What both forms of attention share: the queries, the indexer's
-    queries and weights, and the two rows each token adds to the cache."""
+    queries and weights, and the two rows each token adds to the cache (the
+    indexer's three ``None`` where there is no indexer)."""
     dt, eps = cfg.dtype, cfg.rms_norm_eps
     T = h.shape[0]
     inv_freq = jnp.asarray(cfg.inv_freq(), jnp.float32)
@@ -214,6 +270,8 @@ def _attention_inputs(h, lp, pos, cfg: DecoderConfig):
     latent = jnp.concatenate(
         [_rms(kva[:, : cfg.kv_lora_rank], lp["kv_norm"], eps), _rotate(kva[:, cfg.kv_lora_rank :], pos, inv_freq)], axis=-1
     ).astype(dt)
+    if not cfg.index_topk:
+        return q_nope, q_rope, latent, None, None, None
     qi = _mm("tr,rd->td", cq, lp["idx_q"]).reshape(T, cfg.index_n_heads, cfg.index_head_dim)
     qi = jnp.concatenate([_rotate(qi[..., :rope], pos, inv_freq), qi[..., rope:]], axis=-1).astype(dt)
     ki = _mm("tc,cd->td", x, lp["idx_k"])
@@ -466,23 +524,38 @@ def _prefill_attention(q_nope, q_rope, qi, wi, latent_rows, index_rows, pos, n_b
     return _prefill_core(q_nope, q_rope, latent_rows, selected, n_blocks, lp, cfg, start, length), selected, visible
 
 
-def prefill(params, ids, cache, slot, start, length, last=True, *, config: DecoderConfig):
-    """One bucket of a prompt: ``ids`` [C] (``length`` of them real, the rest
-    padding) are the tokens ``start .. start + C`` of the sequence in
-    ``slot``.  Returns float32 logits over the held vocabulary at the last
-    real token, the cache with the chunk's rows written, and the counts of
-    :data:`STATS`.  ``start + C`` may not pass the cache's positions.
-    ``last`` (whether the prompt ends in this chunk) is the executor's to
-    say and changes nothing here: every layer runs for every token."""
-    cfg = config
+def _chunk_layer(h, lp, rows_all, layer: int, slot, pos, n_blocks, live, start, length, cfg: DecoderConfig):
+    """One layer with no indexer over a prompt chunk at positions ``pos``
+    (``live`` of them real): its latent rows written into layer ``layer`` of
+    ``rows_all`` from ``start`` on, each query over every key up to its own
+    (:func:`_prefill_core` with the causal mask), the feed-forward half.
+    Returns the residual stream after the layer, ``rows_all`` and the
+    layer's counts of :data:`STATS`."""
+    q_nope, q_rope, latent, _, _, _ = _attention_inputs(h, lp, pos, cfg)
+    rows_all = jax.lax.dynamic_update_slice(rows_all, latent[None, None], (layer, slot, start, 0))
+    rows = _rows_of(rows_all, layer, slot)
+    visible = jnp.arange(rows.shape[0])[None, :] <= pos[:, None]
+    h = h + _prefill_core(q_nope, q_rope, rows, visible, n_blocks, lp, cfg, start, length)
+    added, rows_here, rows_routed, grouped = _mlp(h, lp, live, cfg)
+    return h + added, rows_all, jnp.stack([rows_here, rows_routed, jnp.int32(0), jnp.int32(0), jnp.int32(grouped)])
+
+
+def _prompt_layers(params, ids, cache, slot, start, length, cfg: DecoderConfig):
+    """The main stack over one bucket of a prompt (what :func:`prefill` and
+    :func:`prefill_drafted` share): the residual stream [C, hidden] after
+    the last layer, the cache with the chunk's rows written, the counts."""
     C = ids.shape[0]
     pos = start + jnp.arange(C, dtype=jnp.int32)
     live = jnp.arange(C) < length
     n_blocks = (start + C + cfg.key_block - 1) // cfg.key_block
     h = params["embed"][ids].astype(jnp.float32)
     stats = jnp.zeros((len(STATS),), jnp.int32)
-    latent_all, index_all = cache["latent"], cache["index_k"]
+    latent_all, index_all = cache["latent"], cache.get("index_k")
     for li, lp in enumerate(params["layers"]):
+        if index_all is None:
+            h, latent_all, counts = _chunk_layer(h, lp, latent_all, li, slot, pos, n_blocks, live, start, length, cfg)
+            stats = stats + counts
+            continue
         q_nope, q_rope, latent, qi, ki, wi = _attention_inputs(h, lp, pos, cfg)
         latent_all = jax.lax.dynamic_update_slice(latent_all, latent[None, None], (li, slot, start, 0))
         index_all = jax.lax.dynamic_update_slice(index_all, ki[None, None], (li, slot, start, 0))
@@ -497,8 +570,71 @@ def prefill(params, ids, cache, slot, start, length, last=True, *, config: Decod
             rows_here, rows_routed,
             jnp.sum(selected & live[:, None]).astype(jnp.int32), jnp.sum(visible & live[:, None]).astype(jnp.int32), jnp.int32(grouped),
         ])
+    cache = dict(cache, latent=latent_all)
+    if index_all is not None:
+        cache["index_k"] = index_all
+    return h, cache, stats
+
+
+def prefill(params, ids, cache, slot, start, length, last=True, *, config: DecoderConfig):
+    """One bucket of a prompt: ``ids`` [C] (``length`` of them real, the rest
+    padding) are the tokens ``start .. start + C`` of the sequence in
+    ``slot``.  Returns float32 logits over the held vocabulary at the last
+    real token, the cache with the chunk's rows written, and the counts of
+    :data:`STATS`.  ``start + C`` may not pass the cache's positions.
+    ``last`` (whether the prompt ends in this chunk) is the executor's to
+    say and changes nothing here: every layer runs for every token."""
+    h, cache, stats = _prompt_layers(params, ids, cache, slot, start, length, config)
     last = jax.lax.dynamic_slice_in_dim(h, length - 1, 1)
-    return _logits(last, params, cfg)[0], {"latent": latent_all, "index_k": index_all}, stats
+    return _logits(last, params, config)[0], cache, stats
+
+
+# --------------------------------------------------------------- the MTP layer
+#: logits of each draft kept beside its id: its largest, the draft's among them
+DRAFT_KEPT = 8
+
+
+def _mtp_input(params, next_ids, normed, pos, cfg: DecoderConfig):
+    """The MTP layer's input at positions ``pos``: ``[RMS(E t_{i+1}; w_e) ;
+    RMS(ĥ_i; w_h)] W_eh`` from the next tokens ``next_ids`` and the main
+    model's final-normed rows ``normed`` (float32), the embedding half zero
+    at position 0.  [T, hidden] float32."""
+    mp, eps = params["mtp"], cfg.rms_norm_eps
+    embedded = jnp.where((pos == 0)[:, None], 0.0, params["embed"][next_ids].astype(jnp.float32))
+    x = jnp.concatenate([_rms(embedded, mp["enorm"], eps), _rms(normed, mp["hnorm"], eps)], axis=-1).astype(cfg.dtype)
+    return _mm("tc,cd->td", x, mp["eh_proj"])
+
+
+def _draft(u, params, cfg: DecoderConfig):
+    """The draft from one row ``u`` [hidden] of the MTP layer's output: the
+    ``DRAFT_KEPT`` largest logits of ``RMS(u; w_sh) W_head`` over the held
+    vocabulary and their ids, the largest (the draft) first."""
+    x = _rms(u, params["mtp"]["head_norm"], cfg.rms_norm_eps).astype(cfg.dtype)
+    return jax.lax.top_k(_mm("c,cv->v", x, params["head"]), DRAFT_KEPT)
+
+
+def prefill_drafted(params, ids, next_id, cache, slot, start, length, last, *, config: DecoderConfig):
+    """:func:`prefill` for a configuration with an MTP layer, which also
+    runs over the chunk: position ``i`` pairs ``ĥ_i`` with ``t_{i+1}`` -- the
+    chunk's own next id, at its last real position ``next_id`` (the next
+    chunk's first) or, where the prompt ends here (``last``), the token
+    chosen from the logits -- and writes its own latent row.  Returns the
+    token chosen (int32 [1]), the float32 logits it was chosen from, the
+    draft made at the last real position (:func:`_draft`), the cache and
+    the counts of :data:`STATS`, the MTP layer's among them."""
+    cfg = config
+    C = ids.shape[0]
+    h, cache, stats = _prompt_layers(params, ids, cache, slot, start, length, cfg)
+    logits = _logits(jax.lax.dynamic_slice_in_dim(h, length - 1, 1), params, cfg)[0]
+    token = jnp.argmax(logits).astype(jnp.int32)
+    following = jnp.where(last, token, next_id)
+    next_ids = jnp.where(jnp.arange(C) == length - 1, following, jnp.roll(ids, -1))
+    pos = start + jnp.arange(C, dtype=jnp.int32)
+    x = _mtp_input(params, next_ids, _rms(h, params["final_norm"], cfg.rms_norm_eps), pos, cfg)
+    n_blocks = (start + C + cfg.key_block - 1) // cfg.key_block
+    u, mtp, counts = _chunk_layer(x, params["mtp"]["layer"], cache["mtp"], 0, slot, pos, n_blocks, jnp.arange(C) < length, start, length, cfg)
+    draft = _draft(jax.lax.dynamic_index_in_dim(u, length - 1, keepdims=False), params, cfg)
+    return token[None], logits, draft, dict(cache, mtp=mtp), stats + counts
 
 
 # ------------------------------------------------------------------ decode
@@ -538,12 +674,17 @@ def decode_step(params, ids, cache, slots, lengths, *, config: DecoderConfig):
     h = params["embed"][ids].astype(jnp.float32)
     live = jnp.ones((B,), bool)
     stats = jnp.zeros((len(STATS),), jnp.int32)
-    latent_all, index_all = cache["latent"], cache["index_k"]
+    latent_all, index_all = cache["latent"], cache.get("index_k")
     for li, lp in enumerate(params["layers"]):
         q_nope, q_rope, latent, qi, ki, wi = _attention_inputs(h, lp, lengths, cfg)
         outs = []
         for b in range(B):  # a row written and a sequence's rows read, each in place: no copy of a cache
             latent_all = jax.lax.dynamic_update_slice(latent_all, latent[b][None, None, None], (li, slots[b], lengths[b], 0))
+            if index_all is None:  # every key up to the token's own
+                rows = _rows_of(latent_all, li, slots[b])
+                visible = (jnp.arange(rows.shape[0]) <= lengths[b])[None, :]
+                outs.append((_decode_core(q_nope[b], q_rope[b], rows, visible, lp, cfg), jnp.int32(0), jnp.int32(0)))
+                continue
             index_all = jax.lax.dynamic_update_slice(index_all, ki[b][None, None, None], (li, slots[b], lengths[b], 0))
             outs.append(_decode_attention(
                 q_nope[b], q_rope[b], qi[b], wi[b], _rows_of(latent_all, li, slots[b]), _rows_of(index_all, li, slots[b]), lengths[b], lp, cfg
@@ -553,4 +694,56 @@ def decode_step(params, ids, cache, slots, lengths, *, config: DecoderConfig):
         added, rows_here, rows_routed, grouped = _mlp(h, lp, live, cfg)
         h = h + added
         stats = stats + jnp.stack([rows_here, rows_routed, jnp.sum(selected), jnp.sum(visible), jnp.int32(grouped)])
-    return _logits(h, params, cfg), {"latent": latent_all, "index_k": index_all}, stats
+    cache = dict(cache, latent=latent_all)
+    if index_all is not None:
+        cache["index_k"] = index_all
+    return _logits(h, params, cfg), cache, stats
+
+
+def _step_layer(h, lp, rows_all, layer: int, slot, pos, cfg: DecoderConfig):
+    """One layer with no indexer over ``T`` consecutive new tokens of the
+    sequence in ``slot`` at positions ``pos`` [T]: their latent rows written
+    into layer ``layer`` of ``rows_all``, each query in the absorbed form
+    (:func:`_decode_core`) over every key up to its own, so the tokens are
+    causal among themselves; the feed-forward half over the ``T`` rows."""
+    q_nope, q_rope, latent, _, _, _ = _attention_inputs(h, lp, pos, cfg)
+    rows_all = jax.lax.dynamic_update_slice(rows_all, latent[None, None], (layer, slot, pos[0], 0))
+    rows = _rows_of(rows_all, layer, slot)
+    visible = (jnp.arange(rows.shape[0])[None, :] <= pos[:, None])[:, None, :]
+    out = jax.vmap(lambda qn, qr, mask: _decode_core(qn, qr, rows, mask, lp, cfg))(q_nope, q_rope, visible)
+    h = h + _mm("td,dc->tc", out, lp["o"])
+    added, rows_here, rows_routed, grouped = _mlp(h, lp, jnp.ones(h.shape[:1], bool), cfg)
+    return h + added, rows_all, jnp.stack([rows_here, rows_routed, jnp.int32(0), jnp.int32(0), jnp.int32(grouped)])
+
+
+def verify_step(params, token, draft, cache, slot, length, *, config: DecoderConfig):
+    """One step of drafting decode for the sequence in ``slot``, which holds
+    ``length`` tokens (int32 scalar): its pending token ``token`` [1] and the
+    draft ``draft`` [1] through the main stack at ``length`` and ``length +
+    1``, then the MTP layer at both.  Returns how many tokens the step emits
+    (1 where the main row at ``length`` chooses another token than the
+    draft, else 2), the two ids the main rows choose and those rows (float32
+    [2, vocab_held]; the second counts only where the draft was kept), the
+    next draft (:func:`_draft`, from position ``length' - 1``), the next
+    ``(length', token', draft')``, the cache and the counts of
+    :data:`STATS`.  ``length'`` is held at ``positions - 2`` or under, so
+    that a step past the answer's end, whose tokens the executor drops,
+    writes nothing past the cache."""
+    cfg = config
+    positions = cache["latent"].shape[2]
+    pos = length + jnp.arange(2, dtype=jnp.int32)
+    h = params["embed"][jnp.concatenate([token, draft])].astype(jnp.float32)
+    stats = jnp.zeros((len(STATS),), jnp.int32)
+    latent_all = cache["latent"]
+    for li, lp in enumerate(params["layers"]):
+        h, latent_all, counts = _step_layer(h, lp, latent_all, li, slot, pos, cfg)
+        stats = stats + counts
+    rows = _logits(h, params, cfg)
+    chosen = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+    kept = chosen[0] == draft[0]
+    x = _mtp_input(params, chosen, _rms(h, params["final_norm"], cfg.rms_norm_eps), pos, cfg)
+    u, mtp, counts = _step_layer(x, params["mtp"]["layer"], cache["mtp"], 0, slot, pos, cfg)
+    values, ids = _draft(jnp.where(kept, u[1], u[0]), params, cfg)
+    emitted = 1 + kept.astype(jnp.int32)
+    state = (jnp.minimum(length + emitted, positions - 2), jnp.where(kept, chosen[1:], chosen[:1]), ids[:1])
+    return emitted, chosen, rows, (values, ids), state, dict(cache, latent=latent_all, mtp=mtp), stats + counts

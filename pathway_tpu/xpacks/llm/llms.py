@@ -8,9 +8,10 @@ client packages; :class:`HFPipelineChat` (``:441``) on a locally cached
 model.  ``prompt_chat_single_qa`` matches the reference helper.
 
 :class:`TPUDecoderChat` is the chat that stays on the chip: a causal
-decoder (one of the architectures of ``models/``: ``decoder``,
-``hybrid_decoder``, ``shortcut_moe_decoder``, ``window_moe_decoder``, each
-named by its presets) behind :class:`JittedDecoder`, so that
+decoder (one of the architectures of ``models/``: ``decoder``, which
+serves DeepSeek-V3.2-Exp and GLM-4.7-Flash, the latter drafting with its MTP
+layer, ``hybrid_decoder``, ``shortcut_moe_decoder``, ``window_moe_decoder``,
+each named by its presets) behind :class:`JittedDecoder`, so that
 ``BaseRAGQuestionAnswerer(llm=TPUDecoderChat(...))`` answers without the
 request leaving the device that retrieved for it.
 """
@@ -163,6 +164,8 @@ _DECODER_PRESETS = {
     "longcat-flash-chat": ("shortcut_moe_decoder", "LONGCAT_FLASH_CHAT"),
     "powerinfer/smallthinker-21ba3b-instruct": ("window_moe_decoder", "SMALLTHINKER_21BA3B"),
     "smallthinker-21ba3b-instruct": ("window_moe_decoder", "SMALLTHINKER_21BA3B"),
+    "zai-org/glm-4.7-flash": ("decoder", "GLM_4_7_FLASH"),
+    "glm-4.7-flash": ("decoder", "GLM_4_7_FLASH"),
 }
 
 
@@ -230,11 +233,17 @@ class TPUDecoderChat(BaseChat):
         out = self.decoder.generate(prompt_ids, self.max_new_tokens)
         with tracing.span("generate_detokenize"):
             text = " ".join(f"t{i}" for i in out["ids"])
-        self._recent.append({"prompt_ids": prompt_ids, "ids": out["ids"], "logits": out["logits"], "text": text})
+        kept = {"prompt_ids": prompt_ids, "ids": out["ids"], "logits": out["logits"], "text": text}
+        if "drafts" in out:
+            kept["drafts"] = out["drafts"]
+        self._recent.append(kept)
         return text
 
     def recent_generations(self) -> list[dict]:
         """The last 64 generations, oldest first: the prompt's ids, the ids
         chosen, the float32 logits over the held vocabulary each was chosen
-        from (what a ``logprobs`` caller is given) and the text returned."""
+        from (what a ``logprobs`` caller is given) and the text returned;
+        where the decoder drafts with an MTP layer, also each draft's
+        signature (``drafts``: its position and its largest logits and
+        their ids, as :meth:`JittedDecoder.generate` returns them)."""
         return list(self._recent)

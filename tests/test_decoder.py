@@ -628,6 +628,22 @@ def test_the_fused_attention_kernel_compiles_for_a_v5e_at_the_published_widths(o
     assert "selected_attention" in compiled.as_text()
 
 
+@pytest.mark.parametrize("chunk", [512, 2048, 2560])
+def test_the_fused_attention_kernel_compiles_for_a_v5e_at_glm_widths(one_chip, chunk):
+    """GLM-4.7-Flash's latent attention through the same kernel with the
+    causal mask: 20 heads (four a grid step) of 192 + 64, values of 256, 8,704
+    keys, a prompt chunk of each bucket."""
+    from pathway_tpu.ops.selected_attention import BLOCK_Q, selected_attention
+
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    H, L, bf16 = 20, 8704, jnp.bfloat16
+    compiled = selected_attention.lower(
+        shape((H, chunk, 192), bf16), shape((H, chunk, 64), bf16), shape((H, L, 192), bf16), shape((L, 64), bf16), shape((H, L, 256), bf16),
+        shape((chunk, L), jnp.bool_), shape((), jnp.int32), shape((), jnp.int32), block_q=BLOCK_Q, block_k=512,
+    ).compile()
+    assert "selected_attention" in compiled.as_text()
+
+
 @pytest.mark.parametrize("chunk,window", [(512, 4096), (2048, 4096), (2560, 4096), (2048, None), (2560, None)])
 def test_the_grouped_attention_kernel_compiles_for_a_v5e_at_the_published_widths(one_chip, chunk, window):
     """``models/window_moe_decoder.py``'s form of the kernel: 28 query heads
